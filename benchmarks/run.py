@@ -30,6 +30,9 @@ def main() -> None:
         bench_kv_gather,
         bench_op_distribution,
     )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     modules = [
         ("table1", bench_calibration_modes),
         ("fig3", bench_int8_matmul),

@@ -75,7 +75,9 @@ class QuantContext:
 
     policy: QuantPolicy
     calibrations: Dict[str, SiteCalibration] = dataclasses.field(default_factory=dict)
-    impl: str = "xla"            # "xla" | "pallas" | "interpret" (kernel choice)
+    # kernel choice: "auto" (Pallas on a TPU, XLA elsewhere) | "pallas" |
+    # "interpret" | "xla"
+    impl: str = "auto"
     enabled: bool = True
 
     def __post_init__(self):
@@ -185,7 +187,7 @@ def quantize_model(
     params: Dict[str, Any],
     calibrations: Optional[Dict[str, SiteCalibration]] = None,
     policy: Optional[QuantPolicy] = None,
-    impl: str = "xla",
+    impl: str = "auto",
     *,
     weight_bits: int = 8,
     weight_group_size: int = 128,

@@ -1,4 +1,3 @@
-from repro.distributed.compat import make_mesh, shard_map  # noqa: F401
 from repro.distributed.compression import (  # noqa: F401
     ef_compressed_mean,
     init_error_state,

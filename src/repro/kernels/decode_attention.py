@@ -8,9 +8,13 @@ vs f32 (2× vs bf16) and shrinks beam-search cache reorders by the same
 factor.
 
 One query token per sequence attends to the full cache with an online
-(flash) softmax: grid (batch, kv_head, seq_blocks), f32 running max / sum /
-accumulator in VMEM scratch.  GQA query groups (G = H / H_kv) ride along the
-sublane dim.
+(flash) softmax: grid (batch, seq_blocks), f32 running max / sum /
+accumulator per KV head in VMEM scratch.  Each K/V block spans every KV
+head — the TPU's block rules want the last two block dims whole (or
+(8, 128)-aligned), so a one-head slice of the ``HKV`` axis is not a legal
+block — and the body loops over heads.  GQA query groups (G = H / H_kv)
+ride along the sublane dim.  Sequence lengths (and the paged variant's
+block tables) arrive as scalar-prefetch operands in SMEM.
 """
 
 from __future__ import annotations
@@ -22,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 DEFAULT_BLOCK_S = 256
 # f32/int8-dequant compute tiles want ≥ 8 rows in the sublane dim: a paged
@@ -33,10 +35,23 @@ DEFAULT_BLOCK_S = 256
 SUBLANE = 8
 
 
-def _kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, len_ref, out_ref,
-            m_ref, l_ref, acc_ref, *, s_steps: int, block_s: int,
-            sm_scale: float):
-    s = pl.program_id(2)
+def _flash_kernel(*refs, n_prefetch: int, block_pages: int, n_steps: int,
+                  block_len: int, sm_scale: float):
+    """One online-softmax step over ``block_pages`` K/V blocks for every KV
+    head.  Refs: ``n_prefetch`` SMEM operands (lengths last), q, then
+    ``block_pages`` blocks each of k, k_scale, v, v_scale, the output and
+    three scratch buffers.  Consecutive blocks hold consecutive token
+    positions, so stacking them along the sublane dim keeps the position
+    iota contiguous; with ``block_pages > 1`` a ``page_size < 8`` pool
+    still feeds the dots full sublane tiles."""
+    F = block_pages
+    len_ref = refs[n_prefetch - 1]
+    q_ref = refs[n_prefetch]
+    kv = refs[n_prefetch + 1:n_prefetch + 1 + 4 * F]
+    k_refs, ks_refs, v_refs, vs_refs = (kv[i * F:(i + 1) * F]
+                                        for i in range(4))
+    out_ref, m_ref, l_ref, acc_ref = refs[n_prefetch + 1 + 4 * F:]
+    b, s = pl.program_id(0), pl.program_id(1)
 
     @pl.when(s == 0)
     def _init():
@@ -44,36 +59,87 @@ def _kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, len_ref, out_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                      # (G, dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                # (bs, dh)
-    k = k * ks_ref[0, :, 0][:, None]                         # dequant in VREGs
-    scores = jax.lax.dot_general(                            # (G, bs)
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
+    def rows(blocks, h):
+        parts = [r[0, :, h, :] for r in blocks]
+        return parts[0] if F == 1 else jnp.concatenate(parts, axis=0)
 
-    pos = s * block_s + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    valid = pos < len_ref[0, 0]
-    scores = jnp.where(valid, scores, NEG_INF)
+    def scales(blocks):
+        parts = [r[0] for r in blocks]                       # (n, HKV)
+        return parts[0] if F == 1 else jnp.concatenate(parts, axis=0)
 
-    m_prev = m_ref[...]                                      # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)
-    p = jnp.where(valid, p, 0.0)
+    ks_all, vs_all = scales(ks_refs), scales(vs_refs)
+    length = len_ref[b]
+    for h in range(k_refs[0].shape[2]):
+        q = q_ref[0, h].astype(jnp.float32)                  # (G, dh)
+        k = rows(k_refs, h).astype(jnp.float32)              # (F·n, dh)
+        k = k * ks_all[:, h:h + 1]                           # dequant in VREGs
+        scores = jax.lax.dot_general(                        # (G, F·n)
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
 
-    v = v_ref[0, :, 0, :].astype(jnp.float32)                # (bs, dh)
-    v = v * vs_ref[0, :, 0][:, None]
+        # logical position of this block's tokens; the length mask also
+        # hides sentinel (unreserved) page slots, clamped into the pool
+        pos = s * block_len + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 1)
+        valid = pos < length
+        scores = jnp.where(valid, scores, NEG_INF)
 
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+        m_prev = m_ref[h]                                    # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        p = jnp.where(valid, p, 0.0)
 
-    @pl.when(s == s_steps - 1)
+        v = rows(v_refs, h).astype(jnp.float32)
+        v = v * vs_all[:, h:h + 1]
+
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
+
+    @pl.when(s == n_steps - 1)
     def _finalize():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[0, 0] = out.astype(out_ref.dtype)
+        out_ref[0] = out.astype(out_ref.dtype)
+
+
+def _flash_decode(q, prefetch, kv_specs, kv_operands, *, n_steps: int,
+                  block_len: int, block_pages: int, sm_scale: float,
+                  interpret: bool) -> jax.Array:
+    """The pallas_call both cache layouts share: grid (batch, n_steps)."""
+    B, H, dh = q.shape
+    HKV = kv_operands[0].shape[-2]
+    assert H % HKV == 0, (H, HKV)
+    G = H // HKV
+    n_prefetch = len(prefetch)
+
+    def head_map(b, s, *_):
+        return (b, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(B, n_steps),
+        in_specs=[pl.BlockSpec((1, HKV, G, dh), head_map), *kv_specs],
+        out_specs=pl.BlockSpec((1, HKV, G, dh), head_map),
+        scratch_shapes=[
+            pltpu.VMEM((HKV, G, 1), jnp.float32),    # running max
+            pltpu.VMEM((HKV, G, 1), jnp.float32),    # running denom
+            pltpu.VMEM((HKV, G, dh), jnp.float32),   # output accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_flash_kernel, n_prefetch=n_prefetch,
+                          block_pages=block_pages, n_steps=n_steps,
+                          block_len=block_len, sm_scale=sm_scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, HKV, G, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(*prefetch, q.reshape(B, HKV, G, dh), *kv_operands)
+    return out.reshape(B, H, dh)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "block_s", "interpret"))
@@ -89,10 +155,7 @@ def decode_attention_pallas(
     block_s: int = DEFAULT_BLOCK_S,
     interpret: bool = False,
 ) -> jax.Array:
-    B, H, dh = q.shape
-    _, S, HKV, _ = k_q.shape
-    assert H % HKV == 0, (H, HKV)
-    G = H // HKV
+    _, S, HKV, dh = k_q.shape
     bs = min(block_s, S)
     pad = (-S) % bs
     if pad:
@@ -100,149 +163,14 @@ def decode_attention_pallas(
         v_q = jnp.pad(v_q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k_scale = jnp.pad(k_scale, ((0, 0), (0, pad), (0, 0)))
         v_scale = jnp.pad(v_scale, ((0, 0), (0, pad), (0, 0)))
-    Sp = S + pad
-    s_steps = Sp // bs
 
-    q4 = q.reshape(B, HKV, G, dh)
-    len2 = lengths.astype(jnp.int32).reshape(B, 1)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, s_steps=s_steps, block_s=bs,
-                          sm_scale=sm_scale),
-        grid=(B, HKV, s_steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda b, h, s: (b, h, 0, 0)),   # q
-            pl.BlockSpec((1, bs, 1, dh), lambda b, h, s: (b, s, h, 0)),  # k
-            pl.BlockSpec((1, bs, 1), lambda b, h, s: (b, s, h)),         # k_scale
-            pl.BlockSpec((1, bs, 1, dh), lambda b, h, s: (b, s, h, 0)),  # v
-            pl.BlockSpec((1, bs, 1), lambda b, h, s: (b, s, h)),         # v_scale
-            pl.BlockSpec((1, 1), lambda b, h, s: (b, 0)),                # lengths
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, dh), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, HKV, G, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),    # running max
-            pltpu.VMEM((G, 1), jnp.float32),    # running denom
-            pltpu.VMEM((G, dh), jnp.float32),   # output accumulator
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q4, k_q, k_scale, v_q, v_scale, len2)
-    return out.reshape(B, H, dh)
-
-
-# ---------------------------------------------------------------------------
-# paged variant: walk the block table per sequence block
-# ---------------------------------------------------------------------------
-
-def _paged_kernel(tab_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, len_ref,
-                  out_ref, m_ref, l_ref, acc_ref, *, s_steps: int,
-                  page_size: int, sm_scale: float):
-    """Same online-softmax body as ``_kernel``; the *grid* walks logical
-    page slots and the BlockSpec index maps translate each (row, slot)
-    into the physical page to DMA — the paged cache is consumed in place,
-    with no linearized copy ever materialized."""
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                      # (G, dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                # (ps, dh)
-    k = k * ks_ref[0, :, 0][:, None]                         # dequant in VREGs
-    scores = jax.lax.dot_general(                            # (G, ps)
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-
-    # logical position of this page slot's tokens; cursor mask also hides
-    # sentinel (unreserved) slots, whose index map clamped into the pool
-    pos = s * page_size + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    valid = pos < len_ref[0, 0]
-    scores = jnp.where(valid, scores, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)
-    p = jnp.where(valid, p, 0.0)
-
-    v = v_ref[0, :, 0, :].astype(jnp.float32)                # (ps, dh)
-    v = v * vs_ref[0, :, 0][:, None]
-
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-
-    @pl.when(s == s_steps - 1)
-    def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[0, 0] = out.astype(out_ref.dtype)
-
-
-def _paged_kernel_multi(tab_ref, q_ref, *refs, s_steps: int, page_size: int,
-                        block_pages: int, sm_scale: float):
-    """Multi-page variant of ``_paged_kernel``: one grid step DMAs
-    ``block_pages`` *consecutive logical slots* (each its own BlockSpec
-    operand, each landing wherever its table entry points) and runs one
-    online-softmax update over their concatenation — so a
-    ``page_size < 8`` pool still feeds the dots full sublane tiles."""
-    F = block_pages
-    k_refs, ks_refs = refs[0:F], refs[F:2 * F]
-    v_refs, vs_refs = refs[2 * F:3 * F], refs[3 * F:4 * F]
-    len_ref, out_ref = refs[4 * F], refs[4 * F + 1]
-    m_ref, l_ref, acc_ref = refs[4 * F + 2:4 * F + 5]
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                      # (G, dh)
-    # consecutive slots hold consecutive token positions, so stacking the
-    # pages along the sublane dim keeps the position iota contiguous
-    k = jnp.concatenate(
-        [r[0, :, 0, :] for r in k_refs], axis=0).astype(jnp.float32)
-    ks = jnp.concatenate([r[0, :, 0] for r in ks_refs], axis=0)
-    k = k * ks[:, None]                                      # (F·ps, dh)
-    scores = jax.lax.dot_general(                            # (G, F·ps)
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-
-    pos = (s * F * page_size
-           + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    valid = pos < len_ref[0, 0]
-    scores = jnp.where(valid, scores, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)
-    p = jnp.where(valid, p, 0.0)
-
-    v = jnp.concatenate(
-        [r[0, :, 0, :] for r in v_refs], axis=0).astype(jnp.float32)
-    vs = jnp.concatenate([r[0, :, 0] for r in vs_refs], axis=0)
-    v = v * vs[:, None]                                      # (F·ps, dh)
-
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-
-    @pl.when(s == s_steps - 1)
-    def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[0, 0] = out.astype(out_ref.dtype)
+    kv_spec = pl.BlockSpec((1, bs, HKV, dh), lambda b, s, L: (b, s, 0, 0))
+    sc_spec = pl.BlockSpec((1, bs, HKV), lambda b, s, L: (b, s, 0))
+    return _flash_decode(
+        q, (lengths.astype(jnp.int32),),
+        [kv_spec, sc_spec, kv_spec, sc_spec], [k_q, k_scale, v_q, v_scale],
+        n_steps=(S + pad) // bs, block_len=bs, block_pages=1,
+        sm_scale=sm_scale, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret",
@@ -262,7 +190,7 @@ def decode_attention_paged_pallas(
 ) -> jax.Array:
     """Flash-decode over a paged INT8 KV cache (paper §5.3, paged).
 
-    Grid (batch, kv_head, page_slot); the block table rides in as a
+    Grid (batch, page_slot_block); the block table rides in as a
     scalar-prefetch operand so each slot's physical page id is known
     before the body runs and the K/V DMAs fetch pages directly — the
     paper's "big tensor stops moving" taken to its endpoint: decode reads
@@ -274,20 +202,15 @@ def decode_attention_paged_pallas(
     tile; block tables fill slots densely from the front, so a block's
     pages hold contiguous positions and the tail mask is unchanged.
     """
-    B, H, dh = q.shape
-    P, ps, HKV, _ = k_pages.shape
-    assert H % HKV == 0, (H, HKV)
-    G = H // HKV
+    P, ps, HKV, dh = k_pages.shape
     maxP = block_tables.shape[1]
 
     if pages_per_block < 0:
         raise ValueError(f"pages_per_block must be >= 0, got {pages_per_block}")
     F = pages_per_block or max(1, SUBLANE // ps)
 
-    q4 = q.reshape(B, HKV, G, dh)
-    len2 = lengths.astype(jnp.int32).reshape(B, 1)
     tab = block_tables.astype(jnp.int32)
-    if F > 1 and maxP % F:
+    if maxP % F:
         # pad logical slots to a block multiple with sentinels: their
         # positions land past every cursor, so the `pos < len` mask drops
         # them exactly like any other unreserved slot
@@ -295,83 +218,19 @@ def decode_attention_paged_pallas(
         maxP = tab.shape[1]
     tab = jnp.clip(tab, 0, P - 1)
 
-    if F > 1:
-        def page_map_j(j):
-            return lambda b, h, s, t: (t[b, s * F + j], 0, h, 0)
+    def kv_spec(j):
+        return pl.BlockSpec((1, ps, HKV, dh),
+                            lambda b, s, t, L: (t[b, s * F + j], 0, 0, 0))
 
-        def scale_map_j(j):
-            return lambda b, h, s, t: (t[b, s * F + j], 0, h)
+    def sc_spec(j):
+        return pl.BlockSpec((1, ps, HKV),
+                            lambda b, s, t, L: (t[b, s * F + j], 0, 0))
 
-        s_steps = maxP // F
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, HKV, s_steps),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, dh), lambda b, h, s, t: (b, h, 0, 0)),
-                *[pl.BlockSpec((1, ps, 1, dh), page_map_j(j))
-                  for j in range(F)],                        # k pages
-                *[pl.BlockSpec((1, ps, 1), scale_map_j(j))
-                  for j in range(F)],                        # k scales
-                *[pl.BlockSpec((1, ps, 1, dh), page_map_j(j))
-                  for j in range(F)],                        # v pages
-                *[pl.BlockSpec((1, ps, 1), scale_map_j(j))
-                  for j in range(F)],                        # v scales
-                pl.BlockSpec((1, 1), lambda b, h, s, t: (b, 0)),  # lengths
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, dh),
-                                   lambda b, h, s, t: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),    # running max
-                pltpu.VMEM((G, 1), jnp.float32),    # running denom
-                pltpu.VMEM((G, dh), jnp.float32),   # output accumulator
-            ],
-        )
-        out = pl.pallas_call(
-            functools.partial(_paged_kernel_multi, s_steps=s_steps,
-                              page_size=ps, block_pages=F,
-                              sm_scale=sm_scale),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, HKV, G, dh), q.dtype),
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-            ),
-            interpret=interpret,
-        )(tab, q4, *([k_pages] * F), *([k_scale] * F),
-          *([v_pages] * F), *([v_scale] * F), len2)
-        return out.reshape(B, H, dh)
-
-    def page_map(b, h, s, tab_ref):
-        return (tab_ref[b, s], 0, h, 0)
-
-    def scale_map(b, h, s, tab_ref):
-        return (tab_ref[b, s], 0, h)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, HKV, maxP),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda b, h, s, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, dh), page_map),                  # k pages
-            pl.BlockSpec((1, ps, 1), scale_map),                     # k_scale
-            pl.BlockSpec((1, ps, 1, dh), page_map),                  # v pages
-            pl.BlockSpec((1, ps, 1), scale_map),                     # v_scale
-            pl.BlockSpec((1, 1), lambda b, h, s, t: (b, 0)),         # lengths
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, dh), lambda b, h, s, t: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),    # running max
-            pltpu.VMEM((G, 1), jnp.float32),    # running denom
-            pltpu.VMEM((G, dh), jnp.float32),   # output accumulator
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, s_steps=maxP, page_size=ps,
-                          sm_scale=sm_scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, HKV, G, dh), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(tab, q4, k_pages, k_scale, v_pages, v_scale, len2)
-    return out.reshape(B, H, dh)
+    specs = ([kv_spec(j) for j in range(F)] + [sc_spec(j) for j in range(F)]
+             + [kv_spec(j) for j in range(F)] + [sc_spec(j) for j in range(F)])
+    operands = ([k_pages] * F + [k_scale] * F + [v_pages] * F
+                + [v_scale] * F)
+    return _flash_decode(
+        q, (tab, lengths.astype(jnp.int32)), specs, operands,
+        n_steps=maxP // F, block_len=F * ps, block_pages=F,
+        sm_scale=sm_scale, interpret=interpret)

@@ -19,8 +19,16 @@ reference oracle uses, which is what makes bit-identity tests meaningful.
 
 Tiling: grid (M/bm, N/bn, K/bk) with K innermost, like ``int8_matmul``.  The
 wrapper forces ``bk`` to a multiple of ``group_size`` so a block's scale/min
-never straddles two k-tiles; packed rows tile at ``bk // 2`` and the
-scale/min operands at ``bk // group_size`` rows per step.
+never straddles two k-tiles; packed rows tile at ``bk // 2``, and the
+scale/min operands are laid out as (K/bk, bk // group_size, N) so each step's
+block spans its whole second-minor dim, as the TPU's block rules require.
+
+Nibble order.  Packed row r holds K rows 2r (low nibble) and 2r+1 (high).
+Interleaving those rows back in VMEM is a sublane shuffle the TPU compiler
+does not lower, so the wrapper instead permutes each group's activation
+columns to [even k | odd k]; the kernel then stacks a group's low nibbles
+over its high nibbles, an aligned concatenation.  Permuting the contraction
+axis of both operands leaves every int32 dot unchanged.
 
 Padding contract (the colsum/zp analogue of the INT8 one): ``a`` is padded
 with zeros along K, so padded rows contribute exactly zero to both the MXU
@@ -38,21 +46,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.int8_matmul import _pad_to
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
 DEFAULT_BK = 512
-
-
-def _unpack_nibbles_tile(packed: jax.Array) -> jax.Array:
-    """(bk//2, bn) int8 → (bk, bn) int8 codes in [0, 15] (row 2r=lo, 2r+1=hi)."""
-    pu = jax.lax.bitcast_convert_type(packed, jnp.uint8)
-    lo = (pu & 0xF).astype(jnp.int8)
-    hi = (pu >> 4).astype(jnp.int8)
-    k2, bn = packed.shape
-    return jnp.stack([lo, hi], axis=1).reshape(2 * k2, bn)
 
 
 def _kernel(a_ref, b_ref, scale_ref, min_ref, a_scale_ref, zp_ref,
@@ -63,15 +61,19 @@ def _kernel(a_ref, b_ref, scale_ref, min_ref, a_scale_ref, zp_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    nib = _unpack_nibbles_tile(b_ref[...])            # (bk, bn) int8 in [0,15]
+    packed = b_ref[...].astype(jnp.int32)             # (bk//2, bn), sign-ext.
+    lo = packed & 0xF                                 # codes of even k rows
+    hi = (packed >> 4) & 0xF                          # codes of odd k rows
     a_tile = a_ref[...]                               # (bm, bk) int8
-    scales = scale_ref[...].astype(jnp.float32)       # (bk//G, bn)
-    mins = min_ref[...].astype(jnp.float32)           # (bk//G, bn)
+    scales = scale_ref[0]                             # (bk//G, bn) f32
+    mins = min_ref[0]                                 # (bk//G, bn) f32
+    half = group_size // 2
     for g in range(groups_per_block):
-        sl = slice(g * group_size, (g + 1) * group_size)
-        a_g = a_tile[:, sl]                           # (bm, G) int8
+        rows = slice(g * half, (g + 1) * half)
+        nib = jnp.concatenate([lo[rows], hi[rows]], axis=0).astype(jnp.int8)
+        a_g = a_tile[:, g * group_size:(g + 1) * group_size]  # [even | odd]
         # MXU step: s8 × s8 → s32, exact
-        d = jnp.dot(a_g, nib[sl, :], preferred_element_type=jnp.int32)
+        d = jnp.dot(a_g, nib, preferred_element_type=jnp.int32)
         rsum = jnp.sum(a_g.astype(jnp.int32), axis=1, keepdims=True)
         acc_ref[...] += (d.astype(jnp.float32) * scales[g][None, :]
                          + rsum.astype(jnp.float32) * mins[g][None, :])
@@ -121,6 +123,8 @@ def int4_matmul_pallas(
     K2, N = b_packed.shape
     n_g = b_scale.shape[0]
     k_store = n_g * group_size
+    if group_size % 2:
+        raise ValueError(f"group_size must be even, got {group_size}")
     if 2 * K2 != k_store:
         raise ValueError(f"packed rows {K2} inconsistent with "
                          f"{n_g} groups of {group_size}")
@@ -135,11 +139,19 @@ def int4_matmul_pallas(
     # weight payload with zero bytes and the tail groups with scale=min=0.
     Kp = -(-k_store // bk) * bk
     a_p = _pad_to(jnp.pad(a_q, ((0, 0), (0, Kp - K))), (bm, bk))
-    b_p = _pad_to(b_packed, (Kp // 2, bn))
-    scale_p = _pad_to(b_scale, (Kp // group_size, bn))
-    min_p = _pad_to(b_min, (Kp // group_size, bn))
     Mp = a_p.shape[0]
+    a_p = (a_p.reshape(Mp, Kp // group_size, group_size // 2, 2)
+           .swapaxes(-1, -2).reshape(Mp, Kp))      # per group: [even | odd]
+    b_p = _pad_to(b_packed, (Kp // 2, bn))
     Np = b_p.shape[1]
+    gpb = bk // group_size
+    k_steps = Kp // bk
+    # the chip's kernels cannot load f16, so scale/min widen to f32 here
+    # (exact) — a (K/G, N) pass, 1/G of the payload's rows
+    scale_p = _pad_to(b_scale.astype(jnp.float32), (Kp // group_size, bn)
+                      ).reshape(k_steps, gpb, Np)
+    min_p = _pad_to(b_min.astype(jnp.float32), (Kp // group_size, bn)
+                    ).reshape(k_steps, gpb, Np)
 
     a_scale_p = _pad_to(jnp.broadcast_to(a_scale, (M, 1)).astype(jnp.float32),
                         (bm, 1))
@@ -163,8 +175,7 @@ def int4_matmul_pallas(
     bias_p = (_pad_to(bias.reshape(1, N).astype(jnp.float32), (1, bn))
               if has_bias else jnp.zeros((1, Np), jnp.float32))
 
-    m_steps, n_steps, k_steps = Mp // bm, Np // bn, Kp // bk
-    gpb = bk // group_size
+    m_steps, n_steps = Mp // bm, Np // bn
 
     out = pl.pallas_call(
         functools.partial(_kernel, k_steps=k_steps, groups_per_block=gpb,
@@ -174,8 +185,8 @@ def int4_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),           # a
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),      # packed b
-            pl.BlockSpec((gpb, bn), lambda i, j, k: (k, j)),          # scales
-            pl.BlockSpec((gpb, bn), lambda i, j, k: (k, j)),          # mins
+            pl.BlockSpec((1, gpb, bn), lambda i, j, k: (k, 0, j)),    # scales
+            pl.BlockSpec((1, gpb, bn), lambda i, j, k: (k, 0, j)),    # mins
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),            # a_scale
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),             # zp
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),            # colsum
@@ -184,7 +195,7 @@ def int4_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -6,7 +6,9 @@ Every op dispatches on ``impl``:
 * ``"interpret"``  — the same kernel body interpreted on CPU (tests),
 * ``"xla"``        — pure-jnp fallback (identical math; this is what the
                      CPU dry-run compiles, and the oracle for tests).
-* ``"auto"``       — pallas on TPU backends, xla elsewhere.
+* ``"auto"``       — pallas on TPU backends, xla elsewhere (the default
+                     of ``QuantContext``, so a chip runs the kernels and a
+                     kernel the chip's compiler refuses raises).
 
 The wrappers are QTensor-aware and handle leading-batch flattening so model
 code can stay shape-agnostic.
@@ -33,12 +35,11 @@ from repro.kernels.int8_matmul import (
 from repro.kernels.quantize import quantize_rowwise_pallas, quantize_static_pallas
 
 
-def default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
-
-
-def _resolve(impl: str) -> str:
-    return default_impl() if impl == "auto" else impl
+def resolve_impl(impl: str) -> str:
+    """The kernel path ``impl`` names on this process's default backend."""
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return impl
 
 
 # ---------------------------------------------------------------------------
@@ -79,7 +80,7 @@ def int8_matmul(
     ``a``: activations, shape (..., K); scale per-row (…,1) or scalar;
     ``b``: weights, shape (K, N); symmetric per-column scale (1, N)/scalar.
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     batch_shape = a.data.shape[:-1]
     K = a.data.shape[-1]
     N = b.data.shape[-1]
@@ -114,7 +115,7 @@ def int4_matmul(
     ``a``: int8 activations, shape (..., K); scale per-row (…, 1) or scalar;
     ``b``: block-quantized INT4 weights (packed nibbles + group scale/min).
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     batch_shape = a.data.shape[:-1]
     K = a.data.shape[-1]
     if b.data.ndim != 2:
@@ -147,7 +148,7 @@ def int8_matmul_batched(
     impl: str = "auto",
 ) -> jax.Array:
     """Per-expert grouped int8 matmul (MoE expert FFN hot path)."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     E, M, K = a.data.shape
     _, _, N = b.data.shape
     a_scale = (jnp.broadcast_to(jnp.asarray(a.scale, jnp.float32),
@@ -170,7 +171,7 @@ def int8_matmul_batched(
 
 def quantize_rowwise(x: jax.Array, *, impl: str = "auto") -> QTensor:
     """Dynamic symmetric per-row quantization of (..., K) activations."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     batch_shape = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     if impl in ("pallas", "interpret"):
@@ -187,7 +188,7 @@ def quantize_rowwise(x: jax.Array, *, impl: str = "auto") -> QTensor:
 
 def quantize_static(x: jax.Array, amax, *, impl: str = "auto") -> QTensor:
     """Calibrated symmetric quantization with a constant threshold."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     batch_shape = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     if impl in ("pallas", "interpret"):
@@ -218,7 +219,7 @@ def decode_attention(
     sm_scale: float,
     impl: str = "auto",
 ) -> jax.Array:
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         return decode_attention_pallas(
             q, k_q, k_scale, v_q, v_scale, lengths,
@@ -243,7 +244,7 @@ def decode_attention_paged(
     """Paged-cache decode attention: the Pallas kernel walks the block
     table per page slot (scalar-prefetched index map); the XLA fallback
     linearizes the table then reuses the contiguous oracle."""
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         return decode_attention_paged_pallas(
             q, k_pages, k_scale, v_pages, v_scale, block_tables, lengths,

@@ -1,4 +1,5 @@
-"""Post-compile HLO analysis: collective-traffic accounting.
+"""Post-compile HLO analysis: collective-traffic accounting, and which
+Pallas kernels a compiled program calls.
 
 ``cost_analysis()`` gives FLOPs/bytes but no collective bytes, and it counts
 while-loop bodies ONCE (verified empirically — see EXPERIMENTS.md
@@ -181,4 +182,19 @@ def count_hlo_ops(hlo: str, op_names: Tuple[str, ...]) -> Dict[str, int]:
             for op in op_names:
                 if f" {op}(" in line:
                     out[op] += multipliers[comp]
+    return dict(out)
+
+
+_PALLAS_RE = re.compile(r'op_name="[^"]*?jit\((\w+)\)/pallas_call')
+
+
+def pallas_kernel_calls(hlo: str) -> Dict[str, int]:
+    """Compiled Pallas kernel call sites (``tpu_custom_call``) per kernel,
+    named by the jitted wrapper that launched them (e.g.
+    ``int8_matmul_pallas``); call sites, not trip-weighted executions."""
+    out: Dict[str, int] = defaultdict(int)
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _PALLAS_RE.search(line)
+            out[m.group(1) if m else "unnamed"] += 1
     return dict(out)

@@ -9,8 +9,7 @@ one device.
 from __future__ import annotations
 
 import jax
-
-from repro.distributed.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def _require_devices(shape, axes) -> list:
@@ -39,14 +38,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     devices = _require_devices(shape, axes)  # dry-run exposes 512 host devs
-    return make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over host devices — tests/examples/sharded serving."""
     axes = ("data", "model")
     devices = _require_devices((data, model), axes)
-    return make_mesh((data, model), axes, devices=devices)
+    return jax.make_mesh((data, model), axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple:

@@ -1,7 +1,11 @@
-"""Serving driver: the paper's full inference stack on a reduced model.
+"""Serving entry point: the paper's full inference stack.
 
 ``python -m repro.launch.serve --arch transformer-base --requests 64
   --quant symmetric --streams 2 --beam 1``
+
+The model is the arch's CPU-runnable reduction (``cfg.reduced()``) by
+default; ``--published`` builds it at its published widths instead (see
+:func:`serving_config`).  Weights are seeded random (``PRNGKey(0)``).
 
 Pipeline (``--mode static``, the paper's): synthetic requests →
 token-sorted scheduler → (optional calibrated INT8 PTQ) → parallel stream
@@ -23,13 +27,15 @@ burst cap under the adaptive controller.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import ModelConfig, get_config
 from repro.core import (
     Calibrator,
     QuantMode,
@@ -38,17 +44,78 @@ from repro.core import (
     count_quantized,
     quantize_model,
 )
-from repro.core.ptq import FP_CONTEXT
-from repro.data import corpus_bleu, make_corpus, pack_batches_token_budget
-from repro.models import build_model
+from repro.core.calibration import SiteCalibration
+from repro.core.ptq import FP_CONTEXT, QuantContext
+from repro.data import Sentence, make_corpus, pack_batches_token_budget
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
+from repro.models import build_model
 from repro.serving import ParallelStreams, ReplicaRouter, Request, \
     ServingEngine, TokenSortedScheduler, make_chaos
+
+# decode positions every serving engine built here holds per row
+MAX_LEN = 96
+
+
+def serving_config(arch: str, *, published: bool = False) -> ModelConfig:
+    """The model configuration this entry point builds.
+
+    ``published=False`` gives the CPU-runnable reduction (d_model 64, two
+    layers, float32).  ``published=True`` keeps every width, head count,
+    the vocab and the bf16 activations as published, and runs the layers
+    unrolled: calibration taps name one site per layer, which a
+    ``lax.scan`` body traced once cannot, and ``remat`` only matters for
+    training.
+    """
+    cfg = get_config(arch)
+    if not cfg.enc_dec:
+        raise SystemExit("serving expects an enc-dec (NMT) arch")
+    if published:
+        return dataclasses.replace(cfg, scan_layers=False, remat=False)
+    return cfg.reduced()
+
+
+def calibrate(model, params, sentences: Sequence[Sentence],
+              mode: str) -> Dict[str, SiteCalibration]:
+    """KL calibration over teacher-forced forwards of ``sentences``."""
+    def tapped(p, src, tgt):
+        taps = Taps()
+        model.forward(p, {"src_tokens": src, "tgt_tokens": tgt}, taps=taps)
+        return taps.values
+
+    forward = jax.jit(tapped)           # one compile per sentence shape
+    cal = Calibrator()
+    for s in sentences:
+        values = forward(params, jnp.asarray(s.src[None, :]),
+                         jnp.asarray(np.concatenate([[1], s.tgt, [2]])[None]))
+        for name, value in values.items():
+            cal.observe_site(name, value)
+    return cal.compute(mode)
+
+
+def quantize_for_serving(model, params, calib: Sequence[Sentence], *,
+                         mode: str = "symmetric", weight_bits: int = 8,
+                         weight_group_size: int = 128
+                         ) -> Tuple[dict, QuantContext, Dict[str, SiteCalibration]]:
+    """Calibrate, then PTQ with static activation thresholds.
+
+    Returns the quantized params, their runtime context (kernel choice
+    ``"auto"``: the Pallas kernels on a TPU) and the calibration records.
+    """
+    recs = calibrate(model, params, calib, mode)
+    qparams, qctx = quantize_model(
+        params, recs, QuantPolicy(mode=QuantMode(mode), act_quant="static"),
+        weight_bits=weight_bits, weight_group_size=weight_group_size)
+    return qparams, qctx, recs
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="transformer-base")
+    ap.add_argument("--published", action="store_true",
+                    help="build the arch at its published widths (bf16, "
+                         "unrolled layers) instead of the CPU-sized "
+                         "reduction")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--batch-size", type=int, default=16)
     ap.add_argument("--quant", default="symmetric",
@@ -147,9 +214,8 @@ def main() -> None:
     burst_len = args.burst_len if args.burst_len == "auto" \
         else int(args.burst_len)
 
-    cfg = get_config(args.arch).reduced()
-    if not cfg.enc_dec:
-        raise SystemExit("serve driver expects an enc-dec (NMT) arch")
+    enable_compile_cache()
+    cfg = serving_config(args.arch, published=args.published)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     corpus = make_corpus(args.requests + 64, cfg.vocab, seed=11)
@@ -157,19 +223,9 @@ def main() -> None:
 
     qctx = FP_CONTEXT
     if args.quant != "none":
-        cal = Calibrator()
-        for s in corpus[args.requests:args.requests + 32]:
-            taps = Taps()
-            model.forward(params, {
-                "src_tokens": jnp.asarray(s.src[None, :]),
-                "tgt_tokens": jnp.asarray(
-                    np.concatenate([[1], s.tgt, [2]])[None, :])}, taps=taps)
-            cal.observe_taps(taps)
-        recs = cal.compute(args.quant)
-        params, qctx = quantize_model(
-            params, recs, QuantPolicy(mode=QuantMode(args.quant),
-                                      act_quant="static"),
-            weight_bits=args.weight_bits,
+        params, qctx, recs = quantize_for_serving(
+            model, params, corpus[args.requests:args.requests + 32],
+            mode=args.quant, weight_bits=args.weight_bits,
             weight_group_size=args.weight_group_size)
         print(f"quantized with mode={args.quant}: "
               f"{sum(r.quantize for r in recs.values())}/{len(recs)} "
@@ -196,16 +252,15 @@ def main() -> None:
                                  f"got {args.mesh!r}")
             mesh = make_host_mesh(data=data_ax, model=model_ax)
 
-        def mk_engine():
-            return ServingEngine(model, params, quant=qctx, max_len=96,
+        def mk_engine(device=None):
+            return ServingEngine(model, params, quant=qctx, max_len=MAX_LEN,
                                  burst_len=burst_len, paged=args.paged,
                                  page_size=args.page_size,
                                  n_pages=args.n_pages,
                                  prefix_cache=args.prefix_cache,
                                  prefix_pages=args.prefix_pages,
-                                 mesh=mesh)
+                                 mesh=mesh, device=device)
 
-        engine = mk_engine()
         bins = pack_batches_token_budget(requests, args.token_budget)
         order = [i for b in bins for i in b]     # FFD admission order
         beam = args.beam if args.beam > 1 else None
@@ -225,8 +280,10 @@ def main() -> None:
                         prefill_chunk=args.prefill_chunk,
                         chaos=chaos)
         if args.replicas > 1:
-            router = ReplicaRouter(
-                [engine] + [mk_engine() for _ in range(args.replicas - 1)])
+            router = (ReplicaRouter.on_devices(mk_engine, args.replicas)
+                      if mesh is None else     # replicas share the tp mesh
+                      ReplicaRouter([mk_engine()
+                                     for _ in range(args.replicas)]))
             rres = router.serve(reqs, **serve_kw)
             print(f"router x{args.replicas}: {len(rres.requests)} requests "
                   f"in {rres.wall_s:.2f}s ({rres.tokens_per_s:.1f} tok/s), "
@@ -241,6 +298,7 @@ def main() -> None:
                       + (f", tp={r.tp_degree} mesh={r.mesh_shape}"
                          if r.tp_degree > 1 else ""))
             return
+        engine = mk_engine()
         t0 = time.perf_counter()
         res = engine.serve(reqs, **serve_kw)
         dt = time.perf_counter() - t0
@@ -304,7 +362,7 @@ def main() -> None:
               f"p95 {met['total_latency_p95_s']:.3f}s")
         return
 
-    engines = [ServingEngine(model, params, quant=qctx, max_len=96)
+    engines = [ServingEngine(model, params, quant=qctx, max_len=MAX_LEN)
                for _ in range(args.streams)]
     sched = TokenSortedScheduler(batch_size=args.batch_size,
                                  sort_mode=args.sort)

@@ -20,6 +20,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs import get_config
 from repro.data import LMBatches, TranslationBatches, make_corpus
 from repro.distributed.fault import StepWatchdog, run_with_restarts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import AdamW, warmup_cosine
 from repro.train import make_train_step, train_loop
@@ -40,6 +41,7 @@ def main() -> None:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
 
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

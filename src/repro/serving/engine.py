@@ -79,15 +79,16 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.core.ptq import FP_CONTEXT, QuantContext
 from repro.data.sorting import next_pow2
 from repro.data.synthetic import EOS, pad_batch
 from repro.distributed.fault import StepWatchdog
-from repro.distributed.sharding import named_shardings
+from repro.kernels.ops import resolve_impl
 from repro.models import kv_cache as kvc
 from repro.serving.sharding import decode_state_shardings, mesh_axis_sizes, \
-    tp_degree
+    param_shardings, tp_degree
 from repro.serving.burst_control import AdaptiveBurst
 from repro.serving.chaos import ChaosSchedule
 from repro.serving.preemption import SpilledRequest, SpillStore, pick_victims
@@ -104,6 +105,39 @@ BEAM_SEED_NEG = np.float32(-1e30)
 # compiled ring-buffer bucket for burst_len="auto": the AdaptiveBurst cap
 # moves as a device scalar inside [1, AUTO_MAX_BURST] — one compile total
 AUTO_MAX_BURST = 64
+
+
+def _partitionable(quant: Optional[QuantContext]) -> Optional[QuantContext]:
+    """``quant`` with its kernel choice moved off the Pallas kernels, for
+    a program GSPMD has to partition across several devices."""
+    if quant is None or resolve_impl(quant.impl) != "pallas":
+        return quant
+    return dataclasses.replace(quant, impl="xla")
+
+
+def on_whole_rows(fn: Callable, mesh=None) -> Callable:
+    """``fn`` run whole on every device of ``mesh``, over replicated
+    operands; ``fn`` itself without a mesh of several devices.
+
+    The TPU compiler rounds a float reduction differently at another
+    per-device shape (attention over 2 of 8 heads, a softmax over a
+    quarter of the vocab) or when partial sums cross devices, so a tp>1
+    program that splits one can stop matching tp=1 bit for bit.  Run
+    whole, each device computes the one-device program; ``shard_map``
+    keeps GSPMD from partitioning it again."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), check_vma=False)
+
+
+def log_probs(logits: jax.Array, mesh=None) -> jax.Array:
+    """f32 log-softmax over the vocab, as beam search ranks with it: on a
+    mesh, each device normalises whole rows (:func:`on_whole_rows`), since
+    a last-bit difference in the normaliser flips near-tied hypotheses."""
+    return on_whole_rows(
+        lambda x: jax.nn.log_softmax(x.astype(jnp.float32), axis=-1),
+        mesh)(logits)
 
 
 def _spec_accept(d: jax.Array, v: jax.Array, remaining: jax.Array,
@@ -366,23 +400,34 @@ class ServingEngine:
                  prefix_pages: int = 256,
                  prefix_page_size: Optional[int] = None,
                  draft_quant: Optional[QuantContext] = None,
-                 mesh=None):
+                 mesh=None, device=None):
+        if mesh is not None and device is not None:
+            raise ValueError("pass a mesh or a device, not both")
         self.model = model
         # tensor-parallel serving: with a ("data","model") mesh the burst
         # programs compile as ONE SPMD program — GSPMD places the per-layer
         # all-reduces inside the lax.while_loop, so a serve round stays one
-        # dispatch + one host sync.  We only *place* the inputs: weights by
-        # the training sharding rules (fsdp off — serving replicates
-        # non-tensor dims), the decode state by serving.sharding (K/V pools
-        # split on heads, host-facing buffers replicated).
+        # dispatch + one host sync.  We only *place* the inputs, both by
+        # serving.sharding: weights by the training tensor rules (fsdp off)
+        # with the encoder's replicated, the decode state with K/V pools
+        # split on heads and host-facing buffers replicated.
+        # Without a mesh, ``device`` pins the params and every decode state
+        # to one device (a router replica), else they stay where they are.
         self.mesh = mesh
+        self.device = device
         self.tp = tp_degree(mesh)
         if mesh is not None:
-            params = jax.device_put(
-                params, named_shardings(params, mesh, tensor="model",
-                                        fsdp=None,
-                                        kv_heads=model.cfg.n_kv_heads))
+            params = jax.device_put(params, param_shardings(
+                params, mesh, kv_heads=model.cfg.n_kv_heads))
+        elif device is not None:
+            params = jax.device_put(params, device)
         self.params = params
+        # GSPMD cannot partition a Pallas (Mosaic) kernel — lowering one
+        # under a multi-device sharding raises — so a sharded engine runs
+        # the kernels' XLA forms, which it partitions like any other op
+        if mesh is not None and mesh.size > 1:
+            quant = _partitionable(quant)
+            draft_quant = _partitionable(draft_quant)
         self.quant = quant
         # speculative decoding draft context: the k cheap draft steps run
         # with these weights/activations (e.g. INT8 while ``quant`` is FP —
@@ -427,8 +472,20 @@ class ServingEngine:
         self._pool_insert_jit: Optional[Callable] = None
         self._hit_splice_jits: Dict[int, Callable] = {}
 
-        self._prefill = jax.jit(
-            lambda p, b, s: model.prefill(p, b, s, quant=quant))
+        # the encoder (once per request) runs whole on every device of a
+        # mesh, so its cross-K/V match tp=1 bit for bit (on_whole_rows)
+        encode = on_whole_rows(
+            lambda p, b: model.encode_cross_kv(p, b, quant=quant), mesh)
+        self._encode = encode
+
+        def prefill(p, b, s):
+            # model.prefill, with the encoder run as above
+            ck, cv, slens = encode(p, b)
+            s = dict(s, cross_k=ck, cross_v=cv, src_lengths=slens)
+            return model.decode_step(p, jnp.zeros((ck.shape[1],), jnp.int32),
+                                     s, quant=quant)
+
+        self._prefill = jax.jit(prefill)
         # continuous-batching row splice: scatter a prefilled side-batch into
         # the long-lived decode state.  Donates the old state/token buffers —
         # the caller always rebinds to the returned ones.
@@ -469,12 +526,32 @@ class ServingEngine:
     def _shard_state(self, state):
         """Place a fresh decode state on the engine mesh: K/V pools (self,
         cross, prefix) split on the heads axis, block tables / cursors /
-        token buffers replicated.  No-op without a mesh."""
+        token buffers replicated.  Without a mesh, on the engine's device
+        if it has one."""
         if self.mesh is None:
-            return state
+            return (state if self.device is None
+                    else jax.device_put(state, self.device))
         cfg = self.model.cfg
         return jax.device_put(state, decode_state_shardings(
             state, self.mesh, kv_heads=cfg.n_kv_heads, head_dim=cfg.hd))
+
+    def compile_burst(self, n_slots: int,
+                      enc_len: int) -> jax.stages.Compiled:
+        """Ahead-of-time compile of the greedy decode burst that
+        :meth:`serve` dispatches between admissions, for an ``n_slots``-row
+        grid over sources padded to ``enc_len`` — the serve's own burst
+        width, cache layout and placement.  ``as_text()`` of the result
+        shows which kernels the program calls."""
+        K = self._resolve_burst(None)
+        width = next_pow2(AUTO_MAX_BURST if K == "auto" else K)
+        state = self._shard_state(self.model.init_decode_state(
+            n_slots, self.max_len, quantized=self.quant.quantize_kv,
+            enc_len=enc_len, paged=self.paged, page_size=self.page_size,
+            n_pages=(self._make_allocator(n_slots).n_pages if self.paged
+                     else None)))
+        rows = jnp.zeros((n_slots,), jnp.int32)
+        return self._greedy_burst_fn(width).lower(
+            self.params, rows, rows, jnp.int32(width), state).compile()
 
     def _mesh_result_fields(self, rows: int) -> Dict[str, Any]:
         """ServeResult kwargs describing the mesh the serve ran on."""
@@ -725,21 +802,23 @@ class ServingEngine:
         layer per serving round rides between decode bursts instead of one
         monolithic width-W encode stalling a whole round."""
         if self._stage_begin_jit is None:
-            model, quant = self.model, self.quant
-            self._stage_begin_jit = jax.jit(
+            model, quant, mesh = self.model, self.quant, self.mesh
+            self._stage_begin_jit = jax.jit(on_whole_rows(
                 lambda p, src, lens: model.encode_staged_begin(
-                    p, {"src_tokens": src, "src_lengths": lens}))
-            self._stage_finish_jit = jax.jit(
+                    p, {"src_tokens": src, "src_lengths": lens}), mesh))
+            self._stage_finish_jit = jax.jit(on_whole_rows(
                 lambda p, x, lens: model.encode_staged_finish(
-                    p, x, src_lengths=lens, quant=quant))
+                    p, x, src_lengths=lens, quant=quant), mesh))
         return self._stage_begin_jit, self._stage_finish_jit
 
     def _stage_layer_fn(self, layer_idx: int) -> Callable:
         fn = self._stage_layer_jits.get(layer_idx)
         if fn is None:
             model, quant = self.model, self.quant
-            fn = jax.jit(lambda p, x, lens: model.encode_staged_layer(
-                p, x, layer_idx, src_lengths=lens, quant=quant))
+            fn = jax.jit(on_whole_rows(
+                lambda p, x, lens: model.encode_staged_layer(
+                    p, x, layer_idx, src_lengths=lens, quant=quant),
+                self.mesh))
             self._stage_layer_jits[layer_idx] = fn
         return fn
 
@@ -1038,7 +1117,7 @@ class ServingEngine:
         combination traces its own specialization, a small bounded set),
         which is how zero-width encode/hit rounds cost nothing.
         """
-        model, quant = self.model, self.quant
+        model = self.model
         state = dict(state)
         if self.paged:
             state["cache"] = kvc.free_inactive_paged(state["cache"], live)
@@ -1046,9 +1125,8 @@ class ServingEngine:
             state["cache"] = kvc.free_inactive(state["cache"], live)
         enc_len = adm_src.shape[1]
         if adm_src.shape[0]:
-            ck, cv, slens = model.encode_cross_kv(
-                params, {"src_tokens": adm_src, "src_lengths": adm_lens},
-                quant=quant)
+            ck, cv, slens = self._encode(
+                params, {"src_tokens": adm_src, "src_lengths": adm_lens})
             if "ins_pages" in extra:
                 state["prefix_k"] = kvc.insert_chain_pages(
                     state["prefix_k"], ck, extra["ins_pages"])
@@ -1326,7 +1404,7 @@ class ServingEngine:
         all-False mask reproduces the uniform-width step exactly.
         """
         model, quant, eos = self.model, self.quant, self.eos_id
-        gather_state = self._beam_gather_state
+        gather_state, mesh = self._beam_gather_state, self.mesh
 
         def step_fn(params, tokens, scores, finished, comp, state, buf,
                     step, act_r, parked):
@@ -1334,7 +1412,7 @@ class ServingEngine:
             G = R // beam
             logits, state = model.decode_step(params, tokens, state,
                                               quant=quant)
-            lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            lp = log_probs(logits, mesh)
             V = lp.shape[-1]
             # finished beams only extend with EOS at no cost
             eos_only = jnp.full_like(lp, -1e30).at[:, eos].set(0.0)
@@ -2655,8 +2733,7 @@ class ServingEngine:
             # negated row reproduces jax.lax.top_k exactly (descending
             # values, ties broken by ascending index) on the same float32
             # log-probs generate_beam's device top-k selects from
-            lp = np.asarray(
-                jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1))
+            lp = np.asarray(log_probs(logits, self.mesh))
             first = lp[:rows].reshape(g, beam, -1)[:, 0]     # (g, V)
             tok_host = np.argsort(-first, axis=-1,
                                   kind="stable")[:, :beam].astype(np.int32)
@@ -2969,7 +3046,7 @@ class ServingEngine:
         jax.block_until_ready(logits)
         t1 = time.perf_counter()
 
-        logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        logprobs = log_probs(logits, self.mesh)
         V = logprobs.shape[-1]
         # first step: take top-`beam` distinct tokens of beam 0 per request
         first = logprobs.reshape(B, beam, V)[:, 0]              # (B, V)

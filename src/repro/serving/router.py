@@ -23,8 +23,9 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.models import kv_cache as kvc
@@ -88,6 +89,16 @@ class ReplicaRouter:
         if not engines:
             raise ValueError("ReplicaRouter needs at least one engine")
         self.engines = list(engines)
+
+    @classmethod
+    def on_devices(cls, make_engine: Callable[..., ServingEngine],
+                   n_replicas: int) -> "ReplicaRouter":
+        """``n_replicas`` engines, replica i built by
+        ``make_engine(device=jax.devices()[i % n_devices])``: each replica
+        on its own device while there are enough, round-robin after."""
+        devices = jax.devices()
+        return cls([make_engine(device=devices[i % len(devices)])
+                    for i in range(n_replicas)])
 
     # ------------------------------------------------------------- routing
     def route(self, reqs: Sequence[Request], *, n_slots: int = 8

@@ -179,10 +179,6 @@ def pad_rows_pow2(src: np.ndarray, lens: np.ndarray
     return src, lens, width
 
 
-_EMPTY_I32 = np.zeros((0,), np.int32)
-_EMPTY_I32_2D = np.zeros((0, 0), np.int32)
-
-
 @dataclasses.dataclass
 class AdmissionPlan:
     """One admission round, shaped for the fused decode-burst program.
@@ -208,13 +204,17 @@ class AdmissionPlan:
     # ``requests`` above then holds only the *encode* rows (prefix misses);
     # hits skip the encoder entirely and arrive pre-shaped here.
     hits: List[Request] = dataclasses.field(default_factory=list)
-    hit_rows: np.ndarray = _EMPTY_I32          # (hit_width,) base rows
-    hit_lengths: np.ndarray = _EMPTY_I32       # (hit_width,) source lengths
-    hit_pages: np.ndarray = _EMPTY_I32_2D      # (hit_width, maxPP) chains
+    hit_rows: np.ndarray = dataclasses.field(       # (hit_width,) base rows
+        default_factory=lambda: np.zeros((0,), np.int32))
+    hit_lengths: np.ndarray = dataclasses.field(    # (hit_width,) src lengths
+        default_factory=lambda: np.zeros((0,), np.int32))
+    hit_pages: np.ndarray = dataclasses.field(      # (hit_width, maxPP) chains
+        default_factory=lambda: np.zeros((0, 0), np.int32))
     hit_width: int = 0                         # pow2 (0 = no hits)
     # per-encode-row chain reservations: rows routed "insert" carry their
     # chain's page ids (sentinel-padded); "skip"/padding rows all-sentinel
-    ins_pages: np.ndarray = _EMPTY_I32_2D      # (width, maxPP)
+    ins_pages: np.ndarray = dataclasses.field(      # (width, maxPP)
+        default_factory=lambda: np.zeros((0, 0), np.int32))
     # overload extensions: ``resumed`` requests carry a host spill payload
     # (preempted earlier; the engine restores their KV instead of encoding)
     # and ``staged`` requests have sources past the chunked-prefill budget

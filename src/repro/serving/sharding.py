@@ -18,6 +18,11 @@ What shards where (``tensor`` axis, default ``"model"``):
 * everything else (block tables, lengths, cursors, token ring):
   replicated.
 
+Params (:func:`param_shardings`) follow the training tensor rules, except
+what the encoder path reads (the embedding table, the encoder blocks and
+the cross-attention K/V projections), which every device holds whole: the
+engine runs the encoder whole on each device (``engine.on_whole_rows``).
+
 GQA guard: when ``HKV`` does not divide the tensor axis the pools fall
 back to replicated — mirroring ``_base_spec``'s k/v_proj rule — instead
 of crashing in ``NamedSharding`` construction.  Q heads still shard, so
@@ -33,8 +38,10 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.distributed.sharding import named_shardings
+
 __all__ = ["tp_degree", "kv_pools_shardable", "decode_state_specs",
-           "decode_state_shardings", "mesh_axis_sizes"]
+           "decode_state_shardings", "mesh_axis_sizes", "param_shardings"]
 
 
 def tp_degree(mesh, tensor: str = "model") -> int:
@@ -47,6 +54,31 @@ def tp_degree(mesh, tensor: str = "model") -> int:
 def mesh_axis_sizes(mesh) -> tuple:
     """Mesh shape as a plain tuple in axis order — for ServeResult."""
     return tuple(int(mesh.shape[a]) for a in mesh.axis_names)
+
+
+def param_shardings(params: Any, mesh, *, kv_heads: int,
+                    tensor: str = "model") -> Any:
+    """Shardings for an enc-dec model's params on a serving mesh: the
+    training tensor rules with fsdp off, except that everything the
+    encoder path reads is replicated — the embedding table, the encoder
+    blocks, and each decoder layer's cross-attention K/V projections."""
+    rules = named_shardings(params, mesh, tensor=tensor, fsdp=None,
+                            kv_heads=kv_heads)
+    whole = NamedSharding(mesh, P())
+
+    def replicate(tree):
+        return jax.tree_util.tree_map(lambda _: whole, tree)
+
+    out = {}
+    for k, v in rules.items():
+        if k == "embed" or k.startswith("enc_"):
+            v = replicate(v)
+        elif k.startswith("dec_blocks.") and "cross_attn" in v:
+            v = dict(v, cross_attn=dict(
+                v["cross_attn"], k_proj=replicate(v["cross_attn"]["k_proj"]),
+                v_proj=replicate(v["cross_attn"]["v_proj"])))
+        out[k] = v
+    return out
 
 
 def kv_pools_shardable(mesh, kv_heads: int, tensor: str = "model") -> bool:

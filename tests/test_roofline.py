@@ -14,7 +14,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.launch import roofline
-from repro.launch.hlo_analysis import analyze_collectives, shape_bytes
+from repro.launch.hlo_analysis import (analyze_collectives,
+                                       pallas_kernel_calls, shape_bytes)
 from repro.launch.roofline import (HBM_BW, ICI_BW, PEAK_BF16, PEAK_INT8,
                                    decode_collective_bytes, model_flops,
                                    sharded_decode_cell)
@@ -147,6 +148,23 @@ def test_analyze_collectives_empty_module():
     rec = analyze_collectives("ENTRY %main () -> f32[] {\n  ROOT %c = "
                               "f32[] constant(0)\n}\n")
     assert rec["total_bytes"] == 0 and rec["n_ops"] == 0
+
+
+def test_pallas_kernel_calls_names_each_call_site():
+    def call(name, kernel):
+        return (f'  %{name} = bf16[8,2048] custom-call(%a, %b), '
+                f'custom_call_target="tpu_custom_call", metadata={{op_name='
+                f'"jit(burst)/while/body/jit({kernel})/pallas_call"}}')
+    hlo = "\n".join([
+        call("int8_matmul_pallas.1", "int8_matmul_pallas"),
+        call("int8_matmul_pallas.2", "int8_matmul_pallas"),
+        call("decode_attention_paged_pallas.1",
+             "decode_attention_paged_pallas"),
+        '  %dot.1 = f32[8,8] dot(%a, %b), metadata={op_name="jit(f)/dot"}',
+    ])
+    assert pallas_kernel_calls(hlo) == {"int8_matmul_pallas": 2,
+                                        "decode_attention_paged_pallas": 1}
+    assert pallas_kernel_calls("") == {}
 
 
 # ------------------------------------------------- build_cell term assembly

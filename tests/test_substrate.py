@@ -210,17 +210,18 @@ def test_prop_stream_simulation_invariants(costs, n):
 def test_ef_compression_unbiased_over_steps(rng):
     """Error feedback: accumulated compressed updates converge to the true
     gradient sum over repeated steps (bias is pushed into the residual)."""
-    from repro.distributed import make_mesh, shard_map
-    mesh = make_mesh((1,), ("data",), explicit=True)
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Explicit,))
 
     def one_body(gx, err):
         return tree_ef_compressed_mean(gx, err, "data", 1)
 
-    one = shard_map(one_body, mesh=mesh,
-                    in_specs=(jax.sharding.PartitionSpec(),
-                              jax.sharding.PartitionSpec()),
-                    out_specs=(jax.sharding.PartitionSpec(),
-                               jax.sharding.PartitionSpec()))
+    one = jax.shard_map(one_body, mesh=mesh,
+                        in_specs=(jax.sharding.PartitionSpec(),
+                                  jax.sharding.PartitionSpec()),
+                        out_specs=(jax.sharding.PartitionSpec(),
+                                   jax.sharding.PartitionSpec()),
+                        check_vma=False)
 
     g = jnp.asarray(rng.normal(size=(64,)), jnp.float32)
     err = jnp.zeros_like(g)
