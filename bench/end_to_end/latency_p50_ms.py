@@ -1,0 +1,8 @@
+"""Median wall time of a serve() call, over every call in the window."""
+
+import numpy as np
+
+
+def read(ctx):
+    return float(np.percentile([c.wall_s for c in ctx.window.calls], 50)
+                 * 1e3)
