@@ -92,61 +92,68 @@ class EncDecLM:
     # ---------------------------------------------------------------- encode
     def encode(self, params, batch, *, quant: QuantContext = FP_CONTEXT,
                taps: Optional[Taps] = None, unroll: bool = False) -> jax.Array:
-        cfg = self.cfg
-        dt = cfg.activation_dtype
-        if "src_embeds" in batch:
-            x = batch["src_embeds"].astype(dt)
-        else:
-            x = embed(params["embed"], batch["src_tokens"], dt)
-            x = x * math.sqrt(cfg.d_model)
-        B, S, D = x.shape
-        x = x + sinusoidal_positions(S, D, dt)[None]
-        lengths = batch.get("src_lengths")
+        with jax.named_scope("encoder"):
+            cfg = self.cfg
+            dt = cfg.activation_dtype
+            if "src_embeds" in batch:
+                x = batch["src_embeds"].astype(dt)
+            else:
+                x = embed(params["embed"], batch["src_tokens"], dt)
+                x = x * math.sqrt(cfg.d_model)
+            B, S, D = x.shape
+            x = x + sinusoidal_positions(S, D, dt)[None]
+            lengths = batch.get("src_lengths")
 
-        def block(x, bparams, site):
-            h = norm(bparams["attn_norm"], x, cfg.norm)
-            a, _ = attention(bparams["attn"], h, cfg=cfg, site=f"{site}/attn",
-                             quant=quant, taps=taps, causal=False, rope=False,
-                             kv_lengths=lengths, unroll=unroll)
-            x = x + a
-            h = norm(bparams["ffn_norm"], x, cfg.norm)
-            return x + ffn(bparams["ffn"], h, cfg=cfg, site=f"{site}/ffn",
-                           quant=quant, taps=taps)
+            def block(x, bparams, site):
+                with jax.named_scope("self_attention"):
+                    h = norm(bparams["attn_norm"], x, cfg.norm)
+                    a, _ = attention(bparams["attn"], h, cfg=cfg,
+                                     site=f"{site}/attn", quant=quant,
+                                     taps=taps, causal=False, rope=False,
+                                     kv_lengths=lengths, unroll=unroll)
+                    x = x + a
+                with jax.named_scope("ffn"):
+                    h = norm(bparams["ffn_norm"], x, cfg.norm)
+                    return x + ffn(bparams["ffn"], h, cfg=cfg,
+                                   site=f"{site}/ffn", quant=quant, taps=taps)
 
-        if cfg.scan_layers:
-            def layer(x, bp):
-                f = lambda xx: block(xx, bp, "enc_blocks.*")
-                if cfg.remat:
-                    f = jax.checkpoint(f)
-                return f(constrain(x)), None
-            x, _ = jax.lax.scan(layer, x, params["enc_blocks"])
-        else:
-            for i in range(cfg.n_enc_layers):
-                x = block(x, params[f"enc_blocks.{i}"], f"enc_blocks.{i}")
-        return norm(params["enc_final_norm"], x, cfg.norm)
+            if cfg.scan_layers:
+                def layer(x, bp):
+                    f = lambda xx: block(xx, bp, "enc_blocks.*")
+                    if cfg.remat:
+                        f = jax.checkpoint(f)
+                    return f(constrain(x)), None
+                x, _ = jax.lax.scan(layer, x, params["enc_blocks"])
+            else:
+                for i in range(cfg.n_enc_layers):
+                    x = block(x, params[f"enc_blocks.{i}"], f"enc_blocks.{i}")
+            return norm(params["enc_final_norm"], x, cfg.norm)
 
     # ---------------------------------------------------------------- decode
     def _dec_block(self, bparams, x, memory, *, site, quant, taps, positions,
                    kv_lengths, memory_lengths, unroll, cache_view=None):
         cfg = self.cfg
-        h = norm(bparams["self_norm"], x, cfg.norm)
-        a, entries = attention(
-            bparams["self_attn"], h, cfg=cfg, site=f"{site}/self_attn",
-            quant=quant, taps=taps, positions=positions,
-            kv_lengths=kv_lengths, cache=cache_view, rope=False,
-            unroll=unroll)
-        x = x + a
-        h = norm(bparams["cross_norm"], x, cfg.norm)
-        c, _ = attention(
-            bparams["cross_attn"], h, cfg=cfg, site=f"{site}/cross_attn",
-            quant=quant, taps=taps, memory=memory,
-            memory_lengths=memory_lengths, unroll=unroll,
-            per_query=cache_view is not None)
-        x = x + c
-        h = norm(bparams["ffn_norm"], x, cfg.norm)
-        f = ffn(bparams["ffn"], h, cfg=cfg, site=f"{site}/ffn", quant=quant,
-                taps=taps)
-        return x + f, entries
+        with jax.named_scope("self_attention"):
+            h = norm(bparams["self_norm"], x, cfg.norm)
+            a, entries = attention(
+                bparams["self_attn"], h, cfg=cfg, site=f"{site}/self_attn",
+                quant=quant, taps=taps, positions=positions,
+                kv_lengths=kv_lengths, cache=cache_view, rope=False,
+                unroll=unroll)
+            x = x + a
+        with jax.named_scope("cross_attention"):
+            h = norm(bparams["cross_norm"], x, cfg.norm)
+            c, _ = attention(
+                bparams["cross_attn"], h, cfg=cfg, site=f"{site}/cross_attn",
+                quant=quant, taps=taps, memory=memory,
+                memory_lengths=memory_lengths, unroll=unroll,
+                per_query=cache_view is not None)
+            x = x + c
+        with jax.named_scope("ffn"):
+            h = norm(bparams["ffn_norm"], x, cfg.norm)
+            f = ffn(bparams["ffn"], h, cfg=cfg, site=f"{site}/ffn",
+                    quant=quant, taps=taps)
+            return x + f, entries
 
     def _cross_kv(self, bparams, memory, *, site, quant, taps):
         """Project encoder memory to this layer's cross K/V (done once)."""
@@ -256,27 +263,35 @@ class EncDecLM:
         result across a beam group's rows via :meth:`splice_prefill`
         instead of paying ``beam×`` encoder FLOPs on tiled inputs.
         """
-        cfg = self.cfg
         memory = self.encode(params, batch, quant=quant)
         B = memory.shape[0]
         src_lengths = batch.get(
             "src_lengths", jnp.full((B,), memory.shape[1], jnp.int32))
-
-        if cfg.scan_layers:
-            def layer(_, bp):
-                k, v = self._cross_kv(bp, memory, site="dec_blocks.*",
-                                      quant=quant, taps=None)
-                return None, (k, v)
-            _, (ck, cv) = jax.lax.scan(layer, None, params["dec_blocks"])
-        else:
-            ks, vs = [], []
-            for i in range(cfg.n_layers):
-                k, v = self._cross_kv(params[f"dec_blocks.{i}"], memory,
-                                      site=f"dec_blocks.{i}", quant=quant,
-                                      taps=None)
-                ks.append(k); vs.append(v)
-            ck, cv = jnp.stack(ks), jnp.stack(vs)
+        ck, cv = self._cross_kv_stack(params, memory, quant=quant)
         return ck, cv, src_lengths
+
+    def _cross_kv_stack(self, params, memory, *, quant: QuantContext
+                        ) -> Tuple[jax.Array, jax.Array]:
+        """Every decoder layer's cross K/V from the encoder memory,
+        layer-major ``(L, B, S_enc, HKV, dh)``: encoder work, done once
+        per source."""
+        cfg = self.cfg
+        with jax.named_scope("encoder"):
+            if cfg.scan_layers:
+                def layer(_, bp):
+                    k, v = self._cross_kv(bp, memory, site="dec_blocks.*",
+                                          quant=quant, taps=None)
+                    return None, (k, v)
+                _, (ck, cv) = jax.lax.scan(layer, None, params["dec_blocks"])
+            else:
+                ks, vs = [], []
+                for i in range(cfg.n_layers):
+                    k, v = self._cross_kv(params[f"dec_blocks.{i}"], memory,
+                                          site=f"dec_blocks.{i}", quant=quant,
+                                          taps=None)
+                    ks.append(k); vs.append(v)
+                ck, cv = jnp.stack(ks), jnp.stack(vs)
+            return ck, cv
 
     # ----------------------------------------------- staged (chunked) encode
     # The encoder is bidirectional (every layer attends over the full
@@ -335,20 +350,7 @@ class EncDecLM:
         B = memory.shape[0]
         if src_lengths is None:
             src_lengths = jnp.full((B,), memory.shape[1], jnp.int32)
-        if cfg.scan_layers:
-            def layer(_, bp):
-                k, v = self._cross_kv(bp, memory, site="dec_blocks.*",
-                                      quant=quant, taps=None)
-                return None, (k, v)
-            _, (ck, cv) = jax.lax.scan(layer, None, params["dec_blocks"])
-        else:
-            ks, vs = [], []
-            for i in range(cfg.n_layers):
-                k, v = self._cross_kv(params[f"dec_blocks.{i}"], memory,
-                                      site=f"dec_blocks.{i}", quant=quant,
-                                      taps=None)
-                ks.append(k); vs.append(v)
-            ck, cv = jnp.stack(ks), jnp.stack(vs)
+        ck, cv = self._cross_kv_stack(params, memory, quant=quant)
         return ck, cv, jnp.asarray(src_lengths, jnp.int32)
 
     def splice_prefill(self, state: Dict[str, Any], cross_k: jax.Array,
@@ -471,23 +473,27 @@ class EncDecLM:
             def layer(carry, xs):
                 x, kc, vc, ksc, vsc = carry
                 bp, ck, cv, li = xs
-                kl = jax.lax.dynamic_index_in_dim(kc, li, 0, keepdims=False)
-                vl = jax.lax.dynamic_index_in_dim(vc, li, 0, keepdims=False)
-                ksl = (jax.lax.dynamic_index_in_dim(ksc, li, 0,
-                                                    keepdims=False)
-                       if quantized else None)
-                vsl = (jax.lax.dynamic_index_in_dim(vsc, li, 0,
-                                                    keepdims=False)
-                       if quantized else None)
+                with jax.named_scope("kv_pool"):
+                    kl = jax.lax.dynamic_index_in_dim(kc, li, 0,
+                                                      keepdims=False)
+                    vl = jax.lax.dynamic_index_in_dim(vc, li, 0,
+                                                      keepdims=False)
+                    ksl = (jax.lax.dynamic_index_in_dim(ksc, li, 0,
+                                                        keepdims=False)
+                           if quantized else None)
+                    vsl = (jax.lax.dynamic_index_in_dim(vsc, li, 0,
+                                                        keepdims=False)
+                           if quantized else None)
                 x, e = block_with_cache(x, bp, kl, vl, ksl, vsl, ck, cv,
                                         "dec_blocks.*")
-                kc = jax.lax.dynamic_update_index_in_dim(kc, e[0], li, 0)
-                vc = jax.lax.dynamic_update_index_in_dim(vc, e[1], li, 0)
-                if quantized:
-                    ksc = jax.lax.dynamic_update_index_in_dim(ksc, e[2],
-                                                              li, 0)
-                    vsc = jax.lax.dynamic_update_index_in_dim(vsc, e[3],
-                                                              li, 0)
+                with jax.named_scope("kv_pool"):
+                    kc = jax.lax.dynamic_update_index_in_dim(kc, e[0], li, 0)
+                    vc = jax.lax.dynamic_update_index_in_dim(vc, e[1], li, 0)
+                    if quantized:
+                        ksc = jax.lax.dynamic_update_index_in_dim(
+                            ksc, e[2], li, 0)
+                        vsc = jax.lax.dynamic_update_index_in_dim(
+                            vsc, e[3], li, 0)
                 return (x, kc, vc, ksc, vsc), None
 
             init = (x, cache.k, cache.v,
@@ -502,17 +508,20 @@ class EncDecLM:
         else:
             kL, vL, ksL, vsL = [], [], [], []
             for i in range(cfg.n_layers):
-                ksl = cache.k_scale[i] if cache.quantized else None
-                vsl = cache.v_scale[i] if cache.quantized else None
-                x, e = block_with_cache(
-                    x, params[f"dec_blocks.{i}"], cache.k[i], cache.v[i],
-                    ksl, vsl, state["cross_k"][i], state["cross_v"][i],
-                    f"dec_blocks.{i}")
+                with jax.named_scope("kv_pool"):
+                    kl, vl = cache.k[i], cache.v[i]
+                    ksl = cache.k_scale[i] if cache.quantized else None
+                    vsl = cache.v_scale[i] if cache.quantized else None
+                with jax.named_scope("cross_attention"):
+                    ck, cv = state["cross_k"][i], state["cross_v"][i]
+                x, e = block_with_cache(x, params[f"dec_blocks.{i}"], kl, vl,
+                                        ksl, vsl, ck, cv, f"dec_blocks.{i}")
                 kL.append(e[0]); vL.append(e[1])
                 ksL.append(e[2]); vsL.append(e[3])
-            k_c, v_c = jnp.stack(kL), jnp.stack(vL)
-            ks_c = jnp.stack(ksL) if cache.quantized else None
-            vs_c = jnp.stack(vsL) if cache.quantized else None
+            with jax.named_scope("kv_pool"):
+                k_c, v_c = jnp.stack(kL), jnp.stack(vL)
+                ks_c = jnp.stack(ksL) if cache.quantized else None
+                vs_c = jnp.stack(vsL) if cache.quantized else None
 
         state = dict(state)
         if paged:
@@ -524,6 +533,6 @@ class EncDecLM:
             state["cache"] = kvc.KVCache(k=k_c, v=v_c, k_scale=ks_c,
                                          v_scale=vs_c,
                                          lengths=cache.lengths + T)
-        x = norm(params["dec_final_norm"], x, cfg.norm)
-        logits = unembed(params["embed"], x)
-        return logits, state
+        with jax.named_scope("logits_head"):
+            x = norm(params["dec_final_norm"], x, cfg.norm)
+            return unembed(params["embed"], x), state

@@ -1119,35 +1119,40 @@ class ServingEngine:
         """
         model = self.model
         state = dict(state)
-        if self.paged:
-            state["cache"] = kvc.free_inactive_paged(state["cache"], live)
-        else:
-            state["cache"] = kvc.free_inactive(state["cache"], live)
         enc_len = adm_src.shape[1]
+        with jax.named_scope("admission"):
+            if self.paged:
+                state["cache"] = kvc.free_inactive_paged(state["cache"],
+                                                         live)
+            else:
+                state["cache"] = kvc.free_inactive(state["cache"], live)
         if adm_src.shape[0]:
             ck, cv, slens = self._encode(
                 params, {"src_tokens": adm_src, "src_lengths": adm_lens})
-            if "ins_pages" in extra:
-                state["prefix_k"] = kvc.insert_chain_pages(
-                    state["prefix_k"], ck, extra["ins_pages"])
-                state["prefix_v"] = kvc.insert_chain_pages(
-                    state["prefix_v"], cv, extra["ins_pages"])
-            state = model.splice_prefill(state, ck, cv, slens, adm_rows,
-                                         group=group,
-                                         pages=extra.get("pages"))
-            rows = kvc.group_rows(jnp.asarray(adm_rows, jnp.int32), group)
-            tokens = tokens.at[rows].set(0, mode="drop")           # BOS
+            with jax.named_scope("admission"):
+                if "ins_pages" in extra:
+                    state["prefix_k"] = kvc.insert_chain_pages(
+                        state["prefix_k"], ck, extra["ins_pages"])
+                    state["prefix_v"] = kvc.insert_chain_pages(
+                        state["prefix_v"], cv, extra["ins_pages"])
+                state = model.splice_prefill(state, ck, cv, slens, adm_rows,
+                                             group=group,
+                                             pages=extra.get("pages"))
+                rows = kvc.group_rows(jnp.asarray(adm_rows, jnp.int32),
+                                      group)
+                tokens = tokens.at[rows].set(0, mode="drop")       # BOS
         if "hit_rows" in extra:
-            hk = kvc.gather_chain_pages(state["prefix_k"],
-                                        extra["hit_pages"], enc_len)
-            hv = kvc.gather_chain_pages(state["prefix_v"],
-                                        extra["hit_pages"], enc_len)
-            state = model.splice_prefill(state, hk, hv, extra["hit_lens"],
-                                         extra["hit_rows"], group=group,
-                                         pages=extra.get("hit_dec_pages"))
-            rows = kvc.group_rows(
-                jnp.asarray(extra["hit_rows"], jnp.int32), group)
-            tokens = tokens.at[rows].set(0, mode="drop")           # BOS
+            with jax.named_scope("admission"):
+                hk = kvc.gather_chain_pages(state["prefix_k"],
+                                            extra["hit_pages"], enc_len)
+                hv = kvc.gather_chain_pages(state["prefix_v"],
+                                            extra["hit_pages"], enc_len)
+                state = model.splice_prefill(
+                    state, hk, hv, extra["hit_lens"], extra["hit_rows"],
+                    group=group, pages=extra.get("hit_dec_pages"))
+                rows = kvc.group_rows(
+                    jnp.asarray(extra["hit_rows"], jnp.int32), group)
+                tokens = tokens.at[rows].set(0, mode="drop")       # BOS
         return state, tokens
 
     # ---------------------------------------------------------------- bursts
@@ -1187,7 +1192,8 @@ class ServingEngine:
                 logits, state = model.decode_step(params, tokens, state,
                                                   quant=quant)
                 active = remaining > 0
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("logits_head"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 nxt = jnp.where(active, nxt, eos)
                 buf = buf.at[:, step].set(nxt)
                 remaining = jnp.where(active & (nxt != eos), remaining - 1,
@@ -1412,29 +1418,31 @@ class ServingEngine:
             G = R // beam
             logits, state = model.decode_step(params, tokens, state,
                                               quant=quant)
-            lp = log_probs(logits, mesh)
-            V = lp.shape[-1]
-            # finished beams only extend with EOS at no cost
-            eos_only = jnp.full_like(lp, -1e30).at[:, eos].set(0.0)
-            lp = jnp.where(finished[:, None], eos_only, lp)
-            cand = (scores[:, None] + lp).reshape(G, beam * V)
-            scores_new, flat_idx = jax.lax.top_k(cand, beam)
-            src_beam = flat_idx // V
-            tok_new = (flat_idx % V).reshape(R).astype(jnp.int32)
-            tok_new = jnp.where(parked, eos, tok_new)
-            gidx = (src_beam + jnp.arange(G)[:, None] * beam).reshape(R)
-            gidx = jnp.where(act_r & ~parked, gidx,
-                             jnp.arange(R, dtype=jnp.int32))
-            state = gather_state(state, gidx)
-            tokens = jnp.where(act_r, tok_new, tokens)
-            scores = jnp.where(act_r, scores_new.reshape(R), scores)
-            scores = jnp.where(parked, BEAM_SEED_NEG, scores)
-            finished = jnp.take(finished, gidx, axis=0) | \
-                (act_r & (tokens == eos)) | parked
-            comp = jnp.take(comp, gidx, axis=0)
-            buf = jnp.take(buf, gidx, axis=0)
-            buf = buf.at[:, step].set(jnp.where(act_r, tokens, eos))
-            return tokens, scores, finished, comp, state, buf
+            with jax.named_scope("logits_head"):
+                lp = log_probs(logits, mesh)
+            with jax.named_scope("beam_step"):
+                V = lp.shape[-1]
+                # finished beams only extend with EOS at no cost
+                eos_only = jnp.full_like(lp, -1e30).at[:, eos].set(0.0)
+                lp = jnp.where(finished[:, None], eos_only, lp)
+                cand = (scores[:, None] + lp).reshape(G, beam * V)
+                scores_new, flat_idx = jax.lax.top_k(cand, beam)
+                src_beam = flat_idx // V
+                tok_new = (flat_idx % V).reshape(R).astype(jnp.int32)
+                tok_new = jnp.where(parked, eos, tok_new)
+                gidx = (src_beam + jnp.arange(G)[:, None] * beam).reshape(R)
+                gidx = jnp.where(act_r & ~parked, gidx,
+                                 jnp.arange(R, dtype=jnp.int32))
+                state = gather_state(state, gidx)
+                tokens = jnp.where(act_r, tok_new, tokens)
+                scores = jnp.where(act_r, scores_new.reshape(R), scores)
+                scores = jnp.where(parked, BEAM_SEED_NEG, scores)
+                finished = jnp.take(finished, gidx, axis=0) | \
+                    (act_r & (tokens == eos)) | parked
+                comp = jnp.take(comp, gidx, axis=0)
+                buf = jnp.take(buf, gidx, axis=0)
+                buf = buf.at[:, step].set(jnp.where(act_r, tokens, eos))
+                return tokens, scores, finished, comp, state, buf
 
         return step_fn
 
@@ -1825,83 +1833,85 @@ class ServingEngine:
                 fused_admission=fused_admission, prefix_cache=prefix_cache,
                 overcommit=overcommit, prefill_chunk=prefill_chunk,
                 chaos=chaos)
-        self._check_overload_args(overcommit, prefill_chunk, chaos,
-                                  fused_admission)
-        spec = int(speculative_k or 0)
-        if spec < 0:
-            raise ValueError(f"speculative_k must be >= 0, got {spec}")
-        if spec and not hasattr(self.model, "decode_step_multi"):
-            raise ValueError(
-                "speculative decoding needs a model with decode_step_multi "
-                f"(multi-position verify); {type(self.model).__name__} "
-                "does not provide one")
-        spec_mult = spec + 1
-        K = self._resolve_burst(burst_len)
-        ctrl = self._burst_controller(K)
-        reqs = self._as_requests(requests, max_new_tokens)
-        if not reqs:
-            return ServeResult(requests=[], n_slots=n_slots, decode_steps=0,
-                               busy_slot_steps=0, prefill_rounds=0,
-                               wall_s=0.0, host_syncs=0,
-                               burst_len=ctrl.k if ctrl else K,
-                               fused_admission=fused_admission,
-                               auto_burst=ctrl is not None,
-                               paged=self.paged, page_size=self.page_size,
-                               speculative_k=spec,
-                               **self._mesh_result_fields(n_slots))
-        if max(r.max_new_tokens for r in reqs) > self.max_len:
-            raise ValueError("a request's max_new_tokens exceeds the "
-                             f"engine KV capacity {self.max_len}")
-        width = next_pow2(ctrl.max_burst if ctrl else K)
-        if spec:
-            burst = self._spec_greedy_burst_fn(width, spec)
-            fused_burst = (self._spec_fused_greedy_burst_fn(width, spec)
-                           if fused_admission else None)
-        else:
-            burst = self._greedy_burst_fn(width)
-            fused_burst = (self._fused_greedy_burst_fn(width)
-                           if fused_admission else None)
-        enc_len = self._enc_bucket(reqs, pad_to_multiple)
-        pc = self._resolve_prefix_cache(prefix_cache)
-        stats0 = pc.stats.snapshot() if pc else None
+        with jax.profiler.TraceAnnotation("engine.setup", round=0):
+            self._check_overload_args(overcommit, prefill_chunk, chaos,
+                                      fused_admission)
+            spec = int(speculative_k or 0)
+            if spec < 0:
+                raise ValueError(f"speculative_k must be >= 0, got {spec}")
+            if spec and not hasattr(self.model, "decode_step_multi"):
+                raise ValueError(
+                    "speculative decoding needs a model with "
+                    "decode_step_multi (multi-position verify); "
+                    f"{type(self.model).__name__} does not provide one")
+            spec_mult = spec + 1
+            K = self._resolve_burst(burst_len)
+            ctrl = self._burst_controller(K)
+            reqs = self._as_requests(requests, max_new_tokens)
+            if not reqs:
+                return ServeResult(requests=[], n_slots=n_slots,
+                                   decode_steps=0,
+                                   busy_slot_steps=0, prefill_rounds=0,
+                                   wall_s=0.0, host_syncs=0,
+                                   burst_len=ctrl.k if ctrl else K,
+                                   fused_admission=fused_admission,
+                                   auto_burst=ctrl is not None,
+                                   paged=self.paged, page_size=self.page_size,
+                                   speculative_k=spec,
+                                   **self._mesh_result_fields(n_slots))
+            if max(r.max_new_tokens for r in reqs) > self.max_len:
+                raise ValueError("a request's max_new_tokens exceeds the "
+                                 f"engine KV capacity {self.max_len}")
+            width = next_pow2(ctrl.max_burst if ctrl else K)
+            if spec:
+                burst = self._spec_greedy_burst_fn(width, spec)
+                fused_burst = (self._spec_fused_greedy_burst_fn(width, spec)
+                               if fused_admission else None)
+            else:
+                burst = self._greedy_burst_fn(width)
+                fused_burst = (self._fused_greedy_burst_fn(width)
+                               if fused_admission else None)
+            enc_len = self._enc_bucket(reqs, pad_to_multiple)
+            pc = self._resolve_prefix_cache(prefix_cache)
+            stats0 = pc.stats.snapshot() if pc else None
 
-        allocator = None
-        if self.paged:
-            allocator = self._make_allocator(n_slots, overcommit)
-            for r in reqs:
-                need = self._pages_per_request(r, 1)
-                if need > allocator.n_pages:
-                    raise ValueError(
-                        f"request {r.req_id} needs {need} pages but the "
-                        f"pool holds {allocator.n_pages}")
-        # overcommit: admission allocates only next-burst pages; the loop
-        # grows rows and preempts-by-spill under pressure.  The hint is
-        # the largest step cap a burst can take — under speculation every
-        # macro-step may append up to spec+1 KV positions, so the page
-        # reach scales by spec_mult or accepted writes would be dropped.
-        burst_hint = (ctrl.max_burst if ctrl else K) * spec_mult
-        initial_fn = None
-        if allocator is not None and overcommit > 1.0:
-            initial_fn = lambda r: self._initial_pages(r, 1, burst_hint)
-        sched = ContinuousScheduler(
-            n_slots, prefill_token_budget=prefill_token_budget,
-            allocator=allocator,
-            pages_per_request=(
-                (lambda r: self._pages_per_request(r, 1))
-                if allocator else None),
-            prefix_cache=pc, initial_pages=initial_fn,
-            prefill_chunk=prefill_chunk)
-        sched.submit_many(reqs)
+            allocator = None
+            if self.paged:
+                allocator = self._make_allocator(n_slots, overcommit)
+                for r in reqs:
+                    need = self._pages_per_request(r, 1)
+                    if need > allocator.n_pages:
+                        raise ValueError(
+                            f"request {r.req_id} needs {need} pages but the "
+                            f"pool holds {allocator.n_pages}")
+            # overcommit: admission allocates only next-burst pages; the loop
+            # grows rows and preempts-by-spill under pressure.  The hint is
+            # the largest step cap a burst can take — under speculation every
+            # macro-step may append up to spec+1 KV positions, so the page
+            # reach scales by spec_mult or accepted writes would be dropped.
+            burst_hint = (ctrl.max_burst if ctrl else K) * spec_mult
+            initial_fn = None
+            if allocator is not None and overcommit > 1.0:
+                initial_fn = lambda r: self._initial_pages(r, 1, burst_hint)
+            sched = ContinuousScheduler(
+                n_slots, prefill_token_budget=prefill_token_budget,
+                allocator=allocator,
+                pages_per_request=(
+                    (lambda r: self._pages_per_request(r, 1))
+                    if allocator else None),
+                prefix_cache=pc, initial_pages=initial_fn,
+                prefill_chunk=prefill_chunk)
+            sched.submit_many(reqs)
 
-        quantized = self.quant.quantize_kv
-        state = self.model.init_decode_state(
-            n_slots, self.max_len, quantized=quantized, enc_len=enc_len,
-            paged=self.paged, page_size=self.page_size,
-            n_pages=allocator.n_pages if allocator else None)
-        if pc is not None:
-            state["prefix_k"], state["prefix_v"] = self._prefix_pool
-        state = self._shard_state(state)
-        tokens = jnp.zeros((n_slots,), jnp.int32)
+            quantized = self.quant.quantize_kv
+            state = self.model.init_decode_state(
+                n_slots, self.max_len, quantized=quantized, enc_len=enc_len,
+                paged=self.paged, page_size=self.page_size,
+                n_pages=allocator.n_pages if allocator else None)
+            if pc is not None:
+                state["prefix_k"], state["prefix_v"] = self._prefix_pool
+            state = self._shard_state(state)
+            tokens = jnp.zeros((n_slots,), jnp.int32)
 
         t0 = time.perf_counter()
         now = lambda: time.perf_counter() - t0
@@ -1927,7 +1937,6 @@ class ServingEngine:
         peak_running = 0
         chunked_admissions = 0
         chunk_rounds = 0
-        round_idx = 0
         maxP = self._max_pages
 
         def preempt_req(req: Request) -> None:
@@ -2118,198 +2127,225 @@ class ServingEngine:
                         sched.release(r, t, step=decode_steps)
             return state, tokens
 
+        round_idx = 0
         while not sched.all_done:
             rnd = round_idx
             round_idx += 1
-            # (a) chaos: forced preemptions at this round edge
-            if chaos is not None and sched.slot_map:
-                by_id = {r.req_id: r for r in sched.slot_map.values()}
-                for rid in chaos.victims_for(rnd, list(by_id)):
-                    preempt_req(by_id[rid])
-            # (b) overcommit growth for mid-flight rows (may itself evict);
-            # speculative macro-steps write up to spec+1 positions each
-            grow_rows((ctrl.k if ctrl else K) * spec_mult)
-            # (c) admission pressure: evict strictly-less-urgent victims
-            preempt_for_admission()
-            plan = None
-            admitted = []
-            want_admit = (sched.n_waiting and sched.n_free >=
-                          min(max(admit_min_free, 1), sched.n_waiting,
-                              n_slots))
-            if want_admit and fused_admission:
-                # admission rides the NEXT burst dispatch: the plan's padded
-                # sources/destinations become burst-program inputs
-                plan = sched.plan_admission(now(), step=decode_steps,
-                                            enc_len=enc_len,
-                                            oob_row=n_slots)
-                if plan.n_admitted:
-                    prefill_rounds += 1
-                encoder_tokens += len(plan.requests) * enc_len
-                if plan.resumed:
-                    restore_resumed(plan.resumed)
-                for r in plan.staged:
-                    staging[r.slot] = {"req": r, "x": None, "li": 0,
-                                       "lens": None}
-                chunked_admissions += len(plan.staged)
-                encoder_tokens += len(plan.staged) * enc_len
-            elif want_admit:
-                admitted = sched.admit(now(), step=decode_steps)
-                if admitted:
-                    prefill_rounds += 1
-                    resumed = [r for r in admitted if r.spill is not None]
-                    fresh = [r for r in admitted if r.spill is None]
-                    if resumed:
-                        restore_resumed(resumed)
-                    hits: List[Request] = []
-                    if pc is not None:
-                        # zero-budget requests skip prefix routing: they
-                        # release inside prefill_into_slots before any
-                        # finish() could pair with their admit()
-                        misses, hits = sched.assign_prefix(
-                            [r for r in fresh if r.max_new_tokens > 0])
-                        enc_list = misses + [r for r in fresh
-                                             if r.max_new_tokens <= 0]
+            with jax.profiler.TraceAnnotation("engine.round", round=rnd):
+                with jax.profiler.TraceAnnotation("engine.admit", round=rnd):
+                    # (a) chaos: forced preemptions at this round edge
+                    if chaos is not None and sched.slot_map:
+                        by_id = {r.req_id: r for r in sched.slot_map.values()}
+                        for rid in chaos.victims_for(rnd, list(by_id)):
+                            preempt_req(by_id[rid])
+                    # (b) overcommit growth for mid-flight rows (may itself
+                    # evict); speculative macro-steps write up to spec+1
+                    # positions each
+                    grow_rows((ctrl.k if ctrl else K) * spec_mult)
+                    # (c) admission pressure: evict strictly-less-urgent
+                    # victims
+                    preempt_for_admission()
+                    plan = None
+                    admitted = []
+                    want_admit = (sched.n_waiting and sched.n_free >=
+                                  min(max(admit_min_free, 1), sched.n_waiting,
+                                      n_slots))
+                    if want_admit and fused_admission:
+                        # admission rides the NEXT burst dispatch: the plan's
+                        # padded sources/destinations become burst-program
+                        # inputs
+                        plan = sched.plan_admission(now(), step=decode_steps,
+                                                    enc_len=enc_len,
+                                                    oob_row=n_slots)
+                        if plan.n_admitted:
+                            prefill_rounds += 1
+                        encoder_tokens += len(plan.requests) * enc_len
+                        if plan.resumed:
+                            restore_resumed(plan.resumed)
+                        for r in plan.staged:
+                            staging[r.slot] = {"req": r, "x": None, "li": 0,
+                                               "lens": None}
+                        chunked_admissions += len(plan.staged)
+                        encoder_tokens += len(plan.staged) * enc_len
+                    elif want_admit:
+                        admitted = sched.admit(now(), step=decode_steps)
+                        if admitted:
+                            prefill_rounds += 1
+                            resumed = [r for r in admitted
+                                       if r.spill is not None]
+                            fresh = [r for r in admitted if r.spill is None]
+                            if resumed:
+                                restore_resumed(resumed)
+                            hits: List[Request] = []
+                            if pc is not None:
+                                # zero-budget requests skip prefix routing:
+                                # they release inside prefill_into_slots before
+                                # any finish() could pair with their admit()
+                                misses, hits = sched.assign_prefix(
+                                    [r for r in fresh if r.max_new_tokens > 0])
+                                enc_list = misses + [r for r in fresh
+                                                     if r.max_new_tokens <= 0]
+                            else:
+                                enc_list = fresh
+                            if enc_list:
+                                prefill_dispatches += 1
+                                host_syncs += 1   # the first-token drain syncs
+                                encoder_tokens += len(enc_list) * enc_len
+                                state, tokens = prefill_into_slots(
+                                    enc_list, state, tokens)
+                            if hits:
+                                # no encoder: gather the cached chains and
+                                # defer the first token to the next burst (BOS
+                                # seed)
+                                hrows, hlens, hpages, hw = sched.shape_hits(
+                                    hits, enc_len=enc_len, oob_row=n_slots)
+                                extra = ({"dec_pages": jnp.asarray(
+                                    self._page_rows(hits, 1, hw,
+                                                    allocator.n_pages))}
+                                         if allocator else {})
+                                state, tokens = self._hit_splice_fn(1)(
+                                    state, tokens, jnp.asarray(hpages),
+                                    jnp.asarray(hlens), jnp.asarray(hrows),
+                                    extra)
+                    peak_running = max(peak_running, sched.n_running)
+                    # per-row budgets: every occupied slot has ≥1 token left
+                    # to emit.  Staging slots stay at 0 — they hold no KV yet,
+                    # so the fused prologue treats them as dead (re-sentinels
+                    # their tables) until their chunked encode completes.
+                    remaining = np.zeros((n_slots,), np.int32)
+                    for slot, req in sched.slot_map.items():
+                        if slot in staging:
+                            continue
+                        remaining[slot] = req.max_new_tokens - len(req.tokens)
+                    has_adm = plan is not None and (plan.width
+                                                    or plan.hit_width)
+                if not sched.slot_map:
+                    continue    # every admitted request finished on token 1
+                if not remaining.any() and not has_adm:
+                    # pure-staging round: nothing to decode — push the staged
+                    # encodes one layer and come back
+                    with jax.profiler.TraceAnnotation("engine.free",
+                                                      round=rnd):
+                        advance_staging()
+                    continue
+                with jax.profiler.TraceAnnotation("engine.dispatch",
+                                                  round=rnd):
+                    cap = jnp.asarray(ctrl.k, jnp.int32) if ctrl else cap_fixed
+                    t_dispatch = time.perf_counter()
+                    if plan is not None and (plan.width or plan.hit_width):
+                        extra = {}
+                        if allocator and plan.width:
+                            extra["pages"] = jnp.asarray(self._page_rows(
+                                plan.requests, 1, plan.width,
+                                allocator.n_pages))
+                        if pc is not None and plan.width:
+                            extra["ins_pages"] = jnp.asarray(plan.ins_pages)
+                        if plan.hit_width:
+                            extra["hit_rows"] = jnp.asarray(plan.hit_rows)
+                            extra["hit_lens"] = jnp.asarray(plan.hit_lengths)
+                            extra["hit_pages"] = jnp.asarray(plan.hit_pages)
+                            if allocator:
+                                extra["hit_dec_pages"] = jnp.asarray(
+                                    self._page_rows(plan.hits, 1,
+                                                    plan.hit_width,
+                                                    allocator.n_pages))
+                        tokens, _, state, buf, steps_dev = fused_burst(
+                            self.params, tokens, jnp.asarray(remaining), cap,
+                            state, jnp.asarray(plan.src_tokens),
+                            jnp.asarray(plan.src_lengths),
+                            jnp.asarray(plan.base_rows), extra)
                     else:
-                        enc_list = fresh
-                    if enc_list:
-                        prefill_dispatches += 1
-                        host_syncs += 1   # first-token drain syncs the host
-                        encoder_tokens += len(enc_list) * enc_len
-                        state, tokens = prefill_into_slots(enc_list, state,
-                                                           tokens)
-                    if hits:
-                        # no encoder: gather the cached chains and defer
-                        # the first token to the next burst (BOS seed)
-                        hrows, hlens, hpages, hw = sched.shape_hits(
-                            hits, enc_len=enc_len, oob_row=n_slots)
-                        extra = ({"dec_pages": jnp.asarray(self._page_rows(
-                                     hits, 1, hw, allocator.n_pages))}
-                                 if allocator else {})
-                        state, tokens = self._hit_splice_fn(1)(
-                            state, tokens, jnp.asarray(hpages),
-                            jnp.asarray(hlens), jnp.asarray(hrows), extra)
-            peak_running = max(peak_running, sched.n_running)
-            if not sched.slot_map:
-                continue        # every admitted request finished on token 1
+                        tokens, _, state, buf, steps_dev = burst(
+                            self.params, tokens, jnp.asarray(remaining), cap,
+                            state)
+                with jax.profiler.TraceAnnotation("engine.wait", round=rnd):
+                    buf_host = np.asarray(buf)    # ONE host sync per burst
+                    steps = int(steps_dev)
+                burst_wall = time.perf_counter() - t_dispatch
+                host_syncs += 1
+                step_base = decode_steps
+                decode_steps += steps
 
-            # per-row budgets: every occupied slot has ≥1 token left to
-            # emit.  Staging slots stay at 0 — they hold no KV yet, so the
-            # fused prologue treats them as dead (re-sentinels their
-            # tables) until their chunked encode completes.
-            remaining = np.zeros((n_slots,), np.int32)
-            for slot, req in sched.slot_map.items():
-                if slot in staging:
-                    continue
-                remaining[slot] = req.max_new_tokens - len(req.tokens)
-            has_adm = plan is not None and (plan.width or plan.hit_width)
-            if not remaining.any() and not has_adm:
-                # pure-staging round: nothing to decode — push the staged
-                # encodes one layer and come back
-                advance_staging()
-                continue
-            cap = jnp.asarray(ctrl.k, jnp.int32) if ctrl else cap_fixed
-            t_dispatch = time.perf_counter()
-            if plan is not None and (plan.width or plan.hit_width):
-                extra = {}
-                if allocator and plan.width:
-                    extra["pages"] = jnp.asarray(self._page_rows(
-                        plan.requests, 1, plan.width, allocator.n_pages))
-                if pc is not None and plan.width:
-                    extra["ins_pages"] = jnp.asarray(plan.ins_pages)
-                if plan.hit_width:
-                    extra["hit_rows"] = jnp.asarray(plan.hit_rows)
-                    extra["hit_lens"] = jnp.asarray(plan.hit_lengths)
-                    extra["hit_pages"] = jnp.asarray(plan.hit_pages)
-                    if allocator:
-                        extra["hit_dec_pages"] = jnp.asarray(self._page_rows(
-                            plan.hits, 1, plan.hit_width, allocator.n_pages))
-                tokens, _, state, buf, steps_dev = fused_burst(
-                    self.params, tokens, jnp.asarray(remaining), cap, state,
-                    jnp.asarray(plan.src_tokens),
-                    jnp.asarray(plan.src_lengths),
-                    jnp.asarray(plan.base_rows), extra)
-            else:
-                tokens, _, state, buf, steps_dev = burst(
-                    self.params, tokens, jnp.asarray(remaining), cap, state)
-            buf_host = np.asarray(buf)         # ONE host sync per burst
-            steps = int(steps_dev)
-            burst_wall = time.perf_counter() - t_dispatch
-            host_syncs += 1
-            step_base = decode_steps
-            decode_steps += steps
-
-            # drain the ring buffer: release at EOS / budget exhaustion;
-            # latencies are observed at the burst edge (burst granularity)
-            t = now()
-            freed = []
-            wasted_row_steps = 0
-            emit_col = width * spec_mult    # first packed-counter column
-            for slot, req in list(sched.slot_map.items()):
-                if slot in staging:
-                    # mid-stage rows are inert grid: their ring columns
-                    # are masked EOS, not output (draining one would
-                    # falsely release the request)
-                    wasted_row_steps += steps
-                    continue
-                if req.first_token_s is None:
-                    req.first_token_s = t   # fused: emitted by this burst
-                if spec:
-                    # speculative ring: rows emit different counts per
-                    # macro-step, so the drain is driven by the per-row
-                    # emitted counter, and busy/wasted are counted in
-                    # macro-steps the row was live (act column).  Release
-                    # steps are attributed at burst granularity.
-                    n_emit = int(buf_host[slot, emit_col])
-                    act = int(buf_host[slot, emit_col + 3])
-                    for i in range(n_emit):
-                        tok = int(buf_host[slot, i])
-                        if tok == self.eos_id:
-                            freed.append(sched.release(
-                                req, t, step=step_base + steps))
-                            break
-                        req.tokens.append(tok)
-                        if len(req.tokens) >= req.max_new_tokens:
-                            freed.append(sched.release(
-                                req, t, step=step_base + steps))
-                            break
-                    busy_slot_steps += act
-                    wasted_row_steps += steps - act
-                    draft_tokens += int(buf_host[slot, emit_col + 1])
-                    accepted_tokens += int(buf_host[slot, emit_col + 2])
-                    continue
-                used = steps
-                for s in range(steps):
-                    tok = int(buf_host[slot, s])
-                    if tok == self.eos_id:
-                        used = s + 1
-                        freed.append(sched.release(req, t,
-                                                   step=step_base + s + 1))
-                        break
-                    req.tokens.append(tok)
-                    if len(req.tokens) >= req.max_new_tokens:
-                        used = s + 1
-                        freed.append(sched.release(req, t,
-                                                   step=step_base + s + 1))
-                        break
-                busy_slot_steps += used
-                wasted_row_steps += steps - used
-            if ctrl:
-                ctrl.observe(burst_wall, steps, wasted_row_steps, n_slots)
-            watchdog.observe(burst_wall +
-                             (chaos.slow_for(rnd) if chaos else 0.0))
-            if freed and (not fused_admission or eager_free):
-                # fused mode normally resets dead cursors inside the next
-                # admission burst's prologue — but under growth/preemption
-                # freed pages can be handed out before any prologue runs,
-                # so dead rows are sentineled eagerly here
-                state = dict(state)
-                free = kvc.free_slots_paged if self.paged else kvc.free_slots
-                state["cache"] = free(state["cache"],
-                                      np.asarray(freed, np.int32))
-            # (h) advance chunked prefills one encoder layer, after the
-            # drain so a stage admitted this round runs its first layer
-            # in this round but never rides this round's burst
-            advance_staging()
+                with jax.profiler.TraceAnnotation("engine.drain", round=rnd):
+                    # drain the ring buffer: release at EOS / budget
+                    # exhaustion; latencies are observed at the burst edge
+                    # (burst granularity)
+                    t = now()
+                    freed = []
+                    wasted_row_steps = 0
+                    emit_col = width * spec_mult   # first packed-counter col
+                    for slot, req in list(sched.slot_map.items()):
+                        if slot in staging:
+                            # mid-stage rows are inert grid: their ring columns
+                            # are masked EOS, not output (draining one would
+                            # falsely release the request)
+                            wasted_row_steps += steps
+                            continue
+                        if req.first_token_s is None:
+                            req.first_token_s = t   # fused: from this burst
+                        if spec:
+                            # speculative ring: rows emit different counts per
+                            # macro-step, so the drain is driven by the per-row
+                            # emitted counter, and busy/wasted are counted in
+                            # macro-steps the row was live (act column).
+                            # Release steps are attributed at burst
+                            # granularity.
+                            n_emit = int(buf_host[slot, emit_col])
+                            act = int(buf_host[slot, emit_col + 3])
+                            for i in range(n_emit):
+                                tok = int(buf_host[slot, i])
+                                if tok == self.eos_id:
+                                    freed.append(sched.release(
+                                        req, t, step=step_base + steps))
+                                    break
+                                req.tokens.append(tok)
+                                if len(req.tokens) >= req.max_new_tokens:
+                                    freed.append(sched.release(
+                                        req, t, step=step_base + steps))
+                                    break
+                            busy_slot_steps += act
+                            wasted_row_steps += steps - act
+                            draft_tokens += int(buf_host[slot, emit_col + 1])
+                            accepted_tokens += int(
+                                buf_host[slot, emit_col + 2])
+                            continue
+                        used = steps
+                        for s in range(steps):
+                            tok = int(buf_host[slot, s])
+                            if tok == self.eos_id:
+                                used = s + 1
+                                freed.append(sched.release(
+                                    req, t, step=step_base + s + 1))
+                                break
+                            req.tokens.append(tok)
+                            if len(req.tokens) >= req.max_new_tokens:
+                                used = s + 1
+                                freed.append(sched.release(
+                                    req, t, step=step_base + s + 1))
+                                break
+                        busy_slot_steps += used
+                        wasted_row_steps += steps - used
+                    if ctrl:
+                        ctrl.observe(burst_wall, steps, wasted_row_steps,
+                                     n_slots)
+                    watchdog.observe(burst_wall +
+                                     (chaos.slow_for(rnd) if chaos else 0.0))
+                with jax.profiler.TraceAnnotation("engine.free", round=rnd):
+                    if freed and (not fused_admission or eager_free):
+                        # fused mode normally resets dead cursors inside the
+                        # next admission burst's prologue — but under
+                        # growth/preemption freed pages can be handed out
+                        # before any prologue runs, so dead rows are sentineled
+                        # eagerly
+                        state = dict(state)
+                        free = (kvc.free_slots_paged if self.paged
+                                else kvc.free_slots)
+                        state["cache"] = free(state["cache"],
+                                              np.asarray(freed, np.int32))
+                    # (h) advance chunked prefills one encoder layer, after the
+                    # drain so a stage admitted this round runs its first layer
+                    # in this round but never rides this round's burst
+                    advance_staging()
 
         if pc is not None:
             # hand the (possibly donated-through) pool arrays back to the
@@ -2398,110 +2434,112 @@ class ServingEngine:
         mask, token history, budget) — and resume re-seeds both sides
         bit-identically.
         """
-        self._check_overload_args(overcommit, prefill_chunk, chaos,
-                                  fused_admission)
-        reqs = self._as_requests(requests, max_new_tokens)
-        # resolve each request's effective width WITHOUT mutating the
-        # caller's Request objects (a serve()-written default would stick
-        # to a reused Request and silently shadow a later serve's beam):
-        # an explicit `beam` sequence wins, then a user-set Request.beam,
-        # then the scalar default
-        if isinstance(beam, (list, tuple, np.ndarray)):
-            seq = [int(b) for b in beam]
-            if len(seq) != len(reqs):
-                raise ValueError(f"beam sequence length {len(seq)} != "
-                                 f"{len(reqs)} requests")
-            width_of = {r.req_id: b for r, b in zip(reqs, seq)}
-            default_beam = max(seq) if seq else 1
-        else:
-            default_beam = int(beam)
-            if default_beam < 1:
-                raise ValueError(f"beam must be ≥ 1, got {default_beam}")
-            width_of = {r.req_id: (int(r.beam) if r.beam is not None
-                                   else default_beam) for r in reqs}
-        for r in reqs:
-            if width_of[r.req_id] < 1:
-                raise ValueError(f"beam must be ≥ 1, got "
-                                 f"{width_of[r.req_id]} "
-                                 f"(request {r.req_id})")
-        beam = max(list(width_of.values()) + [default_beam])  # grid width
-        K = self._resolve_burst(burst_len)
-        ctrl = self._burst_controller(K)
-        n_groups = n_slots // beam
-        if n_groups < 1:
-            raise ValueError(f"n_slots={n_slots} rows cannot hold a "
-                             f"beam-{beam} group")
-        R = n_groups * beam                 # rows actually in the grid
-        if not reqs:
-            return ServeResult(requests=[], n_slots=R, decode_steps=0,
-                               busy_slot_steps=0, prefill_rounds=0,
-                               wall_s=0.0, host_syncs=0,
-                               burst_len=ctrl.k if ctrl else K,
-                               beam=beam, fused_admission=fused_admission,
-                               auto_burst=ctrl is not None,
-                               paged=self.paged, page_size=self.page_size,
-                               **self._mesh_result_fields(R))
-        if max(r.max_new_tokens for r in reqs) > self.max_len:
-            raise ValueError("a request's max_new_tokens exceeds the "
-                             f"engine KV capacity {self.max_len}")
-        width = next_pow2(ctrl.max_burst if ctrl else K)
-        burst = self._beam_serve_burst_fn(width, beam)
-        fused_burst = (self._fused_beam_serve_burst_fn(width, beam)
-                       if fused_admission else None)
-        enc_len = self._enc_bucket(reqs, pad_to_multiple)
-        pc = self._resolve_prefix_cache(prefix_cache)
-        stats0 = pc.stats.snapshot() if pc else None
-
-        allocator = None
-        if self.paged:
-            allocator = self._make_allocator(R, overcommit)
+        with jax.profiler.TraceAnnotation("engine.setup", round=0):
+            self._check_overload_args(overcommit, prefill_chunk, chaos,
+                                      fused_admission)
+            reqs = self._as_requests(requests, max_new_tokens)
+            # resolve each request's effective width WITHOUT mutating the
+            # caller's Request objects (a serve()-written default would stick
+            # to a reused Request and silently shadow a later serve's beam):
+            # an explicit `beam` sequence wins, then a user-set Request.beam,
+            # then the scalar default
+            if isinstance(beam, (list, tuple, np.ndarray)):
+                seq = [int(b) for b in beam]
+                if len(seq) != len(reqs):
+                    raise ValueError(f"beam sequence length {len(seq)} != "
+                                     f"{len(reqs)} requests")
+                width_of = {r.req_id: b for r, b in zip(reqs, seq)}
+                default_beam = max(seq) if seq else 1
+            else:
+                default_beam = int(beam)
+                if default_beam < 1:
+                    raise ValueError(f"beam must be ≥ 1, got {default_beam}")
+                width_of = {r.req_id: (int(r.beam) if r.beam is not None
+                                       else default_beam) for r in reqs}
             for r in reqs:
-                need = self._pages_per_request(r, width_of[r.req_id])
-                if need > allocator.n_pages:
-                    raise ValueError(
-                        f"request {r.req_id} needs {need} pages but the "
-                        f"pool holds {allocator.n_pages}")
-        burst_hint = ctrl.max_burst if ctrl else K
-        initial_fn = None
-        if allocator is not None and overcommit > 1.0:
-            initial_fn = lambda r: self._initial_pages(
-                r, width_of[r.req_id], burst_hint)
-        sched = ContinuousScheduler(
-            R, group_size=beam, prefill_token_budget=prefill_token_budget,
-            allocator=allocator,
-            pages_per_request=(
-                (lambda r: self._pages_per_request(r, width_of[r.req_id]))
-                if allocator else None),
-            prefix_cache=pc, initial_pages=initial_fn,
-            prefill_chunk=prefill_chunk)
-        sched.submit_many(reqs)
+                if width_of[r.req_id] < 1:
+                    raise ValueError(f"beam must be ≥ 1, got "
+                                     f"{width_of[r.req_id]} "
+                                     f"(request {r.req_id})")
+            beam = max(list(width_of.values()) + [default_beam])  # grid width
+            K = self._resolve_burst(burst_len)
+            ctrl = self._burst_controller(K)
+            n_groups = n_slots // beam
+            if n_groups < 1:
+                raise ValueError(f"n_slots={n_slots} rows cannot hold a "
+                                 f"beam-{beam} group")
+            R = n_groups * beam                 # rows actually in the grid
+            if not reqs:
+                return ServeResult(requests=[], n_slots=R, decode_steps=0,
+                                   busy_slot_steps=0, prefill_rounds=0,
+                                   wall_s=0.0, host_syncs=0,
+                                   burst_len=ctrl.k if ctrl else K,
+                                   beam=beam, fused_admission=fused_admission,
+                                   auto_burst=ctrl is not None,
+                                   paged=self.paged, page_size=self.page_size,
+                                   **self._mesh_result_fields(R))
+            if max(r.max_new_tokens for r in reqs) > self.max_len:
+                raise ValueError("a request's max_new_tokens exceeds the "
+                                 f"engine KV capacity {self.max_len}")
+            width = next_pow2(ctrl.max_burst if ctrl else K)
+            burst = self._beam_serve_burst_fn(width, beam)
+            fused_burst = (self._fused_beam_serve_burst_fn(width, beam)
+                           if fused_admission else None)
+            enc_len = self._enc_bucket(reqs, pad_to_multiple)
+            pc = self._resolve_prefix_cache(prefix_cache)
+            stats0 = pc.stats.snapshot() if pc else None
 
-        quantized = self.quant.quantize_kv
-        state = self.model.init_decode_state(
-            R, self.max_len, quantized=quantized, enc_len=enc_len,
-            paged=self.paged, page_size=self.page_size,
-            n_pages=allocator.n_pages if allocator else None)
-        if pc is not None:
-            state["prefix_k"], state["prefix_v"] = self._prefix_pool
-        state = self._shard_state(state)
-        tokens = jnp.zeros((R,), jnp.int32)
-        # bytes one beam step's cache reorder moves: paged = the table
-        # permutation + one partial-page copy per row; unpaged = the whole
-        # KV slab plus the per-row cross-K/V gather
-        cache0 = state["cache"]
-        if self.paged:
-            reorder_step_bytes = cache0.reorder_bytes_per_step()
-        else:
-            cross_bytes = 0
-            if state["cross_k"] is not None:
-                cross_bytes = 2 * (state["cross_k"].size
-                                   * state["cross_k"].dtype.itemsize)
-            reorder_step_bytes = cache0.nbytes() + cross_bytes
-        # host-side per-row beam state (re-uploaded each burst, bit-exact)
-        scores_np = np.zeros((R,), np.float32)
-        finished_np = np.ones((R,), bool)        # unoccupied rows are inert
-        histories: Dict[int, List[np.ndarray]] = {}  # base → (beam,) columns
-        budget_left: Dict[int, int] = {}             # base → decode steps left
+            allocator = None
+            if self.paged:
+                allocator = self._make_allocator(R, overcommit)
+                for r in reqs:
+                    need = self._pages_per_request(r, width_of[r.req_id])
+                    if need > allocator.n_pages:
+                        raise ValueError(
+                            f"request {r.req_id} needs {need} pages but the "
+                            f"pool holds {allocator.n_pages}")
+            burst_hint = ctrl.max_burst if ctrl else K
+            initial_fn = None
+            if allocator is not None and overcommit > 1.0:
+                initial_fn = lambda r: self._initial_pages(
+                    r, width_of[r.req_id], burst_hint)
+            sched = ContinuousScheduler(
+                R, group_size=beam, prefill_token_budget=prefill_token_budget,
+                allocator=allocator,
+                pages_per_request=(
+                    (lambda r: self._pages_per_request(r, width_of[r.req_id]))
+                    if allocator else None),
+                prefix_cache=pc, initial_pages=initial_fn,
+                prefill_chunk=prefill_chunk)
+            sched.submit_many(reqs)
+
+            quantized = self.quant.quantize_kv
+            state = self.model.init_decode_state(
+                R, self.max_len, quantized=quantized, enc_len=enc_len,
+                paged=self.paged, page_size=self.page_size,
+                n_pages=allocator.n_pages if allocator else None)
+            if pc is not None:
+                state["prefix_k"], state["prefix_v"] = self._prefix_pool
+            state = self._shard_state(state)
+            tokens = jnp.zeros((R,), jnp.int32)
+            # bytes one beam step's cache reorder moves: paged = the table
+            # permutation + one partial-page copy per row; unpaged = the whole
+            # KV slab plus the per-row cross-K/V gather
+            cache0 = state["cache"]
+            if self.paged:
+                reorder_step_bytes = cache0.reorder_bytes_per_step()
+            else:
+                cross_bytes = 0
+                if state["cross_k"] is not None:
+                    cross_bytes = 2 * (state["cross_k"].size
+                                       * state["cross_k"].dtype.itemsize)
+                reorder_step_bytes = cache0.nbytes() + cross_bytes
+            # host-side per-row beam state (re-uploaded each burst, bit-exact)
+            scores_np = np.zeros((R,), np.float32)
+            finished_np = np.ones((R,), bool)   # unoccupied rows are inert
+            # base → its (beam,) columns; base → decode steps left
+            histories: Dict[int, List[np.ndarray]] = {}
+            budget_left: Dict[int, int] = {}
 
         t0 = time.perf_counter()
         now = lambda: time.perf_counter() - t0
@@ -2522,7 +2560,6 @@ class ServingEngine:
         peak_running = 0
         chunked_admissions = 0
         chunk_rounds = 0
-        round_idx = 0
         maxP = self._max_pages
 
         def preempt_req(req: Request) -> None:
@@ -2778,219 +2815,246 @@ class ServingEngine:
                     finalize(r, base, t, step=decode_steps)
             return state, tokens
 
+        round_idx = 0
         while not sched.all_done:
             rnd = round_idx
             round_idx += 1
-            # (a) chaos: forced preemptions at this round edge
-            if chaos is not None and sched.slot_map:
-                by_id = {r.req_id: r for r in sched.slot_map.values()}
-                for rid in chaos.victims_for(rnd, list(by_id)):
-                    preempt_req(by_id[rid])
-            # (b) overcommit growth for mid-flight groups (may itself evict)
-            grow_rows(ctrl.k if ctrl else K)
-            # (c) admission pressure: evict strictly-less-urgent victims
-            preempt_for_admission()
-            plan = None
-            admitted = []
-            want_admit = (sched.n_waiting and sched.n_free >=
-                          min(max(admit_min_free, 1), sched.n_waiting,
-                              n_groups))
-            if want_admit and fused_admission:
-                # encode-once fused admission: the plan carries ONE source
-                # row per request; the burst program broadcasts it across
-                # the group's rows.  Host seeds the group's beam state so
-                # the shared step's first iteration IS generate_beam's
-                # first step (see _make_fused_beam_serve_burst).
-                plan = sched.plan_admission(now(), step=decode_steps,
-                                            enc_len=enc_len, oob_row=R)
-                if plan.n_admitted:
-                    prefill_rounds += 1
-                encoder_tokens += len(plan.requests) * enc_len
-                if plan.resumed:
-                    restore_resumed(plan.resumed)
-                for r in plan.staged:
-                    staging[r.slot] = {"req": r, "x": None, "li": 0,
-                                       "lens": None}
-                chunked_admissions += len(plan.staged)
-                encoder_tokens += len(plan.staged) * enc_len
-                for r in plan.requests + plan.hits:
-                    base, b = r.slot, width_of[r.req_id]
-                    scores_np[base] = 0.0
-                    scores_np[base + 1:base + beam] = BEAM_SEED_NEG
-                    finished_np[base:base + b] = False
-                    finished_np[base + b:base + beam] = True   # parked tail
-                    histories[base] = []
-                    budget_left[base] = r.max_new_tokens
-            elif want_admit:
-                admitted = sched.admit(now(), step=decode_steps)
-                if admitted:
-                    prefill_rounds += 1
-                    resumed = [r for r in admitted if r.spill is not None]
-                    fresh = [r for r in admitted if r.spill is None]
-                    if resumed:
-                        restore_resumed(resumed)
-                    hits: List[Request] = []
-                    if pc is not None:
-                        # zero-budget requests skip prefix routing: they
-                        # release inside prefill_groups before any
-                        # finish() could pair with their admit()
-                        misses, hits = sched.assign_prefix(
-                            [r for r in fresh if r.max_new_tokens > 0])
-                        enc_list = misses + [r for r in fresh
-                                             if r.max_new_tokens <= 0]
-                    else:
-                        enc_list = fresh
-                    if enc_list:
-                        prefill_dispatches += 1
-                        host_syncs += 1   # first-token drain syncs the host
-                        # the unfused side batch tiles each source beam×
-                        # through the encoder — the FLOP tax encode-once
-                        # fusion removes
-                        encoder_tokens += len(enc_list) * beam * enc_len
-                        state, tokens = prefill_groups(enc_list, state,
-                                                       tokens)
-                    if hits:
-                        # no encoder: gather cached chains, splice them
-                        # across each group's rows, and seed the group
-                        # exactly like fused admission (first tokens arrive
-                        # with the next burst, in final beam order)
-                        hrows, hlens, hpages, hw = sched.shape_hits(
-                            hits, enc_len=enc_len, oob_row=R)
-                        extra = ({"dec_pages": jnp.asarray(self._page_rows(
-                                     hits, beam, hw, allocator.n_pages,
-                                     widths=[width_of[r.req_id]
-                                             for r in hits]))}
-                                 if allocator else {})
-                        state, tokens = self._hit_splice_fn(beam)(
-                            state, tokens, jnp.asarray(hpages),
-                            jnp.asarray(hlens), jnp.asarray(hrows), extra)
-                        for r in hits:
+            with jax.profiler.TraceAnnotation("engine.round", round=rnd):
+                with jax.profiler.TraceAnnotation("engine.admit", round=rnd):
+                    # (a) chaos: forced preemptions at this round edge
+                    if chaos is not None and sched.slot_map:
+                        by_id = {r.req_id: r for r in sched.slot_map.values()}
+                        for rid in chaos.victims_for(rnd, list(by_id)):
+                            preempt_req(by_id[rid])
+                    # (b) overcommit growth for mid-flight groups (may evict)
+                    grow_rows(ctrl.k if ctrl else K)
+                    # (c) admission pressure: evict strictly-less-urgent
+                    # victims
+                    preempt_for_admission()
+                    plan = None
+                    admitted = []
+                    want_admit = (sched.n_waiting and sched.n_free >=
+                                  min(max(admit_min_free, 1), sched.n_waiting,
+                                      n_groups))
+                    if want_admit and fused_admission:
+                        # encode-once fused admission: the plan carries ONE
+                        # source row per request; the burst program broadcasts
+                        # it across the group's rows.  Host seeds the group's
+                        # beam state so the shared step's first iteration IS
+                        # generate_beam's first step (see
+                        # _make_fused_beam_serve_burst).
+                        plan = sched.plan_admission(now(), step=decode_steps,
+                                                    enc_len=enc_len, oob_row=R)
+                        if plan.n_admitted:
+                            prefill_rounds += 1
+                        encoder_tokens += len(plan.requests) * enc_len
+                        if plan.resumed:
+                            restore_resumed(plan.resumed)
+                        for r in plan.staged:
+                            staging[r.slot] = {"req": r, "x": None, "li": 0,
+                                               "lens": None}
+                        chunked_admissions += len(plan.staged)
+                        encoder_tokens += len(plan.staged) * enc_len
+                        for r in plan.requests + plan.hits:
                             base, b = r.slot, width_of[r.req_id]
                             scores_np[base] = 0.0
                             scores_np[base + 1:base + beam] = BEAM_SEED_NEG
                             finished_np[base:base + b] = False
-                            finished_np[base + b:base + beam] = True
+                            finished_np[base + b:base + beam] = True  # parked
                             histories[base] = []
                             budget_left[base] = r.max_new_tokens
-            peak_running = max(peak_running, sched.n_running)
-            if not sched.slot_map:
-                continue    # every admitted group finished on token 1
-
-            # staging groups stay at budget 0 / finished rows — they hold
-            # no KV yet; the fused prologue re-sentinels their tables and
-            # the burst's act mask keeps their rows frozen
-            remaining_in = np.zeros((n_groups,), np.int32)
-            parked_np = np.zeros((R,), bool)
-            for base, req in sched.slot_map.items():
-                if base in staging:
+                    elif want_admit:
+                        admitted = sched.admit(now(), step=decode_steps)
+                        if admitted:
+                            prefill_rounds += 1
+                            resumed = [r for r in admitted
+                                       if r.spill is not None]
+                            fresh = [r for r in admitted if r.spill is None]
+                            if resumed:
+                                restore_resumed(resumed)
+                            hits: List[Request] = []
+                            if pc is not None:
+                                # zero-budget requests skip prefix routing:
+                                # they release inside prefill_groups before any
+                                # finish() could pair with their admit()
+                                misses, hits = sched.assign_prefix(
+                                    [r for r in fresh if r.max_new_tokens > 0])
+                                enc_list = misses + [r for r in fresh
+                                                     if r.max_new_tokens <= 0]
+                            else:
+                                enc_list = fresh
+                            if enc_list:
+                                prefill_dispatches += 1
+                                host_syncs += 1   # the first-token drain syncs
+                                # the unfused side batch tiles each source
+                                # beam× through the encoder — the FLOP tax
+                                # encode-once fusion removes
+                                encoder_tokens += (len(enc_list) * beam
+                                                   * enc_len)
+                                state, tokens = prefill_groups(enc_list, state,
+                                                               tokens)
+                            if hits:
+                                # no encoder: gather cached chains, splice them
+                                # across each group's rows, and seed the group
+                                # exactly like fused admission (first tokens
+                                # arrive with the next burst, in final beam
+                                # order)
+                                hrows, hlens, hpages, hw = sched.shape_hits(
+                                    hits, enc_len=enc_len, oob_row=R)
+                                extra = ({"dec_pages": jnp.asarray(
+                                    self._page_rows(
+                                        hits, beam, hw, allocator.n_pages,
+                                        widths=[width_of[r.req_id]
+                                                for r in hits]))}
+                                         if allocator else {})
+                                state, tokens = self._hit_splice_fn(beam)(
+                                    state, tokens, jnp.asarray(hpages),
+                                    jnp.asarray(hlens), jnp.asarray(hrows),
+                                    extra)
+                                for r in hits:
+                                    base, b = r.slot, width_of[r.req_id]
+                                    scores_np[base] = 0.0
+                                    scores_np[base + 1:base + beam] = \
+                                        BEAM_SEED_NEG
+                                    finished_np[base:base + b] = False
+                                    finished_np[base + b:base + beam] = True
+                                    histories[base] = []
+                                    budget_left[base] = r.max_new_tokens
+                    peak_running = max(peak_running, sched.n_running)
+                    # staging groups stay at budget 0 / finished rows — they
+                    # hold no KV yet; the fused prologue re-sentinels their
+                    # tables and the burst's act mask keeps their rows frozen
+                    remaining_in = np.zeros((n_groups,), np.int32)
+                    parked_np = np.zeros((R,), bool)
+                    for base, req in sched.slot_map.items():
+                        if base in staging:
+                            continue
+                        remaining_in[base // beam] = budget_left[base]
+                        parked_np[base + width_of[req.req_id]:
+                                  base + beam] = True
+                    has_adm = plan is not None and (plan.width
+                                                    or plan.hit_width)
+                if not sched.slot_map:
+                    continue    # every admitted group finished on token 1
+                if not remaining_in.any() and not has_adm:
+                    # pure-staging round: nothing to decode — push the staged
+                    # encodes one layer and come back
+                    with jax.profiler.TraceAnnotation("engine.free",
+                                                      round=rnd):
+                        advance_staging()
                     continue
-                remaining_in[base // beam] = budget_left[base]
-                parked_np[base + width_of[req.req_id]:base + beam] = True
-            has_adm = plan is not None and (plan.width or plan.hit_width)
-            if not remaining_in.any() and not has_adm:
-                # pure-staging round: nothing to decode — push the staged
-                # encodes one layer and come back
-                advance_staging()
-                continue
-            parked = jnp.asarray(parked_np)
-            cap = jnp.asarray(ctrl.k, jnp.int32) if ctrl else cap_fixed
-            t_dispatch = time.perf_counter()
-            if plan is not None and (plan.width or plan.hit_width):
-                extra = {}
-                if allocator and plan.width:
-                    extra["pages"] = jnp.asarray(self._page_rows(
-                        plan.requests, beam, plan.width, allocator.n_pages,
-                        widths=[width_of[r.req_id]
-                                for r in plan.requests]))
-                if pc is not None and plan.width:
-                    extra["ins_pages"] = jnp.asarray(plan.ins_pages)
-                if plan.hit_width:
-                    extra["hit_rows"] = jnp.asarray(plan.hit_rows)
-                    extra["hit_lens"] = jnp.asarray(plan.hit_lengths)
-                    extra["hit_pages"] = jnp.asarray(plan.hit_pages)
-                    if allocator:
-                        extra["hit_dec_pages"] = jnp.asarray(self._page_rows(
-                            plan.hits, beam, plan.hit_width,
-                            allocator.n_pages,
-                            widths=[width_of[r.req_id]
-                                    for r in plan.hits]))
-                (tokens, scores_dev, finished_dev, remaining_dev, comp,
-                 state, buf, steps_dev) = fused_burst(
-                    self.params, tokens, jnp.asarray(scores_np),
-                    jnp.asarray(finished_np), jnp.asarray(remaining_in),
-                    cap, state, parked, jnp.asarray(plan.src_tokens),
-                    jnp.asarray(plan.src_lengths),
-                    jnp.asarray(plan.base_rows), extra)
-            else:
-                (tokens, scores_dev, finished_dev, remaining_dev, comp,
-                 state, buf, steps_dev) = burst(
-                    self.params, tokens, jnp.asarray(scores_np),
-                    jnp.asarray(finished_np), jnp.asarray(remaining_in),
-                    cap, state, parked)
-            buf_host = np.asarray(buf)         # ONE host sync per burst
-            comp_host = np.asarray(comp)
-            scores_np = np.array(scores_dev, np.float32)
-            finished_np = np.array(finished_dev, bool)
-            remaining_out = np.asarray(remaining_dev)
-            steps = int(steps_dev)
-            burst_wall = time.perf_counter() - t_dispatch
-            host_syncs += 1
-            step_base = decode_steps
-            decode_steps += steps
+                with jax.profiler.TraceAnnotation("engine.dispatch",
+                                                  round=rnd):
+                    parked = jnp.asarray(parked_np)
+                    cap = jnp.asarray(ctrl.k, jnp.int32) if ctrl else cap_fixed
+                    t_dispatch = time.perf_counter()
+                    if plan is not None and (plan.width or plan.hit_width):
+                        extra = {}
+                        if allocator and plan.width:
+                            extra["pages"] = jnp.asarray(self._page_rows(
+                                plan.requests, beam, plan.width,
+                                allocator.n_pages, widths=[width_of[r.req_id]
+                                        for r in plan.requests]))
+                        if pc is not None and plan.width:
+                            extra["ins_pages"] = jnp.asarray(plan.ins_pages)
+                        if plan.hit_width:
+                            extra["hit_rows"] = jnp.asarray(plan.hit_rows)
+                            extra["hit_lens"] = jnp.asarray(plan.hit_lengths)
+                            extra["hit_pages"] = jnp.asarray(plan.hit_pages)
+                            if allocator:
+                                extra["hit_dec_pages"] = jnp.asarray(
+                                    self._page_rows(
+                                        plan.hits, beam, plan.hit_width,
+                                        allocator.n_pages,
+                                        widths=[width_of[r.req_id]
+                                                for r in plan.hits]))
+                        (tokens, scores_dev, finished_dev, remaining_dev, comp,
+                         state, buf, steps_dev) = fused_burst(
+                            self.params, tokens, jnp.asarray(scores_np),
+                            jnp.asarray(finished_np),
+                            jnp.asarray(remaining_in), cap, state, parked,
+                            jnp.asarray(plan.src_tokens),
+                            jnp.asarray(plan.src_lengths),
+                            jnp.asarray(plan.base_rows), extra)
+                    else:
+                        (tokens, scores_dev, finished_dev, remaining_dev, comp,
+                         state, buf, steps_dev) = burst(
+                            self.params, tokens, jnp.asarray(scores_np),
+                            jnp.asarray(finished_np),
+                            jnp.asarray(remaining_in), cap, state, parked)
+                with jax.profiler.TraceAnnotation("engine.wait", round=rnd):
+                    buf_host = np.asarray(buf)    # ONE host sync per burst
+                    comp_host = np.asarray(comp)
+                    scores_np = np.array(scores_dev, np.float32)
+                    finished_np = np.array(finished_dev, bool)
+                    remaining_out = np.asarray(remaining_dev)
+                    steps = int(steps_dev)
+                burst_wall = time.perf_counter() - t_dispatch
+                host_syncs += 1
+                step_base = decode_steps
+                decode_steps += steps
 
-            # drain at the burst edge: replay each group's composed beam
-            # permutation over its host-side history, append its new ring
-            # columns, finalize groups that finished or spent their budget
-            t = now()
-            freed = []
-            wasted_row_steps = 0
-            for base, req in list(sched.slot_map.items()):
-                if base in staging:
-                    # staged encode in flight: the group's rows rode the
-                    # burst frozen (finished, budget 0) — pure overhead
-                    wasted_row_steps += steps * beam
-                    continue
-                gi = base // beam
-                s_g = int(remaining_in[gi] - remaining_out[gi])
-                if req.first_token_s is None:
-                    req.first_token_s = t   # fused: emitted by this burst
-                if s_g:
-                    local = comp_host[base:base + beam] - base
-                    hist = [c[local] for c in histories[base]]
-                    hist.extend(buf_host[base:base + beam, j]
-                                for j in range(s_g))
-                    histories[base] = hist
-                    budget_left[base] -= s_g
-                # parked rows of narrow requests are computed-but-idle grid
-                b_req = width_of[req.req_id]
-                busy_slot_steps += s_g * b_req
-                wasted_row_steps += (steps - s_g) * beam + \
-                    s_g * (beam - b_req)
-                if finished_np[base:base + beam].all() or \
-                        budget_left[base] <= 0:
-                    freed.append(finalize(req, base, t,
-                                          step=step_base + s_g))
-            if ctrl:
-                ctrl.observe(burst_wall, steps, wasted_row_steps, R)
-            watchdog.observe(burst_wall +
-                             (chaos.slow_for(rnd) if chaos else 0.0))
-            if freed and (not fused_admission or eager_free):
-                # fused mode resets dead cursors inside the next admission
-                # burst's prologue (kv_cache.free_inactive) — no dispatch.
-                # Under overcommit/chaos, free eagerly even then: growth or
-                # resume may hand the freed pages to another group before
-                # any admission prologue runs, and the dead group's stale
-                # block table would route masked-but-real writes into them.
-                state = dict(state)
-                if self.paged:
-                    state["cache"] = kvc.free_slots_paged(
-                        state["cache"],
-                        kvc.group_rows(np.asarray(freed, np.int32), beam))
-                else:
-                    state["cache"] = kvc.free_groups(
-                        state["cache"], np.asarray(freed, np.int32), beam)
-            # staged encodes advance one layer per serving round
-            advance_staging()
+                with jax.profiler.TraceAnnotation("engine.drain", round=rnd):
+                    # drain at the burst edge: replay each group's composed
+                    # beam permutation over its host-side history, append its
+                    # new ring columns, finalize groups that finished or spent
+                    # their budget
+                    t = now()
+                    freed = []
+                    wasted_row_steps = 0
+                    for base, req in list(sched.slot_map.items()):
+                        if base in staging:
+                            # staged encode in flight: the group's rows rode
+                            # the burst frozen (finished, budget 0) — pure
+                            # overhead
+                            wasted_row_steps += steps * beam
+                            continue
+                        gi = base // beam
+                        s_g = int(remaining_in[gi] - remaining_out[gi])
+                        if req.first_token_s is None:
+                            req.first_token_s = t   # fused: from this burst
+                        if s_g:
+                            local = comp_host[base:base + beam] - base
+                            hist = [c[local] for c in histories[base]]
+                            hist.extend(buf_host[base:base + beam, j]
+                                        for j in range(s_g))
+                            histories[base] = hist
+                            budget_left[base] -= s_g
+                        # parked rows of narrow requests are computed-but-idle
+                        # grid
+                        b_req = width_of[req.req_id]
+                        busy_slot_steps += s_g * b_req
+                        wasted_row_steps += (steps - s_g) * beam + \
+                            s_g * (beam - b_req)
+                        if finished_np[base:base + beam].all() or \
+                                budget_left[base] <= 0:
+                            freed.append(finalize(req, base, t,
+                                                  step=step_base + s_g))
+                    if ctrl:
+                        ctrl.observe(burst_wall, steps, wasted_row_steps, R)
+                    watchdog.observe(burst_wall +
+                                     (chaos.slow_for(rnd) if chaos else 0.0))
+                with jax.profiler.TraceAnnotation("engine.free", round=rnd):
+                    if freed and (not fused_admission or eager_free):
+                        # fused mode resets dead cursors inside the next
+                        # admission burst's prologue (kv_cache.free_inactive) —
+                        # no dispatch.  Under overcommit/chaos, free eagerly
+                        # even then: growth or resume may hand the freed pages
+                        # to another group before any admission prologue runs,
+                        # and the dead group's stale block table would route
+                        # masked-but-real writes into them.
+                        state = dict(state)
+                        if self.paged:
+                            state["cache"] = kvc.free_slots_paged(
+                                state["cache"],
+                                kvc.group_rows(np.asarray(freed, np.int32),
+                                               beam))
+                        else:
+                            state["cache"] = kvc.free_groups(
+                                state["cache"], np.asarray(freed, np.int32),
+                                beam)
+                    # staged encodes advance one layer per serving round
+                    advance_staging()
 
         if pc is not None:
             # hand the (possibly donated-through) pool arrays back to the

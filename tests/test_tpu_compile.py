@@ -238,6 +238,51 @@ def test_tp4_encoder_is_the_one_chip_program(topo, one_chip):
                            for line in missing), sorted(missing)
 
 
+def test_scoped_decode_step_keeps_kernel_names(one_chip):
+    """Named scopes change op metadata only.  In a paged INT8 decode step
+    at published widths (one decoder layer), every Pallas call keeps the
+    instruction name of its jitted wrapper, which the benchmark's trace
+    reduction matches kernels by, and sits under its layer's scope."""
+    cfg = dataclasses.replace(serving_config("transformer-base",
+                                             published=True),
+                              n_layers=1, n_enc_layers=1)
+    tiny = dataclasses.replace(cfg, d_model=64, d_ff=128, vocab=512,
+                               head_dim=8, dtype="float32")
+    tiny_model = build_model(tiny)
+    _, _, recs = quantize_for_serving(
+        tiny_model, tiny_model.init(jax.random.PRNGKey(0)),
+        make_corpus(2, tiny.vocab, seed=0))          # same site names
+    policy = QuantPolicy(mode=QuantMode("symmetric"), act_quant="static")
+    model = build_model(cfg)
+    p_abs = jax.eval_shape(
+        lambda k: quantize_model(model.init(k), recs, policy)[0],
+        jax.random.PRNGKey(0))
+    qctx = dataclasses.replace(quantize_model({}, recs, policy)[1],
+                               impl="pallas")
+    assert qctx.quantize_kv
+    state = jax.eval_shape(lambda: model.init_decode_state(
+        DECODE_ROWS, MAX_LEN, quantized=True, enc_len=64, paged=True,
+        page_size=PAGE_SIZE, n_pages=DECODE_ROWS * MAX_LEN // PAGE_SIZE))
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    tokens = jax.ShapeDtypeStruct((DECODE_ROWS,), jnp.int32,
+                                  sharding=one_chip)
+    text = jax.jit(lambda p, t, s: model.decode_step(p, t, s, quant=qctx)
+                   ).lower(on_chip(p_abs), tokens, on_chip(state)
+                           ).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = pallas_kernel_calls(text)
+    assert {"int8_matmul_pallas", "decode_attention_paged_pallas"} <= \
+        set(kernels) and "unnamed" not in kernels
+    for line in calls:
+        name = line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+        assert re.search(rf"/jit\({name}\)/pallas_call", line), line
+        if name == "decode_attention_paged_pallas":
+            assert "/self_attention/" in line, line
+
+
 def test_quant_context_resolves_to_xla_on_cpu():
     """The ``"auto"`` kernel default picks Pallas only on a TPU; the CPU
     path (every other test) keeps running the jnp references."""
