@@ -16,7 +16,10 @@ continuous, paged, fused admission — greedy and with beam 4.  Checks:
 * the compiled serve burst calls the Pallas INT8 GEMM and paged
   decode-attention kernels;
 * one decode step's logits through the Pallas kernels agree with the same
-  step through the kernels' XLA forms within ``LOGIT_RTOL``.
+  step through the kernels' XLA forms within ``LOGIT_RTOL``;
+* the compiled paged decode-attention kernel matches its f32 oracle at the
+  base, transformer-big, greedy, GQA and chunked shapes
+  (``tests/test_paged_kv.py::test_paged_kernel_on_chip_matches_oracle``).
 
 ``--four-chips`` runs only the multi-chip checks: a tp=4 serve (mesh 1x4)
 against a tp=1 serve of the same requests, greedy and beam 4; and a
@@ -183,6 +186,16 @@ def decode_logits(model, params, qctx, requests, warm_steps: int = 4):
             for impl, f in step.items()}
 
 
+def paged_kernel_parity(check: Checks) -> None:
+    """The repo's on-chip test of the paged kernel, run in this process
+    (the chip belongs to one process at a time)."""
+    import pytest
+    test = (Path(__file__).resolve().parent / "tests" / "test_paged_kv.py")
+    rc = pytest.main(["-q", "-p", "no:cacheprovider",
+                      f"{test}::test_paged_kernel_on_chip_matches_oracle"])
+    check("paged_kernel_matches_oracle", rc == 0, f"pytest exit code {rc}")
+
+
 def one_chip(check: Checks) -> None:
     import jax
     from repro.data import pad_batch
@@ -190,6 +203,7 @@ def one_chip(check: Checks) -> None:
     from repro.kernels.ops import resolve_impl
     from repro.launch.hlo_analysis import pallas_kernel_calls
 
+    paged_kernel_parity(check)
     model, params, requests, calib = build()
     qparams, qctx = quantize(model, params, calib)
     check("kernels_resolve_to_pallas", resolve_impl(qctx.impl) == "pallas",

@@ -241,9 +241,10 @@ def decode_attention_paged(
     sm_scale: float,
     impl: str = "auto",
 ) -> jax.Array:
-    """Paged-cache decode attention: the Pallas kernel walks the block
-    table per page slot (scalar-prefetched index map); the XLA fallback
-    linearizes the table then reuses the contiguous oracle."""
+    """Paged-cache decode attention: the Pallas kernel copies each row's
+    live pages through the scalar-prefetched block table, many rows a grid
+    step; the XLA fallback linearizes the table then reuses the contiguous
+    oracle."""
     impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         return decode_attention_paged_pallas(

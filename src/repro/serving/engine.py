@@ -240,6 +240,12 @@ class ServeResult:
     reorder_bytes: int = 0            # total bytes beam reorders moved
     #                                   (slab gathers unpaged; block-table
     #                                   permutation + partial-page copy paged)
+    # paged decode attention: over the decode steps, layers and occupied
+    # rows that busy_slot_steps counts (one-token steps; speculative
+    # macro-steps are left out), the pages the kernel copies,
+    # ceil(len / page_size), and the block-table slots, max_len / page_size
+    kv_pages_read: int = 0
+    kv_page_slots: int = 0
     # cross-request prefix cache (per-serve deltas; the cache itself —
     # tree, chains, pool — persists on the engine across serves)
     prefix_cache: bool = False
@@ -348,6 +354,8 @@ class ServeResult:
             "pages_in_use": float(self.pages_in_use),
             "page_hwm": float(self.page_hwm),
             "reorder_bytes": float(self.reorder_bytes),
+            "kv_pages_read": float(self.kv_pages_read),
+            "kv_page_slots": float(self.kv_page_slots),
             "prefix_cache": float(self.prefix_cache),
             "prefix_hits": float(self.prefix_hits),
             "prefix_misses": float(self.prefix_misses),
@@ -686,6 +694,23 @@ class ServingEngine:
         *live* row (parked rows of a narrow beam reserve nothing)."""
         return rows * kvc.pages_per_row(
             min(req.max_new_tokens, self.max_len), self.page_size)
+
+    def _kv_page_counts(self, done: int, steps: int,
+                        rows: int) -> Tuple[int, int]:
+        """Pages the paged decode kernel copies, and the block-table slots,
+        for ``rows`` rows over ``steps`` one-token decode steps that follow
+        ``done`` earlier ones, summed over the layers.  A step copies
+        ceil(len / page_size) pages of each row, ``len`` counting the
+        position the step appends."""
+        ps = self.page_size
+
+        def upto(n):       # Σ_{k=1..n} ceil(k / ps), in closed form
+            q, r = divmod(n, ps)
+            return ps * q * (q + 1) // 2 + r * (q + 1)
+
+        per_layer = rows * self.model.cfg.n_layers
+        return (per_layer * (upto(done + steps) - upto(done)),
+                per_layer * steps * (self.max_len // ps))
 
     def _page_rows(self, reqs: Sequence[Request], rows_per_req: int,
                    n_req_rows: int, sentinel: int,
@@ -1917,6 +1942,7 @@ class ServingEngine:
         now = lambda: time.perf_counter() - t0
         decode_steps = 0
         busy_slot_steps = 0
+        kv_pages_read = kv_page_slots = 0
         prefill_rounds = 0
         host_syncs = 0
         prefill_dispatches = 0
@@ -2310,6 +2336,7 @@ class ServingEngine:
                                 buf_host[slot, emit_col + 2])
                             continue
                         used = steps
+                        done = len(req.tokens)
                         for s in range(steps):
                             tok = int(buf_host[slot, s])
                             if tok == self.eos_id:
@@ -2325,6 +2352,10 @@ class ServingEngine:
                                 break
                         busy_slot_steps += used
                         wasted_row_steps += steps - used
+                        if self.paged:
+                            read, slots = self._kv_page_counts(done, used, 1)
+                            kv_pages_read += read
+                            kv_page_slots += slots
                     if ctrl:
                         ctrl.observe(burst_wall, steps, wasted_row_steps,
                                      n_slots)
@@ -2354,6 +2385,8 @@ class ServingEngine:
         return ServeResult(requests=reqs, n_slots=n_slots,
                            decode_steps=decode_steps,
                            busy_slot_steps=busy_slot_steps,
+                           kv_pages_read=kv_pages_read,
+                           kv_page_slots=kv_page_slots,
                            prefill_rounds=prefill_rounds, wall_s=now(),
                            host_syncs=host_syncs,
                            burst_len=ctrl.k if ctrl else K,
@@ -2545,6 +2578,7 @@ class ServingEngine:
         now = lambda: time.perf_counter() - t0
         decode_steps = 0
         busy_slot_steps = 0
+        kv_pages_read = kv_page_slots = 0
         prefill_rounds = 0
         host_syncs = 0
         prefill_dispatches = 0
@@ -3013,6 +3047,12 @@ class ServingEngine:
                         s_g = int(remaining_in[gi] - remaining_out[gi])
                         if req.first_token_s is None:
                             req.first_token_s = t   # fused: from this burst
+                        b_req = width_of[req.req_id]
+                        if self.paged:
+                            read, slots = self._kv_page_counts(
+                                len(histories[base]), s_g, b_req)
+                            kv_pages_read += read
+                            kv_page_slots += slots
                         if s_g:
                             local = comp_host[base:base + beam] - base
                             hist = [c[local] for c in histories[base]]
@@ -3022,7 +3062,6 @@ class ServingEngine:
                             budget_left[base] -= s_g
                         # parked rows of narrow requests are computed-but-idle
                         # grid
-                        b_req = width_of[req.req_id]
                         busy_slot_steps += s_g * b_req
                         wasted_row_steps += (steps - s_g) * beam + \
                             s_g * (beam - b_req)
@@ -3063,6 +3102,8 @@ class ServingEngine:
         return ServeResult(requests=reqs, n_slots=R,
                            decode_steps=decode_steps,
                            busy_slot_steps=busy_slot_steps,
+                           kv_pages_read=kv_pages_read,
+                           kv_page_slots=kv_page_slots,
                            prefill_rounds=prefill_rounds, wall_s=now(),
                            host_syncs=host_syncs,
                            burst_len=ctrl.k if ctrl else K, beam=beam,

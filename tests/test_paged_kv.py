@@ -19,6 +19,8 @@ Three layers of coverage:
   even when the page pool is smaller than contiguous-equivalent capacity.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -509,64 +511,147 @@ def test_free_slots_paged_drops_writes(rng):
         np.asarray(paged.k[0]))
 
 
-def test_paged_kernel_interpret_matches_oracle(rng):
-    """Pallas paged flash-decode (scalar-prefetched block-table walk) vs
-    the pure-jnp oracle, including a sentinel table entry."""
-    B, H, HKV, dh, P, ps, maxP = 3, 4, 2, 8, 16, 4, 4
+def _paged_case(rng, B, H, HKV, dh, ps, maxP, lengths, sentinel_tail):
+    """Random INT8 pools and dense-prefix block tables: row b holds
+    ceil(len_b / ps) pages from a permutation of the pool, the rest of its
+    table is the sentinel ``P`` (or, with ``sentinel_tail=False``, real
+    pages the row does not own)."""
+    P = B * maxP + 3
     q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.float32)
     kp = jnp.asarray(rng.integers(-127, 128, (P, ps, HKV, dh)), jnp.int8)
     vp = jnp.asarray(rng.integers(-127, 128, (P, ps, HKV, dh)), jnp.int8)
     ks = jnp.asarray(rng.uniform(0.001, 0.02, (P, ps, HKV)), jnp.float32)
     vs = jnp.asarray(rng.uniform(0.001, 0.02, (P, ps, HKV)), jnp.float32)
-    tab = jnp.asarray(rng.permutation(P)[:B * maxP].reshape(B, maxP),
-                      jnp.int32)
-    tab = tab.at[0, 3].set(P)                        # unreserved tail
-    lengths = jnp.asarray([11, 16, 5], jnp.int32)
-    want = ref.ref_decode_attention_paged(q, kp, ks, vp, vs, tab, lengths,
-                                          0.35)
-    got = ops.decode_attention_paged(q, kp, ks, vp, vs, tab, lengths,
-                                     sm_scale=0.35, impl="interpret")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("ps,maxP", [(2, 5), (2, 8), (4, 3)])
-def test_paged_kernel_multi_page_blocks_interpret(rng, ps, maxP):
-    """``page_size < 8`` pools fetch SUBLANE//ps consecutive slots per
-    grid step (multi-page sublane blocks) — parity vs the oracle must
-    hold including odd slot counts (sentinel-padded to a block multiple)
-    and an explicit ``pages_per_block`` override."""
-    from repro.kernels.decode_attention import decode_attention_paged_pallas
-
-    B, H, HKV, dh, P = 3, 4, 2, 8, 32
-    q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.float32)
-    kp = jnp.asarray(rng.integers(-127, 128, (P, ps, HKV, dh)), jnp.int8)
-    vp = jnp.asarray(rng.integers(-127, 128, (P, ps, HKV, dh)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.001, 0.02, (P, ps, HKV)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.001, 0.02, (P, ps, HKV)), jnp.float32)
-    tab = np.full((B, maxP), P, np.int32)
     perm = rng.permutation(P)
+    tab = (np.full((B, maxP), P, np.int32) if sentinel_tail else
+           rng.integers(0, P, (B, maxP)).astype(np.int32))
     c = 0
-    lengths = np.zeros((B,), np.int32)
-    for b in range(B):                    # dense-prefix tables, ragged tails
-        n = int(rng.integers(1, maxP + 1))
+    for b, n in enumerate(-(-np.asarray(lengths) // ps)):
         tab[b, :n] = perm[c:c + n]
         c += n
-        lengths[b] = int(rng.integers(1, n * ps + 1))
-    tab, lengths = jnp.asarray(tab), jnp.asarray(lengths)
-    want = ref.ref_decode_attention_paged(q, kp, ks, vp, vs, tab, lengths,
-                                          0.35)
-    got = decode_attention_paged_pallas(q, kp, ks, vp, vs, tab, lengths,
-                                        sm_scale=0.35, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-    # explicit override and the single-page path agree with auto
-    for f in (1, 2):
-        forced = decode_attention_paged_pallas(
-            q, kp, ks, vp, vs, tab, lengths, sm_scale=0.35, interpret=True,
-            pages_per_block=f)
-        np.testing.assert_allclose(np.asarray(forced), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
+    return (q, kp, ks, vp, vs, jnp.asarray(tab),
+            jnp.asarray(lengths, jnp.int32))
+
+
+# (B, H, HKV, dh, ps, maxP, lengths); a length of None draws one in
+# [1, maxP·ps].  At 19 rows of transformer-base's page shape the kernel
+# takes 9 rows a block: blocks of 9, 9 and 1.
+PAGED_CASES = {
+    "gqa2_ps4": (3, 4, 2, 8, 4, 4, [11, 16, 5]),
+    "ps2_odd_slots": (3, 4, 2, 8, 2, 5, [None] * 3),
+    "ps2": (3, 4, 2, 8, 2, 8, [None] * 3),
+    "ps4_three_slots": (3, 4, 2, 8, 4, 3, [None] * 3),
+    "ps16_full_and_empty": (4, 8, 8, 16, 16, 3, [48, 0, 1, 17]),
+    "rows_not_a_block_multiple": (19, 8, 8, 64, 16, 9,
+                                  [0, 144] + [None] * 17),
+    "hkv16": (5, 16, 16, 8, 16, 2, [32, 9, 0, 16, 1]),
+    # 1600-token rows outgrow VMEM: two chunks of 50 page slots, a row
+    # ending in each and a row filling both
+    "rows_in_two_chunks": (3, 8, 8, 64, 16, 100, [1600, 700, 1000]),
+}
+
+
+@pytest.mark.parametrize("sentinel_tail", [True, False])
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_kernel_interpret_matches_oracle(rng, case, sentinel_tail):
+    """Pallas paged flash-decode (row blocks, live pages only) vs the
+    pure-jnp oracle: GQA, page sizes 2/4/16, full and one-token rows, odd
+    slot counts, a batch that is no multiple of the row block, rows walked
+    in two chunks of page slots, and tails of sentinel or foreign table
+    entries.  An empty row attends to nothing:
+    its output is exactly 0 (the oracle's softmax over no position is
+    NaN)."""
+    from repro.kernels.decode_attention import paged_block_plan
+    B, H, HKV, dh, ps, maxP, lengths = PAGED_CASES[case]
+    rows, chunk = paged_block_plan(B, maxP, ps, H, HKV, dh)
+    if case == "rows_not_a_block_multiple":
+        assert B % rows == 1
+    assert (chunk == 50) == (case == "rows_in_two_chunks")
+    lengths = [int(rng.integers(1, maxP * ps + 1)) if n is None else n
+               for n in lengths]
+    args = _paged_case(rng, B, H, HKV, dh, ps, maxP, lengths, sentinel_tail)
+    want = np.asarray(ref.ref_decode_attention_paged(*args, 0.35))
+    got = np.asarray(ops.decode_attention_paged(*args, sm_scale=0.35,
+                                                impl="interpret"))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    assert not np.isnan(got).any()
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
+@pytest.mark.parametrize("B,H,HKV,maxP", [
+    (512, 8, 8, 9), (8, 8, 8, 9), (512, 16, 16, 9), (3, 4, 2, 9),
+    (512, 8, 1, 9), (4, 8, 8, 256), (1, 8, 8, 10_000)])
+def test_paged_rows_per_block_fits_budget(B, H, HKV, maxP):
+    """The row block and the chunk of page slots follow from shapes alone:
+    the kernel's VMEM plan, page buffers at the chip's tile-padded extent,
+    fits the budget; a block never outgrows the batch; a row's slots are
+    cut into equal chunks only when they do not fit.  At the offline
+    cell's shapes a page slot is 16 KiB each of K and V (an (8, 64) int8
+    slab per token fills an (8, 128) tile) and 8 KiB each of their
+    scales, and a block takes 9 rows of all 9 slots."""
+    from repro.kernels.decode_attention import (
+        PAGED_VMEM_BUDGET, _paged_scratch, _tiled_bytes, paged_block_plan,
+        paged_vmem_bytes)
+    ps, dh = 16, 64
+    rows, chunk = paged_block_plan(B, maxP, ps, H, HKV, dh)
+    assert 1 <= rows <= B and 1 <= chunk <= maxP
+    n_chunks = -(-maxP // chunk)
+    assert -(-maxP // n_chunks) == chunk
+    planned = paged_vmem_bytes(rows, chunk, ps, H, HKV, dh)
+    assert planned <= PAGED_VMEM_BUDGET
+    if chunk < maxP:
+        assert rows == 1
+        assert paged_vmem_bytes(1, maxP, ps, H, HKV, dh) > PAGED_VMEM_BUDGET
+    assert rows == B or planned + planned // rows > PAGED_VMEM_BUDGET
+    if (B, H, HKV, maxP) == (512, 8, 8, 9):
+        pages = _paged_scratch(rows, chunk, ps, H, HKV, dh,
+                               interpret=False)[5:]
+        assert sum(map(_tiled_bytes, pages)) == \
+            rows * 2 * maxP * 2 * (16 + 8) * 1024
+        assert (rows, chunk) == (9, 9)
+
+
+def test_paged_block_plan_refuses_a_page_past_the_budget():
+    """A page too big to hold twice for one row is refused by name, not
+    left to the chip's compiler."""
+    from repro.kernels.decode_attention import paged_block_plan
+    with pytest.raises(ValueError, match="budget"):
+        paged_block_plan(1, 2, 2048, 8, 8, 64)
+
+
+# (B, H, HKV, dh, ps, maxP, lengths) at the chip's real shapes; a length of
+# None draws one in [0, maxP·ps]
+ON_CHIP_CASES = {
+    "base_offline_beam4": (512, 8, 8, 64, 16, 9, [None] * 512),
+    "base_greedy": (8, 8, 8, 64, 16, 9, [None] * 8),
+    "big_offline_beam4": (512, 16, 16, 64, 16, 9, [None] * 512),
+    "gqa4": (19, 8, 2, 64, 16, 9, [None] * 19),
+    "rows_in_chunks": (3, 8, 8, 64, 16, 256, [4096, 1000, 2500]),
+}
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="runs the compiled kernel: TPU only")
+@pytest.mark.parametrize("case", sorted(ON_CHIP_CASES))
+def test_paged_kernel_on_chip_matches_oracle(case):
+    """The compiled paged kernel on the chip vs the oracle in f32 at the
+    base, transformer-big, greedy, GQA and chunked shapes.  On the chip
+    each page copy moves the pool's whole HBM tiles past its logical shape
+    (``_page_extents``); a tiling that differs from the one assumed reads
+    other pages' bytes and fails here.  ``chip_smoke.py`` runs this."""
+    rng = np.random.default_rng(1)
+    B, H, HKV, dh, ps, maxP, lengths = ON_CHIP_CASES[case]
+    lengths = [int(rng.integers(0, maxP * ps + 1)) if n is None else n
+               for n in lengths]
+    args = _paged_case(rng, B, H, HKV, dh, ps, maxP, lengths, True)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.ref_decode_attention_paged(*args, dh ** -0.5))
+    got = np.asarray(ops.decode_attention_paged(*args, sm_scale=dh ** -0.5,
+                                                impl="pallas"))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(got[~live], 0.0)
 
 
 def test_init_paged_cache_validates_page_multiple():
@@ -703,3 +788,33 @@ def test_paged_result_metrics_exposed():
         state["requests"], n_slots=4, max_new_tokens=BUDGETS, beam=2)
     # the whole point: the paged reorder moves a fraction of the slab
     assert res.reorder_bytes * 2 < unpaged.reorder_bytes
+
+
+@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("beam", [None, 2])
+def test_paged_kv_page_counters(beam, fused):
+    """``kv_pages_read`` and ``kv_page_slots`` from known lengths.  With an
+    EOS the model never emits, every request decodes exactly its budget;
+    its k-th decoder position is attended with length k, so the kernel
+    copies ceil(k / page_size) pages of each of its rows in each layer,
+    out of max_len / page_size slots.  Unfused admission decodes position
+    1 in the prefill program, outside the decode steps counted."""
+    state = _module_state()
+    ps = 2
+    eng = ServingEngine(state["model"], state["params"], max_len=MAX_LEN,
+                        paged=True, page_size=ps, eos_id=-1)
+    kw = {} if beam is None else {"beam": beam}
+    res = eng.serve(state["requests"], n_slots=4, max_new_tokens=BUDGETS,
+                    burst_len=3, fused_admission=fused, **kw)
+    rows, layers = beam or 1, state["cfg"].n_layers
+    positions = [range(1 if fused else 2, cap + 1) for cap in BUDGETS]
+    assert [len(r.tokens) for r in res.requests] == BUDGETS
+    assert res.kv_pages_read == layers * rows * sum(
+        -(-k // ps) for pos in positions for k in pos)
+    assert res.kv_page_slots == layers * (MAX_LEN // ps) * rows * sum(
+        len(pos) for pos in positions)
+    assert res.kv_page_slots == \
+        layers * (MAX_LEN // ps) * res.busy_slot_steps
+    m = res.metrics()
+    assert (m["kv_pages_read"], m["kv_page_slots"]) == \
+        (res.kv_pages_read, res.kv_page_slots)

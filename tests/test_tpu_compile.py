@@ -126,6 +126,27 @@ def test_decode_attention_paged_compiles(one_chip):
         ((B, max_pages), jnp.int32), ((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("B,H,HKV,max_pages", [
+    (512, HEADS, HEADS, 9),      # the offline beam-4 cell: 512 rows, 144
+    (512, 16, 16, 9),            # transformer-big's 16 heads
+    (19, HEADS, 2, 4),           # GQA (G=4), rows no multiple of the block
+    (2, HEADS, HEADS, 256),      # 4096-token rows: four chunks of slots
+])
+def test_decode_attention_paged_compiles_row_blocks(one_chip, B, H, HKV,
+                                                     max_pages):
+    P = B * max_pages
+    _compiles_to_kernel(
+        "decode_attention_paged_pallas",
+        lambda q, k, ks, v, vs, t, n: decode_attention_paged_pallas(
+            q, k, ks, v, vs, t, n, sm_scale=HEAD_DIM ** -0.5),
+        one_chip, ((B, H, HEAD_DIM), jnp.bfloat16),
+        ((P, PAGE_SIZE, HKV, HEAD_DIM), jnp.int8),
+        ((P, PAGE_SIZE, HKV), jnp.float32),
+        ((P, PAGE_SIZE, HKV, HEAD_DIM), jnp.int8),
+        ((P, PAGE_SIZE, HKV), jnp.float32),
+        ((B, max_pages), jnp.int32), ((B,), jnp.int32))
+
+
 @pytest.mark.parametrize("K,N", [(D_MODEL, D_FF), (D_FF, D_MODEL)])
 def test_int4_matmul_compiles(one_chip, K, N):
     M = DECODE_ROWS
