@@ -13,13 +13,17 @@ continuous, paged, fused admission — greedy and with beam 4.  Checks:
 
 * every request finishes with tokens;
 * greedy ``serve`` tokens equal ``generate`` tokens for the same requests;
-* the compiled serve burst calls the Pallas INT8 GEMM and paged
-  decode-attention kernels;
+* the compiled serve burst calls the Pallas INT8 GEMM, paged
+  decode-attention and scale-writing kernels;
 * one decode step's logits through the Pallas kernels agree with the same
   step through the kernels' XLA forms within ``LOGIT_RTOL``;
 * the compiled paged decode-attention kernel matches its f32 oracle at the
-  base, transformer-big, greedy, GQA and chunked shapes
-  (``tests/test_paged_kv.py::test_paged_kernel_on_chip_matches_oracle``).
+  base, transformer-big, greedy, GQA and chunked shapes, reading each
+  layer of a stacked pool by its index
+  (``tests/test_paged_kv.py::test_paged_kernel_on_chip_matches_oracle``),
+  and the compiled writes of the stacked scale pools leave them as XLA's
+  scatters would
+  (``tests/test_paged_kv.py::test_scale_pool_writes_on_chip_match_xla``).
 
 ``--four-chips`` runs only the multi-chip checks: a tp=4 serve (mesh 1x4)
 against a tp=1 serve of the same requests, greedy and beam 4; and a
@@ -187,13 +191,18 @@ def decode_logits(model, params, qctx, requests, warm_steps: int = 4):
 
 
 def paged_kernel_parity(check: Checks) -> None:
-    """The repo's on-chip test of the paged kernel, run in this process
-    (the chip belongs to one process at a time)."""
+    """The repo's on-chip tests of the paged kernel and of the scale-pool
+    writes, run in this process (the chip belongs to one process at a
+    time)."""
     import pytest
     test = (Path(__file__).resolve().parent / "tests" / "test_paged_kv.py")
-    rc = pytest.main(["-q", "-p", "no:cacheprovider",
-                      f"{test}::test_paged_kernel_on_chip_matches_oracle"])
-    check("paged_kernel_matches_oracle", rc == 0, f"pytest exit code {rc}")
+    for name, case in (
+            ("paged_kernel_matches_oracle",
+             "test_paged_kernel_on_chip_matches_oracle"),
+            ("scale_pool_writes_match_xla",
+             "test_scale_pool_writes_on_chip_match_xla")):
+        rc = pytest.main(["-q", "-p", "no:cacheprovider", f"{test}::{case}"])
+        check(name, rc == 0, f"pytest exit code {rc}")
 
 
 def one_chip(check: Checks) -> None:
@@ -240,6 +249,8 @@ def one_chip(check: Checks) -> None:
     check("burst_calls_int8_matmul", calls.get("int8_matmul_pallas", 0) > 0)
     check("burst_calls_paged_attention",
           calls.get("decode_attention_paged_pallas", 0) > 0)
+    check("burst_calls_scale_writes",
+          calls.get("append_scales_paged_pallas", 0) > 0)
 
     logits = decode_logits(model, qparams, qctx, requests)
     ref = logits["xla"]

@@ -23,6 +23,11 @@ Two cache layouts, two kernels:
   manual DMA, whole tiles at a time, double-buffered so the next step's
   pages are in flight while this one computes, and runs every head of a
   page in one pass on the vector unit.
+
+Both take the model's whole stacked cache, (L, …), and the layer to attend
+as a scalar-prefetch operand, so a decode step never slices a layer out:
+one compiled kernel serves every layer, a Python index and a scanned one
+alike.
 """
 
 from __future__ import annotations
@@ -42,11 +47,11 @@ DEFAULT_BLOCK_S = 256
 PAGED_VMEM_BUDGET = 8 * 1024 * 1024
 
 
-def _flash_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, out_ref,
-                  m_ref, l_ref, acc_ref, *, n_steps: int, block_len: int,
-                  sm_scale: float):
+def _flash_kernel(len_ref, layer_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
+                  out_ref, m_ref, l_ref, acc_ref, *, n_steps: int,
+                  block_len: int, sm_scale: float):
     """One online-softmax step over one contiguous K/V block for every KV
-    head."""
+    head (the layer is chosen by the block index maps)."""
     b, s = pl.program_id(0), pl.program_id(1)
 
     @pl.when(s == 0)
@@ -93,34 +98,38 @@ def _flash_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, out_ref,
 @functools.partial(jax.jit, static_argnames=("sm_scale", "block_s", "interpret"))
 def decode_attention_pallas(
     q: jax.Array,          # (B, H, dh)
-    k_q: jax.Array,        # (B, S, HKV, dh) int8
-    k_scale: jax.Array,    # (B, S, HKV) f32
-    v_q: jax.Array,        # (B, S, HKV, dh) int8
-    v_scale: jax.Array,    # (B, S, HKV) f32
+    k_q: jax.Array,        # (L, B, S, HKV, dh) int8 stacked cache
+    k_scale: jax.Array,    # (L, B, S, HKV) f32
+    v_q: jax.Array,        # (L, B, S, HKV, dh) int8
+    v_scale: jax.Array,    # (L, B, S, HKV) f32
     lengths: jax.Array,    # (B,) int32
+    layer,                 # int or int32 scalar: the layer attended
     *,
     sm_scale: float,
     block_s: int = DEFAULT_BLOCK_S,
     interpret: bool = False,
 ) -> jax.Array:
-    B, S, HKV, dh = k_q.shape
+    _, B, S, HKV, dh = k_q.shape
     H = q.shape[1]
     assert H % HKV == 0, (H, HKV)
     G = H // HKV
     bs = min(block_s, S)
     pad = (-S) % bs
     if pad:
-        k_q = jnp.pad(k_q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_q = jnp.pad(v_q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k_scale = jnp.pad(k_scale, ((0, 0), (0, pad), (0, 0)))
-        v_scale = jnp.pad(v_scale, ((0, 0), (0, pad), (0, 0)))
+        k_q = jnp.pad(k_q, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+        v_q = jnp.pad(v_q, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+        k_scale = jnp.pad(k_scale, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v_scale = jnp.pad(v_scale, ((0, 0), (0, 0), (0, pad), (0, 0)))
     n_steps = (S + pad) // bs
 
-    kv_spec = pl.BlockSpec((1, bs, HKV, dh), lambda b, s, L: (b, s, 0, 0))
-    sc_spec = pl.BlockSpec((1, bs, HKV), lambda b, s, L: (b, s, 0))
-    head_spec = pl.BlockSpec((1, HKV, G, dh), lambda b, s, L: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((pl.Squeezed(), 1, bs, HKV, dh),
+                           lambda b, s, n, ly: (ly[0], b, s, 0, 0))
+    sc_spec = pl.BlockSpec((pl.Squeezed(), 1, bs, HKV),
+                           lambda b, s, n, ly: (ly[0], b, s, 0))
+    head_spec = pl.BlockSpec((1, HKV, G, dh),
+                             lambda b, s, n, ly: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, n_steps),
         in_specs=[head_spec, kv_spec, sc_spec, kv_spec, sc_spec],
         out_specs=head_spec,
@@ -139,9 +148,14 @@ def decode_attention_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q.reshape(B, HKV, G, dh), k_q, k_scale, v_q,
-      v_scale)
+    )(lengths.astype(jnp.int32), _layer_operand(layer),
+      q.reshape(B, HKV, G, dh), k_q, k_scale, v_q, v_scale)
     return out.reshape(B, H, dh)
+
+
+def _layer_operand(layer) -> jax.Array:
+    """A layer index as a (1,) int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 # ---------------------------------------------------------------------------
@@ -157,14 +171,18 @@ def _hbm_tile(rows: int, lanes: int, dtype) -> tuple:
     return -(-rows // tile) * tile, -(-lanes // 128) * 128
 
 
-def _page_extents(ps: int, HKV: int, dh: int, interpret: bool) -> tuple:
-    """(K/V page, scale page) shapes the kernel copies: the tile-padded
-    extents on the chip, the logical ones in interpret mode (whose arrays
+def scale_page_extent(ps: int, HKV: int, interpret: bool) -> tuple:
+    """The (ps, HKV) f32 scale page as a DMA moves it: the tile-padded
+    extent on the chip, the logical one in interpret mode (whose arrays
     carry no padding)."""
-    if interpret:
-        return (ps, HKV, dh), (ps, HKV)
-    return ((ps,) + _hbm_tile(HKV, dh, jnp.int8),
-            _hbm_tile(ps, HKV, jnp.float32))
+    return (ps, HKV) if interpret else _hbm_tile(ps, HKV, jnp.float32)
+
+
+def _page_extents(ps: int, HKV: int, dh: int, interpret: bool) -> tuple:
+    """(K/V page, scale page) shapes the kernel copies, as
+    :func:`scale_page_extent` gives them."""
+    kv = (HKV, dh) if interpret else _hbm_tile(HKV, dh, jnp.int8)
+    return (ps,) + kv, scale_page_extent(ps, HKV, interpret)
 
 
 def _tiled_bytes(buf) -> int:
@@ -223,16 +241,16 @@ def paged_block_plan(B: int, maxP: int, ps: int, H: int, HKV: int,
     return min(B, rows), chunk
 
 
-def _paged_kernel(tab_ref, len_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
-                  out_ref, q32_ref, o32_ref, m_ref, l_ref, acc_ref, k_buf,
-                  ks_buf, v_buf, vs_buf, sems, *, B: int, rows: int,
+def _paged_kernel(tab_ref, len_ref, layer_ref, q_ref, k_hbm, ks_hbm, v_hbm,
+                  vs_hbm, out_ref, q32_ref, o32_ref, m_ref, l_ref, acc_ref,
+                  k_buf, ks_buf, v_buf, vs_buf, sems, *, B: int, rows: int,
                   chunk: int, n_chunks: int, max_pages: int, page_size: int,
                   groups: int, sm_scale: float):
     """One chunk of page slots of a block of ``rows`` rows.  Refs: the flat
-    block table and the lengths (SMEM), the block's queries, the four page
-    pools (HBM), the block's output, f32 queries and outputs, the running
-    max, sum and accumulator, the two-slot page buffers and their DMA
-    semaphores (one per pool and slot)."""
+    block table, the lengths and the layer (SMEM), the block's queries, the
+    four stacked page pools (HBM), the block's output, f32 queries and
+    outputs, the running max, sum and accumulator, the two-slot page
+    buffers and their DMA semaphores (one per pool and slot)."""
     i, c = pl.program_id(0), pl.program_id(1)
     # grid steps run in order: step t's buffer slot is t % 2
     step = i * n_chunks + c
@@ -240,6 +258,7 @@ def _paged_kernel(tab_ref, len_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
     slot = step % 2
     ps, G = page_size, groups
     HKV, dh = q_ref.shape[1] // G, q_ref.shape[2]
+    layer = layer_ref[0]
 
     def live_pages(b, ch):
         """Pages row ``b`` holds in chunk ``ch``: its slots below
@@ -265,7 +284,8 @@ def _paged_kernel(tab_ref, len_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
                     extent = dst.shape[3:]
                     # the page's whole tiles: rows and lanes past the
                     # pool's logical shape are the tile padding it holds
-                    src = src.at[(pid,) + tuple(pl.ds(0, e) for e in extent)]
+                    src = src.at[(layer, pid)
+                                 + tuple(pl.ds(0, e) for e in extent)]
                     action(pltpu.make_async_copy(
                         src, dst.at[buf_slot, r, j], sems.at[n, buf_slot]))
                 return carry
@@ -361,12 +381,13 @@ def _paged_kernel(tab_ref, len_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def decode_attention_paged_pallas(
     q: jax.Array,            # (B, H, dh)
-    k_pages: jax.Array,      # (P, ps, HKV, dh) int8 page pool
-    k_scale: jax.Array,      # (P, ps, HKV) f32
-    v_pages: jax.Array,      # (P, ps, HKV, dh) int8
-    v_scale: jax.Array,      # (P, ps, HKV) f32
+    k_pages: jax.Array,      # (L, P, ps, HKV, dh) int8 stacked page pools
+    k_scale: jax.Array,      # (L, P, ps, HKV) f32
+    v_pages: jax.Array,      # (L, P, ps, HKV, dh) int8
+    v_scale: jax.Array,      # (L, P, ps, HKV) f32
     block_tables: jax.Array, # (B, maxP) int32; sentinel P = unreserved
     lengths: jax.Array,      # (B,) int32
+    layer,                   # int or int32 scalar: the layer attended
     *,
     sm_scale: float,
     interpret: bool = False,
@@ -375,20 +396,20 @@ def decode_attention_paged_pallas(
 
     Grid (ceil(B / rows), ceil(maxP / chunk)) over blocks of ``rows`` rows
     and chunks of ``chunk`` page slots (:func:`paged_block_plan`: one chunk
-    unless a row's slots outgrow the VMEM budget).  The block table and
-    lengths ride in as scalar-prefetch operands; the pools stay in HBM, and
-    each grid step DMAs exactly the pages its rows hold in its chunk —
-    slots below ``ceil(len / page_size)``, wherever they sit in the pool —
-    into VMEM, while the next step's copies are in flight.  A row of length
-    0 copies nothing and writes 0; slots past a row's length, sentinels
-    included, are never read.  Each page runs all of a row's heads in one
-    online-softmax pass, in f32 from the dequantized INT8 K/V, on the
-    vector unit: with G = 1 each dot is a matrix-vector product, too thin
-    for the MXU.  The running max, sum and accumulator carry a row across
-    chunks.
+    unless a row's slots outgrow the VMEM budget).  The block table,
+    lengths and layer ride in as scalar-prefetch operands; the stacked
+    pools stay in HBM, and each grid step DMAs exactly the pages its rows
+    hold in its chunk — that layer's slots below ``ceil(len / page_size)``,
+    wherever they sit in the pool — into VMEM, while the next step's
+    copies are in flight.  A row of length 0 copies nothing and writes 0;
+    slots past a row's length, sentinels included, are never read.  Each
+    page runs all of a row's heads in one online-softmax pass, in f32
+    from the dequantized INT8 K/V, on the vector unit: with G = 1 each dot
+    is a matrix-vector product, too thin for the MXU.  The running max,
+    sum and accumulator carry a row across chunks.
     """
     B, H, dh = q.shape
-    P, ps, HKV, _ = k_pages.shape
+    _, P, ps, HKV, _ = k_pages.shape
     maxP = block_tables.shape[1]
     assert H % HKV == 0, (H, HKV)
     rows, chunk = paged_block_plan(B, maxP, ps, H, HKV, dh)
@@ -396,10 +417,10 @@ def decode_attention_paged_pallas(
     # slots past a row's live pages are never copied; the clip keeps a
     # sentinel inside a live slot (a caller's bug) from reading past the pool
     tab = jnp.clip(block_tables.astype(jnp.int32), 0, P - 1).reshape(-1)
-    row_spec = pl.BlockSpec((rows, H, dh), lambda i, c, t, L: (i, 0, 0))
+    row_spec = pl.BlockSpec((rows, H, dh), lambda i, c, t, n, ly: (i, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(pl.cdiv(B, rows), n_chunks),
         in_specs=[row_spec, hbm, hbm, hbm, hbm],
         out_specs=row_spec,
@@ -418,4 +439,5 @@ def decode_attention_paged_pallas(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(tab, lengths.astype(jnp.int32), q, k_pages, k_scale, v_pages, v_scale)
+    )(tab, lengths.astype(jnp.int32), _layer_operand(layer), q, k_pages,
+      k_scale, v_pages, v_scale)

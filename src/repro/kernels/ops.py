@@ -32,6 +32,10 @@ from repro.kernels.int8_matmul import (
     int8_matmul_batched_pallas,
     int8_matmul_pallas,
 )
+from repro.kernels.kv_pool import (
+    append_scales_paged_pallas,
+    copy_scale_pages_pallas,
+)
 from repro.kernels.quantize import quantize_rowwise_pallas, quantize_static_pallas
 
 
@@ -209,48 +213,99 @@ def quantize_static(x: jax.Array, amax, *, impl: str = "auto") -> QTensor:
 # ---------------------------------------------------------------------------
 
 def decode_attention(
-    q: jax.Array,
-    k_q: jax.Array,
-    k_scale: jax.Array,
-    v_q: jax.Array,
-    v_scale: jax.Array,
-    lengths: jax.Array,
+    q: jax.Array,            # (B, H, dh)
+    k_q: jax.Array,          # (L, B, S, HKV, dh) int8 stacked cache
+    k_scale: jax.Array,      # (L, B, S, HKV) f32
+    v_q: jax.Array,          # (L, B, S, HKV, dh) int8
+    v_scale: jax.Array,      # (L, B, S, HKV) f32
+    lengths: jax.Array,      # (B,) int32
+    layer,                   # int or int32 scalar: the layer attended
     *,
     sm_scale: float,
     impl: str = "auto",
 ) -> jax.Array:
+    """Contiguous-cache decode attention over layer ``layer`` of the
+    stacked cache: the Pallas kernel reads the stack by that index; the
+    XLA fallback indexes the layer and runs the oracle."""
     impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         return decode_attention_pallas(
-            q, k_q, k_scale, v_q, v_scale, lengths,
+            q, k_q, k_scale, v_q, v_scale, lengths, layer,
             sm_scale=sm_scale, interpret=(impl == "interpret"),
         )
-    return ref.ref_decode_attention(q, k_q, k_scale, v_q, v_scale, lengths,
+    return ref.ref_decode_attention(q, k_q[layer], k_scale[layer],
+                                    v_q[layer], v_scale[layer], lengths,
                                     sm_scale)
 
 
 def decode_attention_paged(
     q: jax.Array,            # (B, H, dh)
-    k_pages: jax.Array,      # (P, ps, HKV, dh) int8
-    k_scale: jax.Array,      # (P, ps, HKV) f32
-    v_pages: jax.Array,      # (P, ps, HKV, dh) int8
-    v_scale: jax.Array,      # (P, ps, HKV) f32
+    k_pages: jax.Array,      # (L, P, ps, HKV, dh) int8 stacked pools
+    k_scale: jax.Array,      # (L, P, ps, HKV) f32
+    v_pages: jax.Array,      # (L, P, ps, HKV, dh) int8
+    v_scale: jax.Array,      # (L, P, ps, HKV) f32
     block_tables: jax.Array, # (B, maxP) int32
     lengths: jax.Array,      # (B,) int32
+    layer,                   # int or int32 scalar: the layer attended
     *,
     sm_scale: float,
     impl: str = "auto",
 ) -> jax.Array:
-    """Paged-cache decode attention: the Pallas kernel copies each row's
-    live pages through the scalar-prefetched block table, many rows a grid
-    step; the XLA fallback linearizes the table then reuses the contiguous
+    """Paged-cache decode attention over layer ``layer`` of the stacked
+    pools: the Pallas kernel copies each row's live pages of that layer
+    through the scalar-prefetched block table, many rows a grid step; the
+    XLA fallback linearizes the layer's pages then reuses the contiguous
     oracle."""
     impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         return decode_attention_paged_pallas(
             q, k_pages, k_scale, v_pages, v_scale, block_tables, lengths,
-            sm_scale=sm_scale, interpret=(impl == "interpret"),
+            layer, sm_scale=sm_scale, interpret=(impl == "interpret"),
         )
     return ref.ref_decode_attention_paged(q, k_pages, k_scale, v_pages,
                                           v_scale, block_tables, lengths,
-                                          sm_scale)
+                                          layer, sm_scale)
+
+
+def append_scales_paged(
+    ks_pages: jax.Array,     # (L, P, ps, HKV) f32 stacked scale pools
+    vs_pages: jax.Array,
+    ks: jax.Array,           # (N, HKV) f32 new scales, one row each
+    vs: jax.Array,
+    page: jax.Array,         # (N,) int32; ≥ P writes nowhere
+    off: jax.Array,          # (N,) int32
+    layer,                   # int or int32 scalar
+    *,
+    impl: str = "auto",
+):
+    """Write new tokens' K/V scales at ``[layer, page, off]`` of the stacked
+    scale pools, in place.  The Pallas kernel keeps the pools in the layout
+    the paged decode kernel reads them in; the XLA form is a scatter."""
+    impl = resolve_impl(impl)
+    if impl in ("pallas", "interpret"):
+        return tuple(append_scales_paged_pallas(
+            ks_pages, vs_pages, ks, vs, page, off, layer,
+            interpret=(impl == "interpret")))
+    put = lambda pool, x: pool.at[layer, page, off].set(x, mode="drop")
+    return put(ks_pages, ks), put(vs_pages, vs)
+
+
+def copy_scale_pages(
+    ks_pages: jax.Array,     # (L, P, ps, HKV) f32 stacked scale pools
+    vs_pages: jax.Array,
+    src: jax.Array,          # (B,) int32 source pages, < P
+    dst: jax.Array,          # (B,) int32 destination pages; ≥ P: none
+    *,
+    impl: str = "auto",
+):
+    """Copy page ``src[b]`` onto ``dst[b]`` in every layer of the stacked
+    scale pools, in place (all reads before any write).  The Pallas kernel
+    keeps the pools in the paged decode kernel's layout; the XLA form is a
+    gather and a scatter."""
+    impl = resolve_impl(impl)
+    if impl in ("pallas", "interpret"):
+        return tuple(copy_scale_pages_pallas(
+            ks_pages, vs_pages, src, dst, interpret=(impl == "interpret")))
+    cow = lambda pool: pool.at[:, dst].set(jnp.take(pool, src, axis=1),
+                                           mode="drop")
+    return cow(ks_pages), cow(vs_pages)

@@ -172,27 +172,29 @@ def ref_decode_attention(
 
 def ref_decode_attention_paged(
     q: jax.Array,           # (B, H, dh) f32/bf16
-    k_pages: jax.Array,     # (P, ps, HKV, dh) int8 page pool
-    k_scale: jax.Array,     # (P, ps, HKV) f32
-    v_pages: jax.Array,     # (P, ps, HKV, dh) int8
-    v_scale: jax.Array,     # (P, ps, HKV) f32
+    k_pages: jax.Array,     # (L, P, ps, HKV, dh) int8 stacked page pools
+    k_scale: jax.Array,     # (L, P, ps, HKV) f32
+    v_pages: jax.Array,     # (L, P, ps, HKV, dh) int8
+    v_scale: jax.Array,     # (L, P, ps, HKV) f32
     block_tables: jax.Array,  # (B, maxP) int32 (sentinel = P, clamped)
     lengths: jax.Array,     # (B,) int32
+    layer,                  # int or int32 scalar
     sm_scale: float,
 ) -> jax.Array:
-    """Paged oracle: linearize each row's pages through its block table,
-    then run the contiguous oracle.  Sentinel (unreserved) entries clamp
-    into the pool and are masked out by ``lengths``; with the logical
-    capacity equal to the contiguous cache's ``S`` the result is
-    bit-identical to :func:`ref_decode_attention` on the linearized rows.
+    """Paged oracle: take layer ``layer`` of the pools, linearize each
+    row's pages through its block table, then run the contiguous oracle.
+    Sentinel (unreserved) entries clamp into the pool and are masked out
+    by ``lengths``; with the logical capacity equal to the contiguous
+    cache's ``S`` the result is bit-identical to
+    :func:`ref_decode_attention` on the linearized rows.
     """
-    P = k_pages.shape[0]
+    P = k_pages.shape[1]
     B, maxP = block_tables.shape
     tab = jnp.clip(block_tables, 0, P - 1)
 
     def lin(pool):
-        got = pool[tab]                           # (B, maxP, ps, …)
-        return got.reshape((B, maxP * pool.shape[1]) + pool.shape[2:])
+        got = pool[layer][tab]                    # (B, maxP, ps, …)
+        return got.reshape((B, maxP * pool.shape[2]) + pool.shape[3:])
 
     return ref_decode_attention(q, lin(k_pages), lin(k_scale),
                                 lin(v_pages), lin(v_scale), lengths, sm_scale)

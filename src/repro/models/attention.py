@@ -204,36 +204,37 @@ def attention(
 
     if cache is not None:
         # ---- decode: append at each sequence's cursor, then attend ----
+        # The cache stays stacked: the new K/V are written in place at
+        # ``cache.layer`` and the kernels read the stack by that index.
         paged = cache.block_tables is not None
-        if S == 1:
+        layer = cache.layer
+        with jax.named_scope("kv_pool"):
             if paged:
-                k_c, v_c, ks_c, vs_c = kvc.append_token_paged(
+                append = (kvc.append_token_paged if S == 1
+                          else kvc.append_tokens_paged)
+                k_c, v_c, ks_c, vs_c = append(
                     cache.k, cache.v, cache.k_scale, cache.v_scale,
-                    cache.block_tables, k, v, cache.lengths)
+                    cache.block_tables, k, v, cache.lengths, layer,
+                    impl=quant.impl)
             else:
-                k_c, v_c, ks_c, vs_c = kvc.append_token(
+                # S > 1 is the speculative verify: all S drafted positions
+                append = kvc.append_token if S == 1 else kvc.append_tokens
+                k_c, v_c, ks_c, vs_c = append(
                     cache.k, cache.v, cache.k_scale, cache.v_scale, k, v,
-                    cache.lengths)
-        else:
-            # speculative verify: append all S drafted positions at once
-            if paged:
-                k_c, v_c, ks_c, vs_c = kvc.append_tokens_paged(
-                    cache.k, cache.v, cache.k_scale, cache.v_scale,
-                    cache.block_tables, k, v, cache.lengths)
-            else:
-                k_c, v_c, ks_c, vs_c = kvc.append_tokens(
-                    cache.k, cache.v, cache.k_scale, cache.v_scale, k, v,
-                    cache.lengths)
+                    cache.lengths, layer)
         sm_scale = 1.0 / math.sqrt(dh)
-        if ks_c is None and paged:
-            # FP paged FALLBACK: linearize the pool through the table and
-            # reuse the contiguous math — it materializes a gathered copy
-            # per step, so it trades the beam-reorder slab gather for an
-            # attention-side one (a wash at worst; the cross-K/V gather
-            # still disappears).  The deployment path is the INT8 cache,
-            # whose Pallas kernel walks the table in place with no copy.
-            k_lin = kvc.linearize_pages(k_c, cache.block_tables)
-            v_lin = kvc.linearize_pages(v_c, cache.block_tables)
+        if ks_c is None:
+            # FP cache: one layer's rows, for the jnp attention below.  The
+            # paged FALLBACK linearizes the pool through the table — it
+            # materializes a gathered copy per step, so it trades the
+            # beam-reorder slab gather for an attention-side one (a wash at
+            # worst; the cross-K/V gather still disappears).  The
+            # deployment path is the INT8 cache, whose Pallas kernel walks
+            # the table in place with no copy.
+            k_fp, v_fp = k_c[layer], v_c[layer]
+            if paged:
+                k_fp = kvc.linearize_pages(k_fp, cache.block_tables)
+                v_fp = kvc.linearize_pages(v_fp, cache.block_tables)
         # Each query position j attends its own causal prefix by running
         # the SAME single-query kernel with cursor lengths + j + 1 — for
         # S == 1 this is literally the pre-speculation decode step, and for
@@ -243,17 +244,16 @@ def attention(
         for j in range(S):
             q1 = q[:, j].reshape(B, H, dh)
             lengths = cache.lengths + (j + 1)
-            if ks_c is not None and paged:
+            if ks_c is None:
+                o = _fp_decode_attention(q1, k_fp, v_fp, lengths, sm_scale)
+            elif paged:
                 o = ops.decode_attention_paged(
                     q1, k_c, ks_c, v_c, vs_c, cache.block_tables, lengths,
-                    sm_scale=sm_scale, impl=quant.impl)
-            elif ks_c is not None:
-                o = ops.decode_attention(q1, k_c, ks_c, v_c, vs_c, lengths,
-                                         sm_scale=sm_scale, impl=quant.impl)
-            elif paged:
-                o = _fp_decode_attention(q1, k_lin, v_lin, lengths, sm_scale)
+                    layer, sm_scale=sm_scale, impl=quant.impl)
             else:
-                o = _fp_decode_attention(q1, k_c, v_c, lengths, sm_scale)
+                o = ops.decode_attention(q1, k_c, ks_c, v_c, vs_c, lengths,
+                                         layer, sm_scale=sm_scale,
+                                         impl=quant.impl)
             outs.append(o)
         out = jnp.stack(outs, axis=1).reshape(B, S, H * dh)
         y = dense(params["o_proj"], out, site=f"{site}/o_proj", quant=quant,

@@ -453,75 +453,40 @@ class EncDecLM:
         paged = isinstance(cache, kvc.PagedKVCache)
         tables = cache.block_tables if paged else None
 
-        def block_with_cache(x, bparams, kl, vl, ksl, vsl, ck, cv, site):
-            view = kvc.LayerCacheView(k=kl, v=vl, k_scale=ksl, v_scale=vsl,
-                                      lengths=cache.lengths,
-                                      block_tables=tables)
-            y, entries = self._dec_block(
+        def block_with_cache(x, bparams, pools, layer, ck, cv, site):
+            """One decoder layer; ``pools`` are the stacked (k, v, k_scale,
+            v_scale), written in place at ``layer`` and handed back."""
+            view = kvc.LayerCacheView(*pools, lengths=cache.lengths,
+                                      layer=layer, block_tables=tables)
+            return self._dec_block(
                 bparams, x, (ck, cv), site=site, quant=quant, taps=None,
                 positions=None, kv_lengths=None,
                 memory_lengths=state["src_lengths"], unroll=False,
                 cache_view=view)
-            return y, entries
 
+        pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
         if cfg.scan_layers:
-            # full self-cache in the scan carry (single live copy — see
-            # transformer.py); cross K/V are read-only xs.
-            idx = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-            quantized = cache.quantized
-
+            # the stacked self-cache rides in the scan carry (one live
+            # copy); cross K/V are read-only xs
             def layer(carry, xs):
-                x, kc, vc, ksc, vsc = carry
+                x, pools = carry
                 bp, ck, cv, li = xs
-                with jax.named_scope("kv_pool"):
-                    kl = jax.lax.dynamic_index_in_dim(kc, li, 0,
-                                                      keepdims=False)
-                    vl = jax.lax.dynamic_index_in_dim(vc, li, 0,
-                                                      keepdims=False)
-                    ksl = (jax.lax.dynamic_index_in_dim(ksc, li, 0,
-                                                        keepdims=False)
-                           if quantized else None)
-                    vsl = (jax.lax.dynamic_index_in_dim(vsc, li, 0,
-                                                        keepdims=False)
-                           if quantized else None)
-                x, e = block_with_cache(x, bp, kl, vl, ksl, vsl, ck, cv,
-                                        "dec_blocks.*")
-                with jax.named_scope("kv_pool"):
-                    kc = jax.lax.dynamic_update_index_in_dim(kc, e[0], li, 0)
-                    vc = jax.lax.dynamic_update_index_in_dim(vc, e[1], li, 0)
-                    if quantized:
-                        ksc = jax.lax.dynamic_update_index_in_dim(
-                            ksc, e[2], li, 0)
-                        vsc = jax.lax.dynamic_update_index_in_dim(
-                            vsc, e[3], li, 0)
-                return (x, kc, vc, ksc, vsc), None
+                return block_with_cache(x, bp, pools, li, ck, cv,
+                                        "dec_blocks.*"), None
 
-            init = (x, cache.k, cache.v,
-                    cache.k_scale if quantized else jnp.zeros((), x.dtype),
-                    cache.v_scale if quantized else jnp.zeros((), x.dtype))
-            (x, k_c, v_c, ks_c, vs_c), _ = jax.lax.scan(
-                layer, init,
+            idx = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            (x, pools), _ = jax.lax.scan(
+                layer, (x, pools),
                 (params["dec_blocks"], state["cross_k"], state["cross_v"],
                  idx))
-            if not quantized:
-                ks_c = vs_c = None
         else:
-            kL, vL, ksL, vsL = [], [], [], []
             for i in range(cfg.n_layers):
-                with jax.named_scope("kv_pool"):
-                    kl, vl = cache.k[i], cache.v[i]
-                    ksl = cache.k_scale[i] if cache.quantized else None
-                    vsl = cache.v_scale[i] if cache.quantized else None
                 with jax.named_scope("cross_attention"):
                     ck, cv = state["cross_k"][i], state["cross_v"][i]
-                x, e = block_with_cache(x, params[f"dec_blocks.{i}"], kl, vl,
-                                        ksl, vsl, ck, cv, f"dec_blocks.{i}")
-                kL.append(e[0]); vL.append(e[1])
-                ksL.append(e[2]); vsL.append(e[3])
-            with jax.named_scope("kv_pool"):
-                k_c, v_c = jnp.stack(kL), jnp.stack(vL)
-                ks_c = jnp.stack(ksL) if cache.quantized else None
-                vs_c = jnp.stack(vsL) if cache.quantized else None
+                x, pools = block_with_cache(x, params[f"dec_blocks.{i}"],
+                                            pools, i, ck, cv,
+                                            f"dec_blocks.{i}")
+        k_c, v_c, ks_c, vs_c = pools
 
         state = dict(state)
         if paged:

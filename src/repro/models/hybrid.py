@@ -225,15 +225,25 @@ class HybridLM:
         ssm = state["ssm"]
         x = embed(params["embed"], tokens[:, None], cfg.activation_dtype)
 
+        def shared_with_cache(x, pools, app):
+            """The shared attention block at application ``app``; ``pools``
+            are the stacked (k, v, k_scale, v_scale), written in place at
+            ``app`` and handed back."""
+            view = kvc.LayerCacheView(*pools, lengths=cache.lengths,
+                                      layer=app)
+            return self._shared_block(
+                params, x, quant=quant, taps=None, positions=None,
+                kv_lengths=None, unroll=False, cache_view=view)
+
+        pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
         if cfg.scan_layers:
             grouped = jax.tree_util.tree_map(
                 lambda a: a.reshape(self.n_apps, every, *a.shape[1:]),
                 params["mamba"])
-            quantized = cache.quantized
             gidx = jnp.arange(self.n_apps, dtype=jnp.int32)
 
             def group(carry, xs):
-                x, h_all, conv_all, kc, vc, ksc, vsc = carry
+                x, h_all, conv_all, pools = carry
                 gparams, gi = xs
 
                 def inner(icarry, ys):
@@ -257,38 +267,14 @@ class HybridLM:
                     inner, (x, h_all, conv_all),
                     (gparams, jnp.arange(every, dtype=jnp.int32)))
 
-                kl = jax.lax.dynamic_index_in_dim(kc, gi, 0, keepdims=False)
-                vl = jax.lax.dynamic_index_in_dim(vc, gi, 0, keepdims=False)
-                ksl = (jax.lax.dynamic_index_in_dim(ksc, gi, 0, False)
-                       if quantized else None)
-                vsl = (jax.lax.dynamic_index_in_dim(vsc, gi, 0, False)
-                       if quantized else None)
-                view = kvc.LayerCacheView(k=kl, v=vl, k_scale=ksl,
-                                          v_scale=vsl, lengths=cache.lengths)
-                x, e = self._shared_block(
-                    params, x, quant=quant, taps=None, positions=None,
-                    kv_lengths=None, unroll=False, cache_view=view)
-                kc = jax.lax.dynamic_update_index_in_dim(kc, e[0], gi, 0)
-                vc = jax.lax.dynamic_update_index_in_dim(vc, e[1], gi, 0)
-                if quantized:
-                    ksc = jax.lax.dynamic_update_index_in_dim(ksc, e[2],
-                                                              gi, 0)
-                    vsc = jax.lax.dynamic_update_index_in_dim(vsc, e[3],
-                                                              gi, 0)
-                return (x, h_all, conv_all, kc, vc, ksc, vsc), None
+                x, pools = shared_with_cache(x, pools, gi)
+                return (x, h_all, conv_all, pools), None
 
-            zero = jnp.zeros((), x.dtype)
-            init = (x, ssm.h, ssm.conv, cache.k, cache.v,
-                    cache.k_scale if quantized else zero,
-                    cache.v_scale if quantized else zero)
-            (x, h_all, conv_all, k_c, v_c, ks_c, vs_c), _ = jax.lax.scan(
-                group, init, (grouped, gidx))
+            (x, h_all, conv_all, pools), _ = jax.lax.scan(
+                group, (x, ssm.h, ssm.conv, pools), (grouped, gidx))
             new_ssm = SSMState(h=h_all, conv=conv_all)
-            if not quantized:
-                ks_c = vs_c = None
         else:
             h_list, conv_list = [], []
-            kL, vL, ksL, vsL = [], [], [], []
             app = 0
             for i in range(cfg.n_layers):
                 st = SSMState(h=ssm.h[i], conv=ssm.conv[i])
@@ -297,21 +283,10 @@ class HybridLM:
                 x = x + y
                 h_list.append(st2.h); conv_list.append(st2.conv)
                 if (i + 1) % every == 0:
-                    ksl = cache.k_scale[app] if cache.quantized else None
-                    vsl = cache.v_scale[app] if cache.quantized else None
-                    view = kvc.LayerCacheView(
-                        k=cache.k[app], v=cache.v[app], k_scale=ksl,
-                        v_scale=vsl, lengths=cache.lengths)
-                    x, e = self._shared_block(
-                        params, x, quant=quant, taps=None, positions=None,
-                        kv_lengths=None, unroll=False, cache_view=view)
-                    kL.append(e[0]); vL.append(e[1])
-                    ksL.append(e[2]); vsL.append(e[3])
+                    x, pools = shared_with_cache(x, pools, app)
                     app += 1
             new_ssm = SSMState(h=jnp.stack(h_list), conv=jnp.stack(conv_list))
-            k_c, v_c = jnp.stack(kL), jnp.stack(vL)
-            ks_c = jnp.stack(ksL) if cache.quantized else None
-            vs_c = jnp.stack(vsL) if cache.quantized else None
+        k_c, v_c, ks_c, vs_c = pools
 
         state = dict(state)
         state["ssm"] = new_ssm

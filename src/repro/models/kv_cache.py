@@ -19,10 +19,12 @@ input pipeline — decode correctly.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import ops
 
 INT8_MAX = 127.0
 _EPS = 1e-12
@@ -94,26 +96,24 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 @dataclasses.dataclass(frozen=True)
 class LayerCacheView:
-    """One layer's slice, as consumed by attention.
+    """One layer of a stacked cache, as consumed by attention.
 
-    Contiguous cache: ``k``/``v`` are (B, S, HKV, dh) rows.  Paged cache:
-    ``k``/``v`` are the layer's (P, ps, HKV, dh) page pool and
+    The arrays stay stacked, so a decode step never materialises a layer:
+    appends scatter at ``layer`` and the decode kernels read the stack by
+    it.  Contiguous cache: ``k``/``v`` are (L, B, S, HKV, dh) rows.  Paged
+    cache: ``k``/``v`` are the (L, P, ps, HKV, dh) page pools and
     ``block_tables`` (B, maxP) maps rows to pages (None ⇔ contiguous).
+    ``layer`` is a Python int in an unrolled layer loop and an int32
+    scalar in a scanned one.
     """
 
-    k: jax.Array            # (B, S, HKV, dh) or (P, ps, HKV, dh) paged
+    k: jax.Array            # (L, B, S, HKV, dh) or (L, P, ps, HKV, dh) paged
     v: jax.Array
     k_scale: Optional[jax.Array]
     v_scale: Optional[jax.Array]
     lengths: jax.Array      # (B,)
+    layer: Union[int, jax.Array]
     block_tables: Optional[jax.Array] = None      # (B, maxP) when paged
-
-    def dequantized(self, dtype=jnp.bfloat16) -> Tuple[jax.Array, jax.Array]:
-        if self.k_scale is None:
-            return self.k.astype(dtype), self.v.astype(dtype)
-        k = self.k.astype(jnp.float32) * self.k_scale[..., None]
-        v = self.v.astype(jnp.float32) * self.v_scale[..., None]
-        return k.astype(dtype), v.astype(dtype)
 
 
 def fill_prefix(
@@ -141,15 +141,17 @@ def fill_prefix(
 
 
 def append_token(
-    k_cache: jax.Array,                  # (B, S_max, HKV, dh)
+    k_cache: jax.Array,                  # (L, B, S_max, HKV, dh)
     v_cache: jax.Array,
-    ks_cache: Optional[jax.Array],
+    ks_cache: Optional[jax.Array],       # (L, B, S_max, HKV)
     vs_cache: Optional[jax.Array],
     k_new: jax.Array,                    # (B, 1, HKV, dh) fp
     v_new: jax.Array,
     lengths: jax.Array,                  # (B,) per-sequence cursors
+    layer,                               # int or int32 scalar
 ):
-    """Scatter one new token per sequence at its own cursor (ragged decode).
+    """Scatter one new token per sequence at its own cursor (ragged decode),
+    into layer ``layer`` of the stacked cache, in place.
 
     ``mode="drop"`` is load-bearing for decode bursts: rows that finished
     mid-burst keep stepping with cursors at/past capacity until the burst
@@ -157,30 +159,20 @@ def append_token(
     valid position (which could corrupt a still-live neighbour of a
     shared-capacity cache on backends where clamping is the default).
     """
-    b_idx = jnp.arange(k_cache.shape[0])
-    if ks_cache is not None:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        k_cache = k_cache.at[b_idx, lengths].set(kq[:, 0], mode="drop")
-        v_cache = v_cache.at[b_idx, lengths].set(vq[:, 0], mode="drop")
-        ks_cache = ks_cache.at[b_idx, lengths].set(ks[:, 0], mode="drop")
-        vs_cache = vs_cache.at[b_idx, lengths].set(vs[:, 0], mode="drop")
-    else:
-        k_cache = k_cache.at[b_idx, lengths].set(
-            k_new[:, 0].astype(k_cache.dtype), mode="drop")
-        v_cache = v_cache.at[b_idx, lengths].set(
-            v_new[:, 0].astype(v_cache.dtype), mode="drop")
-    return k_cache, v_cache, ks_cache, vs_cache
+    b_idx = jnp.arange(k_cache.shape[1])
+    return _scatter(k_cache, v_cache, ks_cache, vs_cache, k_new[:, 0],
+                    v_new[:, 0], (layer, b_idx, lengths))
 
 
 def append_tokens(
-    k_cache: jax.Array,                  # (B, S_max, HKV, dh)
+    k_cache: jax.Array,                  # (L, B, S_max, HKV, dh)
     v_cache: jax.Array,
-    ks_cache: Optional[jax.Array],
+    ks_cache: Optional[jax.Array],       # (L, B, S_max, HKV)
     vs_cache: Optional[jax.Array],
     k_new: jax.Array,                    # (B, T, HKV, dh) fp
     v_new: jax.Array,
     lengths: jax.Array,                  # (B,) per-sequence cursors
+    layer,                               # int or int32 scalar
 ):
     """Scatter ``T`` consecutive tokens per row starting at its cursor.
 
@@ -193,19 +185,20 @@ def append_tokens(
     B, T = k_new.shape[0], k_new.shape[1]
     b_idx = jnp.arange(B)[:, None]
     pos = lengths[:, None] + jnp.arange(T, dtype=lengths.dtype)[None, :]
-    if ks_cache is not None:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        k_cache = k_cache.at[b_idx, pos].set(kq, mode="drop")
-        v_cache = v_cache.at[b_idx, pos].set(vq, mode="drop")
-        ks_cache = ks_cache.at[b_idx, pos].set(ks, mode="drop")
-        vs_cache = vs_cache.at[b_idx, pos].set(vs, mode="drop")
-    else:
-        k_cache = k_cache.at[b_idx, pos].set(
-            k_new.astype(k_cache.dtype), mode="drop")
-        v_cache = v_cache.at[b_idx, pos].set(
-            v_new.astype(v_cache.dtype), mode="drop")
-    return k_cache, v_cache, ks_cache, vs_cache
+    return _scatter(k_cache, v_cache, ks_cache, vs_cache, k_new, v_new,
+                    (layer, b_idx, pos))
+
+
+def _scatter(k_cache, v_cache, ks_cache, vs_cache, k_new, v_new, at):
+    """Write new K/V (quantized when the cache has scales) at index ``at``
+    of the stacked caches; out-of-range indices write nowhere."""
+    put = lambda c, x: c.at[at].set(x.astype(c.dtype), mode="drop")
+    if ks_cache is None:
+        return put(k_cache, k_new), put(v_cache, v_new), None, None
+    kq, ks = quantize_kv(k_new)
+    vq, vs = quantize_kv(v_new)
+    return (put(k_cache, kq), put(v_cache, vq), put(ks_cache, ks),
+            put(vs_cache, vs))
 
 
 def insert_at_slots(cache: KVCache, sub: KVCache,
@@ -474,53 +467,49 @@ def init_paged_cache(n_layers: int, batch: int, max_len: int, n_kv: int,
 
 
 def append_token_paged(
-    k_pages: jax.Array,                  # (P, ps, HKV, dh) one layer's pool
+    k_pages: jax.Array,                  # (L, P, ps, HKV, dh) stacked pools
     v_pages: jax.Array,
-    ks_pages: Optional[jax.Array],       # (P, ps, HKV)
+    ks_pages: Optional[jax.Array],       # (L, P, ps, HKV)
     vs_pages: Optional[jax.Array],
     block_tables: jax.Array,             # (B, maxP) int32
     k_new: jax.Array,                    # (B, 1, HKV, dh) fp
     v_new: jax.Array,
     lengths: jax.Array,                  # (B,) per-row cursors
+    layer,                               # int or int32 scalar
+    *,
+    impl: str = "xla",                   # kernel path of the scale writes
 ):
-    """Paged ``append_token``: scatter one token per row at its cursor.
+    """Paged ``append_token``: scatter one token per row at its cursor,
+    into layer ``layer`` of the stacked pools, in place.
 
     The destination page comes from the block table; rows whose cursor is
     past capacity, or whose table entry is the unreserved sentinel, drop
     the write (same ``mode="drop"`` contract as the contiguous append —
     finished rows keep stepping inside a burst and must write nowhere).
     """
-    P, ps = k_pages.shape[0], k_pages.shape[1]
+    P, ps = k_pages.shape[1], k_pages.shape[2]
     maxP = block_tables.shape[1]
     b_idx = jnp.arange(block_tables.shape[0])
     slot = lengths // ps
     off = lengths % ps
     entry = block_tables[b_idx, jnp.minimum(slot, maxP - 1)]
     page = jnp.where(slot < maxP, entry, P)          # past capacity → drop
-    if ks_pages is not None:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        k_pages = k_pages.at[page, off].set(kq[:, 0], mode="drop")
-        v_pages = v_pages.at[page, off].set(vq[:, 0], mode="drop")
-        ks_pages = ks_pages.at[page, off].set(ks[:, 0], mode="drop")
-        vs_pages = vs_pages.at[page, off].set(vs[:, 0], mode="drop")
-    else:
-        k_pages = k_pages.at[page, off].set(
-            k_new[:, 0].astype(k_pages.dtype), mode="drop")
-        v_pages = v_pages.at[page, off].set(
-            v_new[:, 0].astype(v_pages.dtype), mode="drop")
-    return k_pages, v_pages, ks_pages, vs_pages
+    return _append_paged(k_pages, v_pages, ks_pages, vs_pages, k_new[:, 0],
+                         v_new[:, 0], layer, page, off, impl)
 
 
 def append_tokens_paged(
-    k_pages: jax.Array,                  # (P, ps, HKV, dh) one layer's pool
+    k_pages: jax.Array,                  # (L, P, ps, HKV, dh) stacked pools
     v_pages: jax.Array,
-    ks_pages: Optional[jax.Array],       # (P, ps, HKV)
+    ks_pages: Optional[jax.Array],       # (L, P, ps, HKV)
     vs_pages: Optional[jax.Array],
     block_tables: jax.Array,             # (B, maxP) int32
     k_new: jax.Array,                    # (B, T, HKV, dh) fp
     v_new: jax.Array,
     lengths: jax.Array,                  # (B,) per-row cursors
+    layer,                               # int or int32 scalar
+    *,
+    impl: str = "xla",                   # kernel path of the scale writes
 ):
     """Paged :func:`append_tokens`: T consecutive tokens per row.
 
@@ -529,7 +518,7 @@ def append_tokens_paged(
     contract as :func:`append_token_paged`).  Positions are distinct
     within a row, so no two writes collide on a (page, offset) pair.
     """
-    P, ps = k_pages.shape[0], k_pages.shape[1]
+    P, ps = k_pages.shape[1], k_pages.shape[2]
     maxP = block_tables.shape[1]
     B, T = k_new.shape[0], k_new.shape[1]
     b_idx = jnp.arange(B)[:, None]
@@ -538,18 +527,28 @@ def append_tokens_paged(
     off = pos % ps
     entry = block_tables[b_idx, jnp.minimum(slot, maxP - 1)]
     page = jnp.where(slot < maxP, entry, P)          # past capacity → drop
-    if ks_pages is not None:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        k_pages = k_pages.at[page, off].set(kq, mode="drop")
-        v_pages = v_pages.at[page, off].set(vq, mode="drop")
-        ks_pages = ks_pages.at[page, off].set(ks, mode="drop")
-        vs_pages = vs_pages.at[page, off].set(vs, mode="drop")
-    else:
-        k_pages = k_pages.at[page, off].set(
-            k_new.astype(k_pages.dtype), mode="drop")
-        v_pages = v_pages.at[page, off].set(
-            v_new.astype(v_pages.dtype), mode="drop")
+    return _append_paged(k_pages, v_pages, ks_pages, vs_pages, k_new, v_new,
+                         layer, page, off, impl)
+
+
+def _append_paged(k_pages, v_pages, ks_pages, vs_pages, k_new, v_new, layer,
+                  page, off, impl):
+    """Write new tokens (rows of ``k_new``/``v_new``, one per ``page`` and
+    ``off`` entry) at ``[layer, page, off]`` of the stacked pools, in
+    place; a page past the pool writes nowhere.  The scales of an INT8
+    pool go through ``ops.append_scales_paged`` (see ``kernels/kv_pool``)
+    so that they stay in the layout the paged decode kernel reads."""
+    at = (layer, page, off)
+    if ks_pages is None:
+        return _scatter(k_pages, v_pages, None, None, k_new, v_new, at)
+    kq, ks = quantize_kv(k_new)
+    vq, vs = quantize_kv(v_new)
+    k_pages, v_pages, _, _ = _scatter(k_pages, v_pages, None, None, kq, vq,
+                                      at)
+    HKV = ks.shape[-1]
+    ks_pages, vs_pages = ops.append_scales_paged(
+        ks_pages, vs_pages, ks.reshape(-1, HKV), vs.reshape(-1, HKV),
+        page.reshape(-1), off.reshape(-1), layer, impl=impl)
     return k_pages, v_pages, ks_pages, vs_pages
 
 
@@ -661,7 +660,8 @@ def insert_rows_paged(cache: PagedKVCache, sub: KVCache, slots: jax.Array,
     )
 
 
-def cow_write_slot(cache: PagedKVCache) -> PagedKVCache:
+def cow_write_slot(cache: PagedKVCache, *, impl: str = "xla"
+                   ) -> PagedKVCache:
     """Copy-on-write for each row's *current write slot* page.
 
     For every row, copy the page its block table currently maps for the
@@ -678,7 +678,9 @@ def cow_write_slot(cache: PagedKVCache) -> PagedKVCache:
     :func:`gather_beams_paged`) and the copy-on-write contract of shared
     prefix pages: shared pages are only ever *read* through block tables,
     and any row about to write through a shared mapping first diverts the
-    write slot into its own reservation here.
+    write slot into its own reservation here.  The scale pools of an INT8
+    cache are copied by ``ops.copy_scale_pages`` on ``impl`` (see
+    ``kernels/kv_pool``).
     """
     P, ps, maxP = cache.n_pages, cache.page_size, cache.max_pages
     B = cache.block_tables.shape[0]
@@ -688,22 +690,22 @@ def cow_write_slot(cache: PagedKVCache) -> PagedKVCache:
     dst_page = cache.own_pages[b_idx, sp]            # sentinel → copy drops
 
     def cow(pool):
-        if pool is None:
-            return None
         payload = jnp.take(pool, src_page, axis=1)   # (L, B, ps, …)
         return pool.at[:, dst_page].set(payload, mode="drop")
 
+    ks, vs = cache.k_scale, cache.v_scale
+    if cache.quantized:
+        ks, vs = ops.copy_scale_pages(ks, vs, src_page, dst_page, impl=impl)
     return PagedKVCache(
-        k=cow(cache.k), v=cow(cache.v),
-        k_scale=cow(cache.k_scale), v_scale=cow(cache.v_scale),
+        k=cow(cache.k), v=cow(cache.v), k_scale=ks, v_scale=vs,
         block_tables=cache.block_tables.at[b_idx, sp].set(dst_page),
         own_pages=cache.own_pages,                   # physical, never moves
         lengths=cache.lengths,
     )
 
 
-def gather_beams_paged(cache: PagedKVCache, beam_idx: jax.Array
-                       ) -> PagedKVCache:
+def gather_beams_paged(cache: PagedKVCache, beam_idx: jax.Array, *,
+                       impl: str = "xla") -> PagedKVCache:
     """Zero-copy beam reorder: permute block tables, not payload.
 
     The contiguous :func:`gather_beams` moves the whole (L, B, S, HKV, dh)
@@ -729,7 +731,7 @@ def gather_beams_paged(cache: PagedKVCache, beam_idx: jax.Array
         block_tables=jnp.take(cache.block_tables, beam_idx, axis=0),
         own_pages=cache.own_pages,
         lengths=jnp.take(cache.lengths, beam_idx, axis=0),
-    ))
+    ), impl=impl)
 
 
 # ---------------------------------------------------------------------------

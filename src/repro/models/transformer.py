@@ -213,63 +213,36 @@ class DecoderLM:
             x = tokens_or_embeds.astype(cfg.activation_dtype)
         B = x.shape[0]
 
-        def block_with_cache(x, bparams, kl, vl, ksl, vsl, site):
-            view = kvc.LayerCacheView(k=kl, v=vl, k_scale=ksl, v_scale=vsl,
-                                      lengths=cache.lengths)
-            x, entries, _ = self._block_apply(
+        def block_with_cache(x, bparams, pools, layer, site):
+            """One layer; ``pools`` are the stacked (k, v, k_scale,
+            v_scale), written in place at ``layer`` and handed back."""
+            view = kvc.LayerCacheView(*pools, lengths=cache.lengths,
+                                      layer=layer)
+            x, pools, _ = self._block_apply(
                 bparams, x, site=site, quant=quant, taps=None,
                 positions=None, kv_lengths=None, unroll=False,
                 cache_view=view)
-            return x, entries
+            return x, pools
 
+        pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
         if cfg.scan_layers:
-            # The full cache rides in the scan CARRY (sliced/written per
-            # layer with dynamic_update_index) so exactly one copy lives —
-            # xs/ys would keep input and output caches alive simultaneously
-            # (2× HBM for the dominant decode buffer).
-            idx = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-            quantized = cache.quantized
-
+            # The stacked cache rides in the scan CARRY, so exactly one
+            # copy lives — xs/ys would keep input and output caches alive
+            # simultaneously (2× HBM for the dominant decode buffer).
             def layer(carry, xs):
-                x, kc, vc, ksc, vsc = carry
+                x, pools = carry
                 bparams, li = xs
-                kl = jax.lax.dynamic_index_in_dim(kc, li, 0, keepdims=False)
-                vl = jax.lax.dynamic_index_in_dim(vc, li, 0, keepdims=False)
-                ksl = (jax.lax.dynamic_index_in_dim(ksc, li, 0,
-                                                    keepdims=False)
-                       if quantized else None)
-                vsl = (jax.lax.dynamic_index_in_dim(vsc, li, 0,
-                                                    keepdims=False)
-                       if quantized else None)
-                x, (k2, v2, ks2, vs2) = block_with_cache(
-                    x, bparams, kl, vl, ksl, vsl, "blocks.*")
-                kc = jax.lax.dynamic_update_index_in_dim(kc, k2, li, 0)
-                vc = jax.lax.dynamic_update_index_in_dim(vc, v2, li, 0)
-                if quantized:
-                    ksc = jax.lax.dynamic_update_index_in_dim(ksc, ks2, li, 0)
-                    vsc = jax.lax.dynamic_update_index_in_dim(vsc, vs2, li, 0)
-                return (x, kc, vc, ksc, vsc), None
+                return block_with_cache(x, bparams, pools, li,
+                                        "blocks.*"), None
 
-            init = (x, cache.k, cache.v,
-                    cache.k_scale if quantized else jnp.zeros((), x.dtype),
-                    cache.v_scale if quantized else jnp.zeros((), x.dtype))
-            (x, k_c, v_c, ks_c, vs_c), _ = jax.lax.scan(
-                layer, init, (params["blocks"], idx))
-            if not quantized:
-                ks_c = vs_c = None
+            idx = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            (x, pools), _ = jax.lax.scan(layer, (x, pools),
+                                         (params["blocks"], idx))
         else:
-            k_list, v_list, ks_list, vs_list = [], [], [], []
             for i in range(cfg.n_layers):
-                ksl = cache.k_scale[i] if cache.quantized else None
-                vsl = cache.v_scale[i] if cache.quantized else None
-                x, (k2, v2, ks2, vs2) = block_with_cache(
-                    x, params[f"blocks.{i}"], cache.k[i], cache.v[i],
-                    ksl, vsl, f"blocks.{i}")
-                k_list.append(k2); v_list.append(v2)
-                ks_list.append(ks2); vs_list.append(vs2)
-            k_c = jnp.stack(k_list); v_c = jnp.stack(v_list)
-            ks_c = jnp.stack(ks_list) if cache.quantized else None
-            vs_c = jnp.stack(vs_list) if cache.quantized else None
+                x, pools = block_with_cache(x, params[f"blocks.{i}"], pools,
+                                            i, f"blocks.{i}")
+        k_c, v_c, ks_c, vs_c = pools
 
         state = dict(state)
         state["cache"] = kvc.KVCache(k=k_c, v=v_c, k_scale=ks_c,

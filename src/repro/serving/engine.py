@@ -985,8 +985,7 @@ class ServingEngine:
             self._hit_splice_jits[group] = fn
         return fn
 
-    @staticmethod
-    def _beam_gather_state(state: Dict[str, Any], idx: jax.Array):
+    def _beam_gather_state(self, state: Dict[str, Any], idx: jax.Array):
         """Reorder every batch-major leaf of the decode state (paper §5.3).
 
         Paged cache: the reorder degenerates to a block-table permutation
@@ -999,7 +998,8 @@ class ServingEngine:
         cache = state.get("cache")
         if isinstance(cache, kvc.PagedKVCache):
             out = dict(state)
-            out["cache"] = kvc.gather_beams_paged(cache, idx)
+            out["cache"] = kvc.gather_beams_paged(cache, idx,
+                                                  impl=self.quant.impl)
             return out
 
         def gather(leaf):

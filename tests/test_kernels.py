@@ -151,36 +151,40 @@ def test_quantize_static_awkward_rows(rng, M):
     (2, 16, 2, 64, 128),
 ])
 def test_decode_attention(rng, B, H, HKV, dh, S):
+    """The contiguous kernel over a cache stacked on two layers of
+    different contents, each layer read by its index, vs the oracle."""
+    L = 2
     q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.float32)
-    kq = jnp.asarray(rng.integers(-127, 128, (B, S, HKV, dh)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, (B, S, HKV, dh)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(1e-3, 2e-2, (B, S, HKV)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(1e-3, 2e-2, (B, S, HKV)), jnp.float32)
+    kq = jnp.asarray(rng.integers(-127, 128, (L, B, S, HKV, dh)), jnp.int8)
+    vq = jnp.asarray(rng.integers(-127, 128, (L, B, S, HKV, dh)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(1e-3, 2e-2, (L, B, S, HKV)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(1e-3, 2e-2, (L, B, S, HKV)), jnp.float32)
     lengths = jnp.asarray(rng.integers(1, S + 1, (B,)), jnp.int32)
     sm = 1.0 / np.sqrt(dh)
-    got = ops.decode_attention(q, kq, ks, vq, vs, lengths, sm_scale=sm,
-                               impl="interpret")
-    want = ops.decode_attention(q, kq, ks, vq, vs, lengths, sm_scale=sm,
-                                impl="xla")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=3e-4, atol=3e-4)
+    for layer in (0, jnp.int32(1)):
+        got = ops.decode_attention(q, kq, ks, vq, vs, lengths, layer,
+                                   sm_scale=sm, impl="interpret")
+        want = ops.decode_attention(q, kq, ks, vq, vs, lengths, layer,
+                                    sm_scale=sm, impl="xla")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=3e-4, atol=3e-4)
 
 
 def test_decode_attention_respects_lengths(rng):
     """Tokens beyond `lengths` must not influence the output."""
     B, H, dh, S = 1, 4, 32, 64
     q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.float32)
-    kq = jnp.asarray(rng.integers(-127, 128, (B, S, H, dh)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, (B, S, H, dh)), jnp.int8)
-    ks = jnp.ones((B, S, H), jnp.float32) * 0.01
-    vs = jnp.ones((B, S, H), jnp.float32) * 0.01
+    kq = jnp.asarray(rng.integers(-127, 128, (1, B, S, H, dh)), jnp.int8)
+    vq = jnp.asarray(rng.integers(-127, 128, (1, B, S, H, dh)), jnp.int8)
+    ks = jnp.ones((1, B, S, H), jnp.float32) * 0.01
+    vs = jnp.ones((1, B, S, H), jnp.float32) * 0.01
     lengths = jnp.asarray([20], jnp.int32)
-    out1 = ops.decode_attention(q, kq, ks, vq, vs, lengths,
+    out1 = ops.decode_attention(q, kq, ks, vq, vs, lengths, 0,
                                 sm_scale=0.1, impl="interpret")
     # poison the out-of-range region
-    kq2 = kq.at[:, 20:].set(127)
-    vq2 = vq.at[:, 20:].set(-127)
-    out2 = ops.decode_attention(q, kq2, ks, vq2, vs, lengths,
+    kq2 = kq.at[:, :, 20:].set(127)
+    vq2 = vq.at[:, :, 20:].set(-127)
+    out2 = ops.decode_attention(q, kq2, ks, vq2, vs, lengths, 0,
                                 sm_scale=0.1, impl="interpret")
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-6, atol=1e-6)

@@ -402,28 +402,16 @@ def _paged_with_rows(rng, *, quantized, n_rows=3, lengths=(5, 8, 0)):
         # pointing their cursor past capacity — mirrors finished rows)
         cur_flat = jnp.where(jnp.asarray(live), cur, flat.capacity)
         k_c, v_c, ks_c, vs_c = kvc.append_token(
-            flat.k[0], flat.v[0],
-            None if not quantized else flat.k_scale[0],
-            None if not quantized else flat.v_scale[0],
-            k_new, v_new, cur_flat)
-        flat = kvc.KVCache(k=flat.k.at[0].set(k_c), v=flat.v.at[0].set(v_c),
-                           k_scale=(None if not quantized
-                                    else flat.k_scale.at[0].set(ks_c)),
-                           v_scale=(None if not quantized
-                                    else flat.v_scale.at[0].set(vs_c)),
+            flat.k, flat.v, flat.k_scale, flat.v_scale, k_new, v_new,
+            cur_flat, 0)
+        flat = kvc.KVCache(k=k_c, v=v_c, k_scale=ks_c, v_scale=vs_c,
                            lengths=flat.lengths)
         cur_paged = jnp.where(jnp.asarray(live), cur, paged.capacity)
         kp, vp, ksp, vsp = kvc.append_token_paged(
-            paged.k[0], paged.v[0],
-            None if not quantized else paged.k_scale[0],
-            None if not quantized else paged.v_scale[0],
-            paged.block_tables, k_new, v_new, cur_paged)
+            paged.k, paged.v, paged.k_scale, paged.v_scale,
+            paged.block_tables, k_new, v_new, cur_paged, 0)
         paged = kvc.PagedKVCache(
-            k=paged.k.at[0].set(kp), v=paged.v.at[0].set(vp),
-            k_scale=(None if not quantized
-                     else paged.k_scale.at[0].set(ksp)),
-            v_scale=(None if not quantized
-                     else paged.v_scale.at[0].set(vsp)),
+            k=kp, v=vp, k_scale=ksp, v_scale=vsp,
             block_tables=paged.block_tables, own_pages=paged.own_pages,
             lengths=paged.lengths)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -456,16 +444,18 @@ def test_paged_append_linearizes_to_contiguous(rng, quantized):
                 lin_ks[r, :n], np.asarray(flat.k_scale[0, r, :n]))
 
 
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
 @pytest.mark.parametrize("quantized", [False, True])
-def test_gather_beams_paged_matches_slab_gather(rng, quantized):
+def test_gather_beams_paged_matches_slab_gather(rng, quantized, impl):
     """The block-table permutation + partial-page copy produces the same
     *logical* rows as the full slab gather, and the next append after the
-    reorder lands in a privately-owned page (no cross-row corruption)."""
+    reorder lands in a privately-owned page (no cross-row corruption);
+    the scale pools copied by XLA or by the Pallas scale copy."""
     paged, flat = _paged_with_rows(rng, quantized=quantized, n_rows=4,
                                    lengths=(6, 6, 6, 6))
     idx = jnp.asarray([2, 2, 0, 1], jnp.int32)
     g_flat = kvc.gather_beams(flat, idx)
-    g_paged = kvc.gather_beams_paged(paged, idx)
+    g_paged = kvc.gather_beams_paged(paged, idx, impl=impl)
     np.testing.assert_array_equal(np.asarray(g_paged.lengths),
                                   np.asarray(g_flat.lengths))
     lin = np.asarray(kvc.linearize_pages(g_paged.k[0],
@@ -473,22 +463,104 @@ def test_gather_beams_paged_matches_slab_gather(rng, quantized):
     for r in range(4):
         np.testing.assert_array_equal(lin[r, :6],
                                       np.asarray(g_flat.k[0, r, :6]))
+    if quantized:
+        lin_s = np.asarray(kvc.linearize_pages(g_paged.v_scale[0],
+                                               g_paged.block_tables))
+        for r in range(4):
+            np.testing.assert_array_equal(
+                lin_s[r, :6], np.asarray(g_flat.v_scale[0, r, :6]))
     # rows 0 and 1 both gathered row 2: appending different tokens next
     # must not collide (each row's write slot points into its own pages)
     k_new = jnp.asarray(rng.normal(size=(4, 1, 2, 4)), jnp.float32)
     v_new = jnp.asarray(rng.normal(size=(4, 1, 2, 4)), jnp.float32)
     kp, vp, _, _ = kvc.append_token_paged(
-        g_paged.k[0], g_paged.v[0],
-        None if not quantized else g_paged.k_scale[0],
-        None if not quantized else g_paged.v_scale[0],
-        g_paged.block_tables, k_new, v_new, g_paged.lengths)
-    lin2 = np.asarray(kvc.linearize_pages(kp, g_paged.block_tables))
+        g_paged.k, g_paged.v, g_paged.k_scale, g_paged.v_scale,
+        g_paged.block_tables, k_new, v_new, g_paged.lengths, 0, impl=impl)
+    lin2 = np.asarray(kvc.linearize_pages(kp[0], g_paged.block_tables))
     for r in range(4):
         np.testing.assert_array_equal(lin2[r, :6], lin[r, :6])  # history kept
         if quantized:
             continue                     # int8 rounding covered via engine
         np.testing.assert_allclose(lin2[r, 6], np.asarray(k_new[r, 0]),
                                    rtol=1e-6)
+
+
+@pytest.mark.parametrize("n_new", [1, 3])
+@pytest.mark.parametrize("cache_kind", ["paged", "paged_interpret",
+                                        "contiguous"])
+def test_append_at_a_layer_leaves_the_others(rng, cache_kind, n_new):
+    """An append into layer i of the stacked cache writes that layer's
+    pages (or rows) and scales in place and leaves every other layer
+    bit-identical: one token a row (decode) and three (speculative
+    verify), paged (scales written by XLA, or by the Pallas scale writer
+    in interpret mode) and contiguous, the layer a Python int or an int32
+    scalar.  The last row's cursor is past capacity: it writes nowhere."""
+    L, B, HKV, DH, ps, max_len = 3, 4, 2, 4, 4, 16
+    paged = cache_kind != "contiguous"
+    impl = "interpret" if cache_kind == "paged_interpret" else "xla"
+    if paged:
+        cache = kvc.init_paged_cache(L, B, max_len, HKV, DH, page_size=ps,
+                                     quantized=True)
+        pages = jnp.arange(B * max_len // ps, dtype=jnp.int32)
+        cache = kvc.assign_pages(cache, jnp.arange(B),
+                                 pages.reshape(B, max_len // ps))
+    else:
+        cache = kvc.init_cache(L, B, max_len, HKV, DH, quantized=True)
+    pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
+    # each layer starts with contents of its own
+    pools = tuple(jnp.asarray(rng.integers(1, 100, p.shape), p.dtype)
+                  for p in pools)
+    lengths = jnp.asarray([0, 5, 9, max_len], jnp.int32)
+    k_new = jnp.asarray(rng.normal(size=(B, n_new, HKV, DH)), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(B, n_new, HKV, DH)), jnp.float32)
+    for layer in (0, jnp.int32(1), 2):
+        if paged:
+            append = (kvc.append_token_paged if n_new == 1
+                      else kvc.append_tokens_paged)
+            got = append(*pools, cache.block_tables, k_new, v_new, lengths,
+                         layer, impl=impl)
+        else:
+            append = kvc.append_token if n_new == 1 else kvc.append_tokens
+            got = append(*pools, k_new, v_new, lengths, layer)
+        i = int(layer)
+        for before, after in zip(pools, got):
+            before, after = np.asarray(before), np.asarray(after)
+            for other in set(range(L)) - {i}:
+                np.testing.assert_array_equal(after[other], before[other])
+            assert not np.array_equal(after[i], before[i])
+        for pool, new in ((0, kvc.quantize_kv(k_new)[0]),
+                          (2, kvc.quantize_kv(k_new)[1]),
+                          (3, kvc.quantize_kv(v_new)[1])):
+            rows = (kvc.linearize_pages(got[pool][i], cache.block_tables)
+                    if paged else got[pool][i])
+            for b, n in enumerate(np.asarray(lengths)[:-1]):
+                np.testing.assert_array_equal(
+                    np.asarray(rows[b, n:n + n_new]), np.asarray(new[b]))
+        # the row past capacity left its own pages (or row) as they were
+        last = (kvc.linearize_pages(got[0][i], cache.block_tables)
+                if paged else got[0][i])[B - 1]
+        before = (kvc.linearize_pages(pools[0][i], cache.block_tables)
+                  if paged else pools[0][i])[B - 1]
+        np.testing.assert_array_equal(np.asarray(last), np.asarray(before))
+
+
+def test_scale_page_copy_kernel_matches_xla(rng):
+    """The Pallas copy-on-write of the scale pools (interpret mode) moves
+    exactly what the XLA gather-then-scatter moves, in every layer: two
+    rows that swap pages, a row that copies its page onto itself, a row
+    whose destination is the unreserved sentinel, and rows that copy one
+    page to two places."""
+    L, P, ps, HKV = 3, 9, 4, 2
+    ks = jnp.asarray(rng.normal(size=(L, P, ps, HKV)), jnp.float32)
+    vs = jnp.asarray(rng.normal(size=(L, P, ps, HKV)), jnp.float32)
+    src = jnp.asarray([1, 2, 4, 3, 5, 5], jnp.int32)
+    dst = jnp.asarray([2, 1, 4, P, 6, 7], jnp.int32)
+    want = ops.copy_scale_pages(ks, vs, src, dst, impl="xla")
+    got = ops.copy_scale_pages(ks, vs, src, dst, impl="interpret")
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert np.array_equal(np.asarray(got[0][:, 2]), np.asarray(ks[:, 1]))
+    assert np.array_equal(np.asarray(got[0][:, 1]), np.asarray(ks[:, 2]))
 
 
 def test_free_slots_paged_drops_writes(rng):
@@ -500,28 +572,31 @@ def test_free_slots_paged_drops_writes(rng):
     assert np.all(np.asarray(freed.block_tables) == paged.n_pages)
     k_new = jnp.asarray(rng.normal(size=(3, 1, 2, 4)), jnp.float32)
     kp, _, _, _ = kvc.append_token_paged(
-        freed.k[0], freed.v[0], None, None, freed.block_tables,
-        k_new, k_new, jnp.zeros((3,), jnp.int32))
-    np.testing.assert_array_equal(np.asarray(kp), np.asarray(freed.k[0]))
+        freed.k, freed.v, None, None, freed.block_tables,
+        k_new, k_new, jnp.zeros((3,), jnp.int32), 0)
+    np.testing.assert_array_equal(np.asarray(kp), np.asarray(freed.k))
     # reserved rows' appends (same cursors) do land
     assert not np.array_equal(
         np.asarray(kvc.append_token_paged(
-            paged.k[0], paged.v[0], None, None, paged.block_tables,
-            k_new, k_new, jnp.zeros((3,), jnp.int32))[0]),
-        np.asarray(paged.k[0]))
+            paged.k, paged.v, None, None, paged.block_tables,
+            k_new, k_new, jnp.zeros((3,), jnp.int32), 0)[0]),
+        np.asarray(paged.k))
 
 
-def _paged_case(rng, B, H, HKV, dh, ps, maxP, lengths, sentinel_tail):
-    """Random INT8 pools and dense-prefix block tables: row b holds
-    ceil(len_b / ps) pages from a permutation of the pool, the rest of its
-    table is the sentinel ``P`` (or, with ``sentinel_tail=False``, real
-    pages the row does not own)."""
+def _paged_case(rng, B, H, HKV, dh, ps, maxP, lengths, sentinel_tail,
+                n_layers=2):
+    """Random stacked INT8 pools, each layer's contents its own, and
+    dense-prefix block tables: row b holds ceil(len_b / ps) pages from a
+    permutation of the pool, the rest of its table is the sentinel ``P``
+    (or, with ``sentinel_tail=False``, real pages the row does not
+    own)."""
     P = B * maxP + 3
+    L = n_layers
     q = jnp.asarray(rng.normal(size=(B, H, dh)), jnp.float32)
-    kp = jnp.asarray(rng.integers(-127, 128, (P, ps, HKV, dh)), jnp.int8)
-    vp = jnp.asarray(rng.integers(-127, 128, (P, ps, HKV, dh)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.001, 0.02, (P, ps, HKV)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.001, 0.02, (P, ps, HKV)), jnp.float32)
+    kp = jnp.asarray(rng.integers(-127, 128, (L, P, ps, HKV, dh)), jnp.int8)
+    vp = jnp.asarray(rng.integers(-127, 128, (L, P, ps, HKV, dh)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.001, 0.02, (L, P, ps, HKV)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.001, 0.02, (L, P, ps, HKV)), jnp.float32)
     perm = rng.permutation(P)
     tab = (np.full((B, maxP), P, np.int32) if sentinel_tail else
            rng.integers(0, P, (B, maxP)).astype(np.int32))
@@ -558,9 +633,11 @@ def test_paged_kernel_interpret_matches_oracle(rng, case, sentinel_tail):
     pure-jnp oracle: GQA, page sizes 2/4/16, full and one-token rows, odd
     slot counts, a batch that is no multiple of the row block, rows walked
     in two chunks of page slots, and tails of sentinel or foreign table
-    entries.  An empty row attends to nothing:
-    its output is exactly 0 (the oracle's softmax over no position is
-    NaN)."""
+    entries.  The pools are stacked over two layers of different contents
+    and every layer is checked, by a Python index and by an int32 scalar,
+    so a kernel that reads the wrong layer fails.  An empty row attends to
+    nothing: its output is exactly 0 (the oracle's softmax over no
+    position is NaN)."""
     from repro.kernels.decode_attention import paged_block_plan
     B, H, HKV, dh, ps, maxP, lengths = PAGED_CASES[case]
     rows, chunk = paged_block_plan(B, maxP, ps, H, HKV, dh)
@@ -570,13 +647,20 @@ def test_paged_kernel_interpret_matches_oracle(rng, case, sentinel_tail):
     lengths = [int(rng.integers(1, maxP * ps + 1)) if n is None else n
                for n in lengths]
     args = _paged_case(rng, B, H, HKV, dh, ps, maxP, lengths, sentinel_tail)
-    want = np.asarray(ref.ref_decode_attention_paged(*args, 0.35))
-    got = np.asarray(ops.decode_attention_paged(*args, sm_scale=0.35,
-                                                impl="interpret"))
     live = np.asarray(lengths) > 0
-    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
-    assert not np.isnan(got).any()
-    np.testing.assert_array_equal(got[~live], 0.0)
+    outs = []
+    for layer in (0, jnp.int32(1)):
+        want = np.asarray(ref.ref_decode_attention_paged(*args, layer, 0.35))
+        got = np.asarray(ops.decode_attention_paged(*args, layer,
+                                                    sm_scale=0.35,
+                                                    impl="interpret"))
+        np.testing.assert_allclose(got[live], want[live], rtol=2e-5,
+                                   atol=2e-5)
+        assert not np.isnan(got).any()
+        np.testing.assert_array_equal(got[~live], 0.0)
+        outs.append(got)
+    if live.any():
+        assert not np.allclose(outs[0][live], outs[1][live])
 
 
 @pytest.mark.parametrize("B,H,HKV,maxP", [
@@ -636,7 +720,8 @@ ON_CHIP_CASES = {
 @pytest.mark.parametrize("case", sorted(ON_CHIP_CASES))
 def test_paged_kernel_on_chip_matches_oracle(case):
     """The compiled paged kernel on the chip vs the oracle in f32 at the
-    base, transformer-big, greedy, GQA and chunked shapes.  On the chip
+    base, transformer-big, greedy, GQA and chunked shapes, over pools
+    stacked on two layers, each layer read by its index.  On the chip
     each page copy moves the pool's whole HBM tiles past its logical shape
     (``_page_extents``); a tiling that differs from the one assumed reads
     other pages' bytes and fails here.  ``chip_smoke.py`` runs this."""
@@ -645,13 +730,61 @@ def test_paged_kernel_on_chip_matches_oracle(case):
     lengths = [int(rng.integers(0, maxP * ps + 1)) if n is None else n
                for n in lengths]
     args = _paged_case(rng, B, H, HKV, dh, ps, maxP, lengths, True)
-    with jax.default_matmul_precision("highest"):
-        want = np.asarray(ref.ref_decode_attention_paged(*args, dh ** -0.5))
-    got = np.asarray(ops.decode_attention_paged(*args, sm_scale=dh ** -0.5,
-                                                impl="pallas"))
     live = np.asarray(lengths) > 0
-    np.testing.assert_allclose(got[live], want[live], rtol=1e-4, atol=1e-4)
-    np.testing.assert_array_equal(got[~live], 0.0)
+    for layer in (0, 1):
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(ref.ref_decode_attention_paged(
+                *args, layer, dh ** -0.5))
+        got = np.asarray(ops.decode_attention_paged(
+            *args, layer, sm_scale=dh ** -0.5, impl="pallas"))
+        np.testing.assert_allclose(got[live], want[live], rtol=1e-4,
+                                   atol=1e-4)
+        np.testing.assert_array_equal(got[~live], 0.0)
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="runs the compiled kernels: TPU only")
+@pytest.mark.parametrize("HKV", [8, 16, 2])
+def test_scale_pool_writes_on_chip_match_xla(HKV):
+    """The compiled Pallas writes of the stacked scale pools on the chip
+    leave the pools bit-identical to XLA's scatter and gather-then-scatter,
+    at the offline cell's pool (6 layers, 512 rows of 9 pages of 16), at
+    transformer-big's 16 KV heads and at 2: an append at a layer, with
+    sentinel rows, and the copy-on-write with swapped, self-copied and
+    sentinel pages.  On the chip each row's scales are one DMA into the
+    pool's tile-padded rows; a tiling that differs from the one assumed
+    writes other rows' bytes and fails here.  ``chip_smoke.py`` runs
+    this."""
+    rng = np.random.default_rng(2)
+    L, B, ps = 6, 512, 16
+    P = B * 9
+    pools = [jnp.asarray(rng.uniform(0.001, 0.02, (L, P, ps, HKV)),
+                         jnp.float32) for _ in range(2)]
+    new = [jnp.asarray(rng.uniform(0.001, 0.02, (B, HKV)), jnp.float32)
+           for _ in range(2)]
+    page = rng.permutation(P)[:B].astype(np.int32)
+    page[::7] = P                                     # rows that drop
+    off = rng.integers(0, ps, B).astype(np.int32)
+    # copy-on-write: distinct destinations (each row's own page); pairs
+    # of rows that swap pages, rows that copy their page onto itself,
+    # rows whose destination is the sentinel, and rows that read the page
+    # the next row writes
+    dst = rng.permutation(P)[:B].astype(np.int32)
+    src = np.roll(dst, -1)
+    src[0:64:2], src[1:64:2] = dst[1:64:2], dst[0:64:2]
+    src[64:128] = dst[64:128]
+    dst[128:160] = P
+    src, dst = jnp.asarray(src), jnp.asarray(dst)
+    for layer in (0, 5):
+        got, want = (ops.append_scales_paged(
+            *pools, *new, jnp.asarray(page), jnp.asarray(off), layer,
+            impl=impl) for impl in ("pallas", "xla"))
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    got, want = (ops.copy_scale_pages(*pools, src, dst, impl=impl)
+                 for impl in ("pallas", "xla"))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_init_paged_cache_validates_page_multiple():

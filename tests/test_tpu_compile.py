@@ -32,11 +32,14 @@ from repro.kernels.decode_attention import (decode_attention_paged_pallas,
                                             decode_attention_pallas)
 from repro.kernels.int4_matmul import int4_matmul_pallas
 from repro.kernels.int8_matmul import int8_matmul_pallas
+from repro.kernels.kv_pool import (append_scales_paged_pallas,
+                                   copy_scale_pages_pallas)
 from repro.kernels.quantize import (quantize_rowwise_pallas,
                                     quantize_static_pallas)
 from repro.launch.hlo_analysis import pallas_kernel_calls
 from repro.launch.serve import quantize_for_serving, serving_config
 from repro.models import build_model
+from repro.models import kv_cache as kvc
 from repro.serving.engine import log_probs, on_whole_rows
 from repro.serving.sharding import param_shardings
 
@@ -99,31 +102,32 @@ def test_quantize_compiles(one_chip, kind, M):
 
 
 def test_decode_attention_compiles(one_chip):
-    B = DECODE_ROWS
+    B, L = DECODE_ROWS, 2
     _compiles_to_kernel(
         "decode_attention_pallas",
-        lambda q, k, ks, v, vs, n: decode_attention_pallas(
-            q, k, ks, v, vs, n, sm_scale=HEAD_DIM ** -0.5),
+        lambda q, k, ks, v, vs, n, layer: decode_attention_pallas(
+            q, k, ks, v, vs, n, layer, sm_scale=HEAD_DIM ** -0.5),
         one_chip, ((B, HEADS, HEAD_DIM), jnp.bfloat16),
-        ((B, MAX_LEN, HEADS, HEAD_DIM), jnp.int8),
-        ((B, MAX_LEN, HEADS), jnp.float32),
-        ((B, MAX_LEN, HEADS, HEAD_DIM), jnp.int8),
-        ((B, MAX_LEN, HEADS), jnp.float32), ((B,), jnp.int32))
+        ((L, B, MAX_LEN, HEADS, HEAD_DIM), jnp.int8),
+        ((L, B, MAX_LEN, HEADS), jnp.float32),
+        ((L, B, MAX_LEN, HEADS, HEAD_DIM), jnp.int8),
+        ((L, B, MAX_LEN, HEADS), jnp.float32), ((B,), jnp.int32),
+        ((), jnp.int32))
 
 
 def test_decode_attention_paged_compiles(one_chip):
-    B, max_pages = DECODE_ROWS, MAX_LEN // PAGE_SIZE
+    B, max_pages, L = DECODE_ROWS, MAX_LEN // PAGE_SIZE, 2
     P = B * max_pages
     _compiles_to_kernel(
         "decode_attention_paged_pallas",
-        lambda q, k, ks, v, vs, t, n: decode_attention_paged_pallas(
-            q, k, ks, v, vs, t, n, sm_scale=HEAD_DIM ** -0.5),
+        lambda q, k, ks, v, vs, t, n, layer: decode_attention_paged_pallas(
+            q, k, ks, v, vs, t, n, layer, sm_scale=HEAD_DIM ** -0.5),
         one_chip, ((B, HEADS, HEAD_DIM), jnp.bfloat16),
-        ((P, PAGE_SIZE, HEADS, HEAD_DIM), jnp.int8),
-        ((P, PAGE_SIZE, HEADS), jnp.float32),
-        ((P, PAGE_SIZE, HEADS, HEAD_DIM), jnp.int8),
-        ((P, PAGE_SIZE, HEADS), jnp.float32),
-        ((B, max_pages), jnp.int32), ((B,), jnp.int32))
+        ((L, P, PAGE_SIZE, HEADS, HEAD_DIM), jnp.int8),
+        ((L, P, PAGE_SIZE, HEADS), jnp.float32),
+        ((L, P, PAGE_SIZE, HEADS, HEAD_DIM), jnp.int8),
+        ((L, P, PAGE_SIZE, HEADS), jnp.float32),
+        ((B, max_pages), jnp.int32), ((B,), jnp.int32), ((), jnp.int32))
 
 
 @pytest.mark.parametrize("B,H,HKV,max_pages", [
@@ -134,17 +138,39 @@ def test_decode_attention_paged_compiles(one_chip):
 ])
 def test_decode_attention_paged_compiles_row_blocks(one_chip, B, H, HKV,
                                                      max_pages):
-    P = B * max_pages
+    P, L = B * max_pages, 6
     _compiles_to_kernel(
         "decode_attention_paged_pallas",
-        lambda q, k, ks, v, vs, t, n: decode_attention_paged_pallas(
-            q, k, ks, v, vs, t, n, sm_scale=HEAD_DIM ** -0.5),
+        lambda q, k, ks, v, vs, t, n, layer: decode_attention_paged_pallas(
+            q, k, ks, v, vs, t, n, layer, sm_scale=HEAD_DIM ** -0.5),
         one_chip, ((B, H, HEAD_DIM), jnp.bfloat16),
-        ((P, PAGE_SIZE, HKV, HEAD_DIM), jnp.int8),
-        ((P, PAGE_SIZE, HKV), jnp.float32),
-        ((P, PAGE_SIZE, HKV, HEAD_DIM), jnp.int8),
-        ((P, PAGE_SIZE, HKV), jnp.float32),
-        ((B, max_pages), jnp.int32), ((B,), jnp.int32))
+        ((L, P, PAGE_SIZE, HKV, HEAD_DIM), jnp.int8),
+        ((L, P, PAGE_SIZE, HKV), jnp.float32),
+        ((L, P, PAGE_SIZE, HKV, HEAD_DIM), jnp.int8),
+        ((L, P, PAGE_SIZE, HKV), jnp.float32),
+        ((B, max_pages), jnp.int32), ((B,), jnp.int32), ((), jnp.int32))
+
+
+@pytest.mark.parametrize("B,HKV", [(512, HEADS), (512, 16), (19, 2)])
+def test_scale_pool_writes_compile(one_chip, B, HKV):
+    """The Pallas writes of the stacked scale pools, at the offline cell's
+    pool (512 rows of 9 pages, 6 layers), transformer-big's 16 KV heads
+    and a GQA pool: one row's scales a DMA, and whole pages through VMEM
+    for the copy-on-write."""
+    P, L = B * 9, 6
+    pool = ((L, P, PAGE_SIZE, HKV), jnp.float32)
+    _compiles_to_kernel(
+        "append_scales_paged_pallas",
+        lambda ks_p, vs_p, ks, vs, page, off, layer:
+            append_scales_paged_pallas(ks_p, vs_p, ks, vs, page, off, layer),
+        one_chip, pool, pool, ((B, HKV), jnp.float32),
+        ((B, HKV), jnp.float32), ((B,), jnp.int32), ((B,), jnp.int32),
+        ((), jnp.int32))
+    _compiles_to_kernel(
+        "copy_scale_pages_pallas",
+        lambda ks_p, vs_p, src, dst: copy_scale_pages_pallas(
+            ks_p, vs_p, src, dst),
+        one_chip, pool, pool, ((B,), jnp.int32), ((B,), jnp.int32))
 
 
 @pytest.mark.parametrize("K,N", [(D_MODEL, D_FF), (D_FF, D_MODEL)])
@@ -302,6 +328,111 @@ def test_scoped_decode_step_keeps_kernel_names(one_chip):
         assert re.search(rf"/jit\({name}\)/pallas_call", line), line
         if name == "decode_attention_paged_pallas":
             assert "/self_attention/" in line, line
+
+
+def _computations(hlo: str) -> dict:
+    """A compiled module's computations: name → its instructions, each
+    (name, output type, opcode, operand names, called computations)."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        ins = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) "
+                       r"([\w\-]+)\((.*?)\)(.*)", line)
+        if cur is not None and ins:
+            name, out, op, args, rest = ins.groups()
+            cur.append((name, out, op, re.findall(r"%([\w.\-]+)", args),
+                        re.findall(r"(?:calls|body)=%([\w.\-]+)", rest),
+                        line))
+    return comps
+
+
+def test_decode_loop_writes_the_stacked_pool_in_place(one_chip):
+    """The decode loop never materialises a layer of the self-KV pool, nor
+    copies the stacked pool.  A paged INT8 decode step and the beam
+    reorder at published widths and depth (six decoder layers), the
+    offline cell's pool (512 rows of 9 pages), in a ``while_loop`` that
+    carries the donated decode state as a burst program does: the loop
+    body calls the paged kernel once a layer, on the stacked K/V pools and
+    scale pools themselves, and no copy, slice, concatenation or fusion in
+    it outputs a pool-shaped array other than an in-place scatter or
+    update.  (At this depth XLA would keep scale pools it scatters into
+    itself in a compact layout and relayout the whole stack before every
+    kernel call; at two layers it would not, so two do not guard.)"""
+    L, rows = 6, 512
+    cfg = dataclasses.replace(serving_config("transformer-base",
+                                             published=True),
+                              n_layers=L, n_enc_layers=1)
+    tiny = dataclasses.replace(cfg, d_model=64, d_ff=128, vocab=512,
+                               head_dim=8, dtype="float32")
+    tiny_model = build_model(tiny)
+    _, _, recs = quantize_for_serving(
+        tiny_model, tiny_model.init(jax.random.PRNGKey(0)),
+        make_corpus(2, tiny.vocab, seed=0))          # same site names
+    policy = QuantPolicy(mode=QuantMode("symmetric"), act_quant="static")
+    model = build_model(cfg)
+    p_abs = jax.eval_shape(
+        lambda k: quantize_model(model.init(k), recs, policy)[0],
+        jax.random.PRNGKey(0))
+    qctx = dataclasses.replace(quantize_model({}, recs, policy)[1],
+                               impl="pallas")
+    max_len = 144
+    P = rows * max_len // PAGE_SIZE
+    state = jax.eval_shape(lambda: model.init_decode_state(
+        rows, max_len, quantized=True, enc_len=128, paged=True,
+        page_size=PAGE_SIZE, n_pages=P))
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+
+    def loop(params, tokens, state):
+        def body(carry):
+            i, tokens, state = carry
+            logits, state = model.decode_step(params, tokens, state,
+                                              quant=qctx)
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+            beams = (jnp.arange(rows) // 4) * 4 + tokens % 4
+            state = dict(state, cache=kvc.gather_beams_paged(
+                state["cache"], beams, impl="pallas"))
+            return i + 1, tokens, state
+
+        return jax.lax.while_loop(lambda c: c[0] < 8, body,
+                                  (jnp.int32(0), tokens, state))
+
+    tokens = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    text = jax.jit(loop, donate_argnums=(2,)).lower(
+        on_chip(p_abs), tokens, on_chip(state)).compile().as_text()
+    comps = _computations(text)
+    bodies = [c for ins_list in comps.values() for ins in ins_list
+              if ins[2] == "while" for c in ins[4]]
+    assert len(bodies) == 1
+    body = comps[bodies[0]]
+    shape = {name: out.split("{")[0] for name, out, *_ in body}
+    kv = f"s8[{L},{P},{PAGE_SIZE},{HEADS},{HEAD_DIM}]"
+    sc = f"f32[{L},{P},{PAGE_SIZE},{HEADS}]"
+    pools = re.compile(r"^\(?(s8|f32)\[(\d+,)?(1,)?%d,%d,%d(,%d)?\]"
+                       % (P, PAGE_SIZE, HEADS, HEAD_DIM))
+    calls = [ins for ins in body
+             if "decode_attention_paged_pallas" in ins[5]
+             and 'custom_call_target="tpu_custom_call"' in ins[5]]
+    assert len(calls) == L
+    for *_, operands, _, _ in calls:
+        assert sorted(shape[o] for o in operands if o in shape
+                      and pools.match(shape[o])) == [sc, sc, kv, kv]
+    in_place = ("scatter", "dynamic-update-slice")
+    for name, out, op, operands, called, line in body:
+        if not pools.match(out):
+            continue
+        if op == "fusion":
+            root = [i for i in comps[called[0]] if "ROOT" in i[5]]
+            assert root[0][2] in in_place, line
+        else:
+            assert op not in ("copy", "copy-start", "copy-done", "slice",
+                              "slice-start", "slice-done", "dynamic-slice",
+                              "concatenate", "transpose"), line
+            assert "ConcatBitcast" not in line, line
 
 
 def test_quant_context_resolves_to_xla_on_cpu():
